@@ -51,6 +51,7 @@ from repro.configs import llama_paper
 from repro.core.energy import decode_counts, prefill_counts, step_energy
 from repro.core.hardware import get_profile
 from repro.core.meter import CarbonMeter
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import Model
 from repro.models.costing import workload_of
 from repro.serving import (EngineConfig, Request, ServingEngine,
@@ -1358,6 +1359,7 @@ def derived() -> float:
 
 def main():
     global REPEATS, TAIL_RUNS
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--variant", default="smoke", choices=["smoke", "full"])
     ap.add_argument("--requests", type=int, default=N_REQUESTS)

@@ -22,6 +22,12 @@ once per (batch, kv head, logical page), independent of Hq AND of S — the
 chunk rides along for free on the memory-bound page read, which is what
 makes mixed prefill+decode quanta cheap. ``chunked_prefill_grid_spec``
 exposes the shapes so tests can assert this without re-deriving internals.
+
+TPU block rules (Mosaic) shape the position operands: q_pos is viewed as
+(B, S, 1) so each row's query positions arrive as a full (S, 1) column,
+k_pos as (B, max_pages, 1, page_size) so each page's positions are a full
+(1, page_size) row, and the running softmax state is kept as (group*S, 1)
+columns.
 """
 from __future__ import annotations
 
@@ -83,34 +89,35 @@ def _chunked_prefill_kernel(tbl_ref, q_ref, k_ref, v_ref, qpos_ref, kpos_ref,
     g, S, hd = q.shape
     k = k_ref[0, 0].astype(jnp.float32)                   # (ps, hd)
     v = v_ref[0, 0].astype(jnp.float32)                   # (ps, hd_v)
-    qpos = qpos_ref[0]                                    # (S,)
-    kpos = kpos_ref[0]                                    # (ps,)
+    qpos = qpos_ref[0]                                    # (S, 1)
+    kpos = kpos_ref[0, 0]                                 # (1, ps)
 
     # (group*S, ps) scores: every query row of the chunk vs this page
     q2 = q.reshape(g * S, hd)
     s = jax.lax.dot_general(q2, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    ok = (kpos[None, :] >= 0) & (kpos[None, :] <= qpos[:, None])  # (S, ps)
+    ok = (kpos >= 0) & (kpos <= qpos)                     # (S, ps)
     if window is not None:
-        ok &= kpos[None, :] > qpos[:, None] - window
+        ok &= kpos > qpos - window
     if chunk is not None:
-        ok &= (kpos[None, :] // chunk) == (qpos[:, None] // chunk)
-    ok = jnp.broadcast_to(ok[None], (g, S, ok.shape[-1])).reshape(g * S, -1)
+        ok &= (kpos // chunk) == (qpos // chunk)
+    if g > 1:
+        ok = jnp.concatenate([ok] * g, axis=0)            # (group*S, ps)
     s = jnp.where(ok, s, NEG_INF)
 
-    m_prev = m_ref[...]                                   # (group*S,)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                                   # (group*S, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
         p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ik == n_kv - 1)
     def _finalize():
         hd_v = acc_ref.shape[-1]
-        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
+        o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
         o_ref[0] = o.reshape(g, S, hd_v).astype(o_ref.dtype)
 
 
@@ -152,14 +159,14 @@ def chunked_prefill_attention(q: jax.Array, k_pages: jax.Array,
                          lambda b, h, ik, tbl: (h, page_of(b, ik, tbl), 0, 0)),
             pl.BlockSpec(spec["v_block"],
                          lambda b, h, ik, tbl: (h, page_of(b, ik, tbl), 0, 0)),
-            pl.BlockSpec((1, S), lambda b, h, ik, tbl: (b, 0)),
-            pl.BlockSpec((1, ps), lambda b, h, ik, tbl: (b, ik)),
+            pl.BlockSpec((1, S, 1), lambda b, h, ik, tbl: (b, 0, 0)),
+            pl.BlockSpec((1, 1, 1, ps), lambda b, h, ik, tbl: (b, ik, 0, 0)),
         ],
         out_specs=pl.BlockSpec(spec["o_block"],
                                lambda b, h, ik, tbl: (b, h, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((group * S,), jnp.float32),
-            pltpu.VMEM((group * S,), jnp.float32),
+            pltpu.VMEM((group * S, 1), jnp.float32),
+            pltpu.VMEM((group * S, 1), jnp.float32),
             pltpu.VMEM((group * S, hd_v), jnp.float32),
         ],
     )
@@ -168,4 +175,5 @@ def chunked_prefill_attention(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, S, hd_v), q.dtype),
         interpret=interpret,
-    )(block_tbl, q, k_pages, v_pages, q_pos, k_pos)
+    )(block_tbl.astype(jnp.int32), q, k_pages, v_pages,
+      q_pos.reshape(B, S, 1), k_pos.reshape(B, M, 1, ps))

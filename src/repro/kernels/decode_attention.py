@@ -6,6 +6,14 @@ differ from hd (MLA-absorbed decode: q/k live in the 512+64-dim latent,
 v IS the 512-dim latent; see ``mla_decode_attention`` in ops.py);
 k_pos (B, W) int32 (-1 empty); q_pos (B,) int32 current absolute position.
 
+TPU block rules (Mosaic): the last two dims of every VMEM block must be
+(8k, 128k) or the array's own. The wrappers therefore view q/o as
+(B, Hkv, group, hd) so a block's last two dims are the full (group, hd)
+at any group size, view k_pos as (B, nk, 1, bk) so each kv block's
+positions are a full (1, bk) row even for 16-token pages, and pass q_pos
+(one scalar per row) through scalar prefetch into SMEM. The running
+softmax state is kept as (group, 1) columns.
+
 Grid (B, Hkv, num_kv_blocks): one program per KV head, with the whole
 (group, hd) GQA query block resident in VMEM — every query head of the
 group scores against the KV block the program just pulled from HBM. The kv
@@ -39,9 +47,9 @@ def decode_grid_spec(B: int, Hq: int, Hkv: int, W: int, hd: int, hd_v: int,
 
     The contract asserted by tests/test_engine_fused.py: the head grid axis is
     Hkv (not Hq), the k/v blocks carry a single KV head, and the q/o blocks
-    carry the full GQA group — so the number of HBM reads of each KV block
-    equals the number of grid points touching it, i.e. exactly one per
-    (batch, kv head, kv block).
+    (of the (B, Hkv, group, hd) head-grouped view) carry the full GQA group
+    — so the number of HBM reads of each KV block equals the number of grid
+    points touching it, i.e. exactly one per (batch, kv head, kv block).
 
     Paged extension (``page_size``/``num_pages`` given): the kv grid axis
     iterates the slot's ``max_pages`` LOGICAL pages and the k/v BlockSpecs
@@ -57,10 +65,10 @@ def decode_grid_spec(B: int, Hq: int, Hkv: int, W: int, hd: int, hd_v: int,
         nk = W // page_size                  # max logical pages per slot
         return {
             "grid": (B, Hkv, nk),
-            "q_block": (1, group, hd),
+            "q_block": (1, 1, group, hd),
             "k_block": (1, 1, page_size, hd),
             "v_block": (1, 1, page_size, hd_v),
-            "o_block": (1, group, hd_v),
+            "o_block": (1, 1, group, hd_v),
             "group": group,
             "block_k": page_size,
             "num_kv_blocks": nk,
@@ -74,10 +82,10 @@ def decode_grid_spec(B: int, Hq: int, Hkv: int, W: int, hd: int, hd_v: int,
     nk = -(-W // bk)
     return {
         "grid": (B, Hkv, nk),
-        "q_block": (1, group, hd),
+        "q_block": (1, 1, group, hd),
         "k_block": (1, 1, bk, hd),
         "v_block": (1, 1, bk, hd_v),
-        "o_block": (1, group, hd_v),
+        "o_block": (1, 1, group, hd_v),
         "group": group,
         "block_k": bk,
         "num_kv_blocks": nk,
@@ -86,9 +94,9 @@ def decode_grid_spec(B: int, Hq: int, Hkv: int, W: int, hd: int, hd_v: int,
     }
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
+def _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, window, chunk, n_kv, scale):
-    ik = pl.program_id(2)
+    b, ik = pl.program_id(0), pl.program_id(2)
 
     @pl.when(ik == 0)
     def _init():
@@ -96,11 +104,11 @@ def _decode_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)                      # (group, hd)
+    q = q_ref[0, 0].astype(jnp.float32)                   # (group, hd)
     k = k_ref[0, 0].astype(jnp.float32)                   # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)                   # (bk, hd_v)
-    qpos = qpos_ref[0]                                    # scalar
-    kpos = kpos_ref[0]                                    # (bk,)
+    qpos = qpos_ref[b]                                    # SMEM scalar
+    kpos = kpos_ref[0, 0]                                 # (1, bk)
 
     # (group, bk) scores: contract hd without materializing k^T
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -110,21 +118,27 @@ def _decode_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
         ok &= kpos > qpos - window
     if chunk is not None:
         ok &= (kpos // chunk) == (qpos // chunk)
-    s = jnp.where(ok[None, :], s, NEG_INF)
+    s = jnp.where(ok, s, NEG_INF)
 
-    m_prev = m_ref[...]                                   # (group,)
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                                   # (group, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
         p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ik == n_kv - 1)
     def _finalize():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-                    ).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                       ).astype(o_ref.dtype)
+
+
+def _scratch(group: int, hd_v: int):
+    return [pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((group, 1), jnp.float32),
+            pltpu.VMEM((group, hd_v), jnp.float32)]
 
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -147,36 +161,37 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     kernel = functools.partial(_decode_kernel, window=window, chunk=chunk,
                                n_kv=nk, scale=1.0 / math.sqrt(hd))
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=spec["grid"],
         in_specs=[
             # q/o blocks cover the whole GQA group of kv head h
-            pl.BlockSpec(spec["q_block"], lambda b, h, ik: (b, h, 0)),
+            pl.BlockSpec(spec["q_block"], lambda b, h, ik, qp: (b, h, 0, 0)),
             # k/v blocks carry ONE kv head: read once per (b, h, ik)
-            pl.BlockSpec(spec["k_block"], lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec(spec["v_block"], lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1,), lambda b, h, ik: (b,)),
-            pl.BlockSpec((1, bk), lambda b, h, ik: (b, ik)),
+            pl.BlockSpec(spec["k_block"], lambda b, h, ik, qp: (b, h, ik, 0)),
+            pl.BlockSpec(spec["v_block"], lambda b, h, ik, qp: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, 1, bk), lambda b, h, ik, qp: (b, ik, 0, 0)),
         ],
-        out_specs=pl.BlockSpec(spec["o_block"], lambda b, h, ik: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, hd_v), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group, hd_v), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec(spec["o_block"],
+                               lambda b, h, ik, qp: (b, h, 0, 0)),
+        scratch_shapes=_scratch(group, hd_v),
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, hd_v), q.dtype),
         interpret=interpret,
-    )(q, k, v, q_pos, k_pos)
-    return out
+    )(q_pos.astype(jnp.int32), q.reshape(B, Hkv, group, hd), k, v,
+      k_pos.reshape(B, nk, 1, bk))
+    return out.reshape(B, Hq, hd_v)
 
 
-def _paged_decode_kernel(tbl_ref, q_ref, k_ref, v_ref, qpos_ref, kpos_ref,
+def _paged_decode_kernel(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, kpos_ref,
                          o_ref, m_ref, l_ref, acc_ref, *, window, chunk,
                          n_kv, scale):
     # identical math to the contiguous kernel — the block table only moves
     # WHICH physical page the k/v BlockSpecs DMA'd in (see index maps)
-    _decode_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
+    _decode_kernel(qpos_ref, q_ref, k_ref, v_ref, kpos_ref, o_ref,
                    m_ref, l_ref, acc_ref, window=window, chunk=chunk,
                    n_kv=n_kv, scale=scale)
 
@@ -195,11 +210,11 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
     positions (-1 = empty). Returns (B, Hq, hd_v).
 
     The grid is the contiguous kernel's (B, Hkv, nk) with nk = M logical
-    pages; the block table rides in as a scalar-prefetch operand so the
-    k/v index maps can chase it — each physical page is still read from
-    HBM exactly once per (batch, kv head) GQA group. Unmapped logical
-    pages resolve to the trash page and are masked by their -1 logical
-    positions, so the running softmax never sees them.
+    pages; the block table and q_pos ride in as scalar-prefetch operands
+    so the k/v index maps can chase the table — each physical page is
+    still read from HBM exactly once per (batch, kv head) GQA group.
+    Unmapped logical pages resolve to the trash page and are masked by
+    their -1 logical positions, so the running softmax never sees them.
     """
     B, Hq, hd = q.shape
     Hkv, P1, ps, _ = k_pages.shape
@@ -218,30 +233,30 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
                                chunk=chunk, n_kv=M,
                                scale=1.0 / math.sqrt(hd))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=spec["grid"],
         in_specs=[
-            pl.BlockSpec(spec["q_block"], lambda b, h, ik, tbl: (b, h, 0)),
+            pl.BlockSpec(spec["q_block"],
+                         lambda b, h, ik, tbl, qp: (b, h, 0, 0)),
             # k/v blocks are ONE physical page of ONE kv head, located by
             # chasing the prefetched block table
-            pl.BlockSpec(spec["k_block"],
-                         lambda b, h, ik, tbl: (h, page_of(b, ik, tbl), 0, 0)),
-            pl.BlockSpec(spec["v_block"],
-                         lambda b, h, ik, tbl: (h, page_of(b, ik, tbl), 0, 0)),
-            pl.BlockSpec((1,), lambda b, h, ik, tbl: (b,)),
-            pl.BlockSpec((1, ps), lambda b, h, ik, tbl: (b, ik)),
+            pl.BlockSpec(spec["k_block"], lambda b, h, ik, tbl, qp:
+                         (h, page_of(b, ik, tbl), 0, 0)),
+            pl.BlockSpec(spec["v_block"], lambda b, h, ik, tbl, qp:
+                         (h, page_of(b, ik, tbl), 0, 0)),
+            pl.BlockSpec((1, 1, 1, ps),
+                         lambda b, h, ik, tbl, qp: (b, ik, 0, 0)),
         ],
         out_specs=pl.BlockSpec(spec["o_block"],
-                               lambda b, h, ik, tbl: (b, h, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group, hd_v), jnp.float32),
-        ],
+                               lambda b, h, ik, tbl, qp: (b, h, 0, 0)),
+        scratch_shapes=_scratch(group, hd_v),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, hd_v), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, hd_v), q.dtype),
         interpret=interpret,
-    )(block_tbl, q, k_pages, v_pages, q_pos, k_pos)
+    )(block_tbl.astype(jnp.int32), q_pos.astype(jnp.int32),
+      q.reshape(B, Hkv, group, hd), k_pages, v_pages,
+      k_pos.reshape(B, M, 1, ps))
+    return out.reshape(B, Hq, hd_v)

@@ -11,6 +11,10 @@ lives in VMEM scratch and persists across kv steps; the output block is
 written on the last kv step. Block shapes default to (128, 128) q x kv
 tiles with the full head dim — MXU-aligned (hd is 64/128 in all assigned
 configs) and well under VMEM (~(2*bq*hd + 2*bk*hd + bq*bk) * 4B ~ 0.4 MB).
+Mosaic wants the last two dims of a block to be (8k, 128k) or the
+array's own, so q_pos is viewed as (B, Sq, 1) (a (bq, 1) column per q
+block), k_pos as (B, nk, 1, bk) (a full (1, bk) row per kv block), and
+the running softmax state is kept as (bq, 1) columns.
 """
 from __future__ import annotations
 
@@ -51,26 +55,25 @@ def _flash_kernel(q_ref, k_ref, v_ref, qpos_ref, kpos_ref, o_ref,
     q = q_ref[0, 0].astype(jnp.float32)                 # (bq, hd)
     k = k_ref[0, 0].astype(jnp.float32)                 # (bk, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    qpos = qpos_ref[0]                                   # (bq,)
-    kpos = kpos_ref[0]                                   # (bk,)
+    qpos = qpos_ref[0]                                   # (bq, 1)
+    kpos = kpos_ref[0, 0]                                # (1, bk)
 
-    s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
-    ok = _mask(qpos[:, None], kpos[None, :], window, chunk)
-    s = jnp.where(ok, s, NEG_INF)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    s = jnp.where(_mask(qpos, kpos, window, chunk), s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.exp(s - m_new[:, None])
+    m_prev = m_ref[...]                                  # (bq, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jnp.dot(
+    l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jnp.dot(
         p, v, preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ik == n_kv - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)[:, None]
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                        ).astype(o_ref.dtype)
 
 
@@ -109,17 +112,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                          lambda b, h, iq, ik: (b, h // group, ik, 0)),
             pl.BlockSpec((1, 1, bk, hd),
                          lambda b, h, iq, ik: (b, h // group, ik, 0)),
-            pl.BlockSpec((1, bq), lambda b, h, iq, ik: (b, iq)),
-            pl.BlockSpec((1, bk), lambda b, h, iq, ik: (b, ik)),
+            pl.BlockSpec((1, bq, 1), lambda b, h, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((1, 1, 1, bk), lambda b, h, iq, ik: (b, ik, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, hd),
                                lambda b, h, iq, ik: (b, h, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hq, nq * bq, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, q_pos, k_pos)
+    )(q, k, v, q_pos.reshape(B, nq * bq, 1), k_pos.reshape(B, nk, 1, bk))
     return out[:, :, :Sq]

@@ -102,8 +102,6 @@ def parse_collectives(hlo_text: str, pod_size: int = 0) -> CollectiveStats:
 
 def extract_cost(compiled) -> Dict[str, float]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     return {
         "flops": float(ca.get("flops", 0.0)),
         "bytes": float(ca.get("bytes accessed", 0.0)),

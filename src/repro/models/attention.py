@@ -479,7 +479,12 @@ def self_attention(p: Dict, cfg, x: jax.Array, positions: jax.Array,
     ``k_pos <= q_pos`` mask gives in-chunk causality for free.
     """
     q, k, v = _qkv(p, cfg, x, positions, qk_norm="q_norm" in p)
-    use_kernel = cfg.attn_impl != "ref" and uniform_gqa_group(cfg) is not None
+    use_kernel = cfg.attn_impl != "ref"
+    if use_kernel and uniform_gqa_group(cfg) is None:
+        raise ValueError(
+            f"{cfg.name}: attn_impl={cfg.attn_impl!r} needs a uniform GQA "
+            "q->kv head map, which this head padding does not give; use "
+            "attn_impl='ref'")
     if mode in ("decode", "chunk"):
         assert cache is not None
         paged = "k_pages" in cache

@@ -30,26 +30,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-try:                                    # jax >= 0.6: top-level export
-    from jax import shard_map as _shard_map
-except ImportError:                     # jax 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kw):
-    """Version-compat wrapper: newer jax renamed check_rep -> check_vma."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-    except TypeError:
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-            return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-        raise
-
 
 from repro.models import layers as L
 from repro.models.moe import load_balance_loss, router_topk, router_z_loss

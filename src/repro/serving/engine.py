@@ -121,9 +121,10 @@ def _chunk_prefill_fn(model, params, caches, tokens, mask, slots, key, *,
     ``sharing`` additionally privatizes (copy-on-write) any page the chunk
     writes that is mapped with refcount > 1 — only possible when the slot
     adopted a shared prefix covering its whole prompt and now recomputes
-    the last prompt token for first-token logits. Returns the slots'
-    block-table rows too, so the host can register the prompt's pages in
-    the prefix index at the last chunk without an extra sync."""
+    the last prompt token for first-token logits. Returns the chunk's
+    last-valid-token logits (n, vocab) and the slots' block-table rows
+    too, so the host can register the prompt's pages in the prefix index
+    at the last chunk without an extra sync."""
     nv = mask.sum(axis=1).astype(jnp.int32)              # (n,) valid tokens
     t0 = caches["t"][slots]
     start_pg = (t0 + page_size - 1) // page_size
@@ -138,8 +139,9 @@ def _chunk_prefill_fn(model, params, caches, tokens, mask, slots, key, *,
     view = paged.gather_slot_view(caches, slots)
     last, view = model.prefill_chunk(params, view, tokens, mask)
     caches = paged.scatter_slot_view(caches, view, slots)
-    first = sampling.sample(last[:, :vocab], key, temperature)
-    return first, caches["paged"]["tbl"][slots], caches
+    logits = last[:, :vocab]
+    first = sampling.sample(logits, key, temperature)
+    return first, logits, caches["paged"]["tbl"][slots], caches
 
 
 _PREFILL = jax.jit(_prefill_fn, static_argnums=(0,),
@@ -420,6 +422,10 @@ class ServingEngine:
         self.decode_chunks = 0                       # device->host syncs
         self.prefill_batches = 0
         self.prefill_chunks = 0                      # chunked-prefill launches
+        # rid -> logits at the last prompt position (chunked path), kept on
+        # device; None = not recorded. Set to {} to compare attention paths
+        # (chip_smoke.py) without an extra host sync per request.
+        self.prompt_logits: Optional[Dict[int, jax.Array]] = None
         self.peak_active = 0                         # max concurrent requests
         # host mirror of which slots are ARMED for decode (device
         # state["active"] at chunk boundaries): in chunked mode a slot is
@@ -1403,7 +1409,7 @@ class ServingEngine:
             tokens[i, :len(piece)] = piece
             mask[i, :len(piece)] = 1
         slots_a = jnp.asarray([slot for _, slot, _, _ in packed], jnp.int32)
-        first, tbl_rows, self.caches = _CHUNK_PREFILL(
+        first, logits, tbl_rows, self.caches = _CHUNK_PREFILL(
             self.model, self.params, self.caches, jnp.asarray(tokens),
             jnp.asarray(mask), slots_a, self._next_key(),
             vocab=self.model.cfg.vocab, temperature=self.cfg.temperature,
@@ -1447,6 +1453,8 @@ class ServingEngine:
             req, slot, _, _ = packed[i]
             if self.sharing:
                 self._register_prefix(req, slot, rows_h[i])
+            if self.prompt_logits is not None:
+                self.prompt_logits[req.rid] = logits[i]
             # chunking changes the schedule, not the modeled energy:
             # attribute the request's prefill at its true prompt length
             # exactly once, so modeled J/token is invariant to the

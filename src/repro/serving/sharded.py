@@ -55,6 +55,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.energy import decode_counts, migrate_counts, step_energy
@@ -66,7 +67,6 @@ from repro.core.scheduler import (FleetSlice, marginal_request_g,
 from repro.launch.mesh import make_serving_mesh
 from repro.models import Model
 from repro.models.costing import workload_of
-from repro.models.moe_sharded import shard_map
 from repro.serving import paged, preempt, sampling
 from repro.serving.engine import (EngineConfig, ServingEngine,
                                   _chunk_prefill_fn, _prefill_phase_counts,
@@ -114,7 +114,7 @@ def _fused_steps_fleet(model, mesh, params, caches, cur_tokens, state, keys,
 def _chunk_prefill_fleet(model, mesh, params, caches, tokens, mask, slots,
                          keys, *, vocab, temperature, page_size, sharing):
     def body(params, caches, tokens, mask, slots, keys):
-        first, rows, caches = _chunk_prefill_fn(
+        first, _, rows, caches = _chunk_prefill_fn(
             model, params, _lane(caches), tokens[0], mask[0], slots[0],
             keys[0], vocab=vocab, temperature=temperature,
             page_size=page_size, sharing=sharing)
@@ -383,22 +383,23 @@ class ShardedServingEngine:
 
         # ---- device state: stack the single-shard tree S-wide and place
         # every leaf leading-axis over the mesh (attention.py declares the
-        # logical axes; rules.py resolves them)
+        # logical axes; rules.py resolves them). The stack runs inside one
+        # program whose outputs are already sharded, so each device
+        # materializes only its own lane — an eager broadcast would first
+        # stage all S copies of the pool on one device.
         def stack(tree):
             return jax.tree_util.tree_map(
                 lambda x: jnp.broadcast_to(x[None], (S,) + x.shape), tree)
 
-        caches = stack(probe.caches)
-        self.caches = jax.device_put(caches,
-                                     serving_shardings(self.mesh, caches))
+        def place(tree):
+            out = serving_shardings(self.mesh, jax.eval_shape(stack, tree))
+            return jax.jit(stack, out_shardings=out)(tree)
+
+        self.caches = place(probe.caches)
         self.params = jax.device_put(params, jax.tree_util.tree_map(
             lambda _: NamedSharding(self.mesh, P()), params))
-        cur = stack(probe.cur_tokens)
-        self.cur_tokens = jax.device_put(
-            cur, serving_shardings(self.mesh, cur))
-        state = stack(probe.state)
-        self.state = jax.device_put(state,
-                                    serving_shardings(self.mesh, state))
+        self.cur_tokens = place(probe.cur_tokens)
+        self.state = place(probe.state)
         del probe
 
         # ---- host mirrors, one entry per shard

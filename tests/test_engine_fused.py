@@ -144,10 +144,10 @@ def test_decode_grid_is_grouped_by_kv_head():
                                 block_k=32)
     assert spec["grid"] == (2, 2, 2)           # (B, Hkv, nk) — NOT (B, Hq, nk)
     assert spec["group"] == 4
-    assert spec["q_block"] == (1, 4, 16)       # whole group rides one program
+    assert spec["q_block"] == (1, 1, 4, 16)    # whole group rides one program
     assert spec["k_block"] == (1, 1, 32, 16)   # one KV head per program
     assert spec["v_block"] == (1, 1, 32, 16)
-    assert spec["o_block"] == (1, 4, 16)
+    assert spec["o_block"] == (1, 1, 4, 16)
     assert spec["kv_block_hbm_reads_per_group"] == 1
     # total KV-block fetches = grid size = B * Hkv * nk (Hq-independent)
     b, h, nk = spec["grid"]

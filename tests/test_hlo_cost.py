@@ -12,12 +12,6 @@ def _compiled(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _xla_cost(c):
-    """cost_analysis() returns a list of dicts on jax 0.4.x, a dict later."""
-    ca = c.cost_analysis()
-    return ca[0] if isinstance(ca, (list, tuple)) else ca
-
-
 def test_dot_flops_match_xla_no_scan():
     def fn(a, b):
         return (a @ b).sum()
@@ -28,7 +22,7 @@ def test_dot_flops_match_xla_no_scan():
     got = hlo_cost.analyze(c.as_text())
     want = 2 * 128 * 256 * 64
     assert got.flops == pytest.approx(want, rel=0.02)
-    xla = _xla_cost(c)
+    xla = c.cost_analysis()
     assert got.dot_flops_uncorrected == pytest.approx(
         float(xla["flops"]), rel=0.05)
 
@@ -47,7 +41,7 @@ def test_scan_trip_count_multiplies():
     per_iter = 2 * 32 * 64 * 64
     assert got.flops == pytest.approx(7 * per_iter, rel=0.05)
     # XLA's own count misses the trip count
-    assert float(_xla_cost(c)["flops"]) == pytest.approx(per_iter,
+    assert float(c.cost_analysis()["flops"]) == pytest.approx(per_iter,
                                                               rel=0.05)
 
 
@@ -76,7 +70,7 @@ def test_bytes_proxy_reasonable():
     b = jax.ShapeDtypeStruct((512, 512), jnp.float32)
     c = _compiled(fn, a, b)
     got = hlo_cost.analyze(c.as_text())
-    xla_bytes = float(_xla_cost(c)["bytes accessed"])
+    xla_bytes = float(c.cost_analysis()["bytes accessed"])
     assert got.bytes == pytest.approx(xla_bytes, rel=1.0)  # same magnitude
 
 

@@ -45,8 +45,9 @@ def test_kernel_path_parity(window, chunk, gae):
 
 
 def test_kernel_path_falls_back_for_nonuniform_heads():
-    """llama4-style padded-q mapping is non-uniform: kernel path must fall
-    back to ref (and still be correct)."""
+    """llama4-style padded-q mapping is non-uniform: the kernels cannot
+    take it, so asking for them raises instead of silently running the
+    ref path (the ref path itself still serves the config)."""
     cfg = ModelConfig(
         name="kp2", family="dense", n_layers=1, d_model=40, n_heads=5,
         n_kv_heads=1, d_ff=64, vocab=64, dtype="float32", head_dim=8,
@@ -57,6 +58,6 @@ def test_kernel_path_falls_back_for_nonuniform_heads():
     m_ref, m_ker, params = pair(cfg)
     tokens = jax.random.randint(jax.random.PRNGKey(2), (1, 6), 0, 64)
     a, _, _ = m_ref.forward(params, tokens, mode="train")
-    b, _, _ = m_ker.forward(params, tokens, mode="train")
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
-                               atol=1e-5)
+    assert np.isfinite(np.asarray(a)).all()
+    with pytest.raises(ValueError, match="uniform GQA"):
+        m_ker.forward(params, tokens, mode="train")

@@ -82,10 +82,10 @@ def test_extended_decode_grid_spec(page_size):
     assert spec["paged"] is True
     assert spec["grid"] == (B, Hkv, M)          # (B, Hkv, nk) — NOT Hq
     assert spec["group"] == 4
-    assert spec["q_block"] == (1, 4, hd)        # whole GQA group rides along
+    assert spec["q_block"] == (1, 1, 4, hd)     # whole GQA group rides along
     assert spec["k_block"] == (1, 1, page_size, hd)   # ONE page, ONE kv head
     assert spec["v_block"] == (1, 1, page_size, hd)
-    assert spec["o_block"] == (1, 4, hd)
+    assert spec["o_block"] == (1, 1, 4, hd)
     assert spec["num_kv_blocks"] == M
     assert spec["page_size"] == page_size
     assert spec["kv_pool_shape"] == (Hkv, P + 1, page_size)  # +1 trash page
