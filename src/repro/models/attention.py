@@ -477,8 +477,14 @@ def self_attention(p: Dict, cfg, x: jax.Array, positions: jax.Array,
     paged decode path widened to S queries: the chunk's keys are written
     first, then the queries score the slot's whole logical history — the
     ``k_pos <= q_pos`` mask gives in-chunk causality for free.
+
+    Named scopes for the profiler (metadata only): ``attn_proj`` holds the
+    q/k/v and output projections; ``attn_decode`` (decode mode) and
+    ``attn_prefill`` (chunk mode) hold everything between them — the KV
+    write, the cache read, the scores, the softmax and the value sum.
     """
-    q, k, v = _qkv(p, cfg, x, positions, qk_norm="q_norm" in p)
+    with jax.named_scope("attn_proj"):
+        q, k, v = _qkv(p, cfg, x, positions, qk_norm="q_norm" in p)
     use_kernel = cfg.attn_impl != "ref"
     if use_kernel and uniform_gqa_group(cfg) is None:
         raise ValueError(
@@ -486,99 +492,10 @@ def self_attention(p: Dict, cfg, x: jax.Array, positions: jax.Array,
             "q->kv head map, which this head padding does not give; use "
             "attn_impl='ref'")
     if mode in ("decode", "chunk"):
-        assert cache is not None
-        paged = "k_pages" in cache
-        if mode == "chunk":
-            assert paged and page_tbl is not None, \
-                "chunked prefill needs a paged cache + block table"
-            cache = paged_chunk_write(cache, page_tbl, k, v, positions)
-        elif paged:
-            assert page_tbl is not None, "paged decode cache needs page_tbl"
-            cache = paged_decode_write(cache, page_tbl, k, v)
-        else:
-            cache = decode_write_cache(cache, k, v)
-        gp = uniform_gqa_group(cfg)
-        if use_kernel:
-            from repro.kernels import ops as KOPS
-            if mode == "chunk":
-                # (B, Hkv, max_pages) GQA grid with the whole (group, S)
-                # query chunk per program — one HBM read per page per
-                # group, independent of chunk size
-                out = jnp.moveaxis(
-                    KOPS.chunked_prefill_attention(
-                        jnp.moveaxis(q, 1, 2), cache["k_pages"],
-                        cache["v_pages"], page_tbl, positions,
-                        cache["pos_ids"], window=layer_window,
-                        chunk=layer_chunk, impl=cfg.attn_impl),
-                    1, 2)                           # (B, S, Hq, hd_v)
-            elif paged:
-                # same (B, Hkv, nk) grid; the scalar-prefetched block table
-                # redirects each program's page DMA — still one HBM read
-                # per (batch, kv head, logical page)
-                out = KOPS.paged_decode_attention(
-                    q[:, 0], cache["k_pages"], cache["v_pages"], page_tbl,
-                    positions[:, 0], cache["pos_ids"],
-                    window=layer_window, chunk=layer_chunk,
-                    impl=cfg.attn_impl)[:, None]    # (B, 1, Hq, hd)
-            else:
-                # (B, Hkv, W, hd) is the grouped-decode kernel's native
-                # layout: its (B, Hkv, nk) grid reads each KV block once
-                # per GQA group
-                out = KOPS.decode_attention(
-                    q[:, 0],                        # (B, Hq, hd)
-                    jnp.moveaxis(cache["k"], 1, 2),  # (B, Hkv, W, hd)
-                    jnp.moveaxis(cache["v"], 1, 2),
-                    positions[:, 0], cache["pos_ids"],
-                    window=layer_window, chunk=layer_chunk,
-                    impl=cfg.attn_impl)[:, None]    # (B, 1, Hq, hd)
-        else:
-            bias = self_attn_bias(positions, cache["pos_ids"],
-                                  layer_window, layer_chunk)[:, None]
-            if gp is not None:
-                # grouped attention: contract against the shard-local kv
-                # head directly — no head-expansion gather of the cache
-                # (perf: the take-based expansion all-gathers the cache
-                # over the model axis; EXPERIMENTS.md SSPerf H3). Same
-                # math on either layout; only the cache einsum signature
-                # differs: a paged pool is gathered page-granular into the
-                # head-major (Hkv, B, W, hd) view ("kbsd") and contracted
-                # in place — garbage rows carry logical pos -1 and mask to
-                # exactly-zero softmax weight, so this is bit-identical to
-                # the contiguous slot pool ("bskd").
-                if paged:
-                    kk = gather_pages_hb(cache["k_pages"], page_tbl)
-                    vv = gather_pages_hb(cache["v_pages"], page_tbl)
-                    kv_layout, n_kv = "kbsd", kk.shape[0]
-                else:
-                    kk = shard(cache["k"], "batch", "kv_seq", "kv_heads",
-                               None)
-                    vv = shard(cache["v"], "batch", "kv_seq", "kv_heads",
-                               None)
-                    kv_layout, n_kv = "bskd", kk.shape[2]
-                B_, Sq_ = q.shape[0], q.shape[1]
-                hd = q.shape[-1]
-                qg = q.reshape(B_, Sq_, n_kv, gp, hd)
-                scale = 1.0 / math.sqrt(hd)
-                # bf16 x bf16 -> f32 accumulation in the dot itself (MXU-
-                # native; avoids materializing an f32 copy of the 32k
-                # cache — H3 iter 3)
-                sc = jnp.einsum(f"bqkgd,{kv_layout}->bkgqs", qg, kk,
-                                preferred_element_type=jnp.float32) * scale
-                sc = sc + bias[:, :, None]
-                w = jax.nn.softmax(sc, axis=-1).astype(q.dtype)
-                out = jnp.einsum(f"bkgqs,{kv_layout}->bqkgd", w, vv)
-                out = out.reshape(B_, Sq_, -1, hd)
-            else:
-                if paged:
-                    ck = gather_pages(cache["k_pages"], page_tbl)
-                    cv = gather_pages(cache["v_pages"], page_tbl)
-                else:
-                    ck, cv = cache["k"], cache["v"]
-                kk = _expand_kv(cfg, ck)
-                vv = _expand_kv(cfg, cv)
-                kk = shard(kk, "batch", "kv_seq", "heads", None)
-                vv = shard(vv, "batch", "kv_seq", "heads", None)
-                out = _direct_attention(q, kk, vv, bias)
+        with jax.named_scope(_CACHED_SCOPE[mode]):
+            out, cache = _cached_attention(cfg, q, k, v, positions,
+                                           layer_window, layer_chunk, cache,
+                                           mode, page_tbl, use_kernel)
     else:
         if mode == "prefill":
             cache = prefill_write_cache(cache, k, v, positions)
@@ -595,10 +512,115 @@ def self_attention(p: Dict, cfg, x: jax.Array, positions: jax.Array,
             vv = _expand_kv(cfg, v)
             out = attention_core(q, kk, vv, positions, positions,
                                  layer_window, layer_chunk)
-    out = shard(out, "batch", "seq", "heads", None)
-    flat = out.reshape(out.shape[:-2] + (-1,))
-    y = jnp.einsum("...h,hd->...d", flat, p["wo"])
+    with jax.named_scope("attn_proj"):
+        out = shard(out, "batch", "seq", "heads", None)
+        flat = out.reshape(out.shape[:-2] + (-1,))
+        y = jnp.einsum("...h,hd->...d", flat, p["wo"])
     return y, cache
+
+
+_CACHED_SCOPE = {"decode": "attn_decode", "chunk": "attn_prefill"}
+
+
+def _cached_attention(cfg, q, k, v, positions, layer_window, layer_chunk,
+                      cache, mode, page_tbl, use_kernel):
+    """Decode or chunk mode of ``self_attention`` between the projections:
+    write the new keys and values, then attend over the slot's history.
+    Returns (out (B, S, Hq, hd), cache)."""
+    assert cache is not None
+    paged = "k_pages" in cache
+    if mode == "chunk":
+        assert paged and page_tbl is not None, \
+            "chunked prefill needs a paged cache + block table"
+        cache = paged_chunk_write(cache, page_tbl, k, v, positions)
+    elif paged:
+        assert page_tbl is not None, "paged decode cache needs page_tbl"
+        cache = paged_decode_write(cache, page_tbl, k, v)
+    else:
+        cache = decode_write_cache(cache, k, v)
+    gp = uniform_gqa_group(cfg)
+    if use_kernel:
+        from repro.kernels import ops as KOPS
+        if mode == "chunk":
+            # (B, Hkv, max_pages) GQA grid with the whole (group, S)
+            # query chunk per program — one HBM read per page per
+            # group, independent of chunk size
+            out = jnp.moveaxis(
+                KOPS.chunked_prefill_attention(
+                    jnp.moveaxis(q, 1, 2), cache["k_pages"],
+                    cache["v_pages"], page_tbl, positions,
+                    cache["pos_ids"], window=layer_window,
+                    chunk=layer_chunk, impl=cfg.attn_impl),
+                1, 2)                           # (B, S, Hq, hd_v)
+        elif paged:
+            # same (B, Hkv, nk) grid; the scalar-prefetched block table
+            # redirects each program's page DMA — still one HBM read
+            # per (batch, kv head, logical page)
+            out = KOPS.paged_decode_attention(
+                q[:, 0], cache["k_pages"], cache["v_pages"], page_tbl,
+                positions[:, 0], cache["pos_ids"],
+                window=layer_window, chunk=layer_chunk,
+                impl=cfg.attn_impl)[:, None]    # (B, 1, Hq, hd)
+        else:
+            # (B, Hkv, W, hd) is the grouped-decode kernel's native
+            # layout: its (B, Hkv, nk) grid reads each KV block once
+            # per GQA group
+            out = KOPS.decode_attention(
+                q[:, 0],                        # (B, Hq, hd)
+                jnp.moveaxis(cache["k"], 1, 2),  # (B, Hkv, W, hd)
+                jnp.moveaxis(cache["v"], 1, 2),
+                positions[:, 0], cache["pos_ids"],
+                window=layer_window, chunk=layer_chunk,
+                impl=cfg.attn_impl)[:, None]    # (B, 1, Hq, hd)
+    else:
+        bias = self_attn_bias(positions, cache["pos_ids"],
+                              layer_window, layer_chunk)[:, None]
+        if gp is not None:
+            # grouped attention: contract against the shard-local kv
+            # head directly — no head-expansion gather of the cache
+            # (perf: the take-based expansion all-gathers the cache
+            # over the model axis; EXPERIMENTS.md SSPerf H3). Same
+            # math on either layout; only the cache einsum signature
+            # differs: a paged pool is gathered page-granular into the
+            # head-major (Hkv, B, W, hd) view ("kbsd") and contracted
+            # in place — garbage rows carry logical pos -1 and mask to
+            # exactly-zero softmax weight, so this is bit-identical to
+            # the contiguous slot pool ("bskd").
+            if paged:
+                kk = gather_pages_hb(cache["k_pages"], page_tbl)
+                vv = gather_pages_hb(cache["v_pages"], page_tbl)
+                kv_layout, n_kv = "kbsd", kk.shape[0]
+            else:
+                kk = shard(cache["k"], "batch", "kv_seq", "kv_heads",
+                           None)
+                vv = shard(cache["v"], "batch", "kv_seq", "kv_heads",
+                           None)
+                kv_layout, n_kv = "bskd", kk.shape[2]
+            B_, Sq_ = q.shape[0], q.shape[1]
+            hd = q.shape[-1]
+            qg = q.reshape(B_, Sq_, n_kv, gp, hd)
+            scale = 1.0 / math.sqrt(hd)
+            # bf16 x bf16 -> f32 accumulation in the dot itself (MXU-
+            # native; avoids materializing an f32 copy of the 32k
+            # cache — H3 iter 3)
+            sc = jnp.einsum(f"bqkgd,{kv_layout}->bkgqs", qg, kk,
+                            preferred_element_type=jnp.float32) * scale
+            sc = sc + bias[:, :, None]
+            w = jax.nn.softmax(sc, axis=-1).astype(q.dtype)
+            out = jnp.einsum(f"bkgqs,{kv_layout}->bqkgd", w, vv)
+            out = out.reshape(B_, Sq_, -1, hd)
+        else:
+            if paged:
+                ck = gather_pages(cache["k_pages"], page_tbl)
+                cv = gather_pages(cache["v_pages"], page_tbl)
+            else:
+                ck, cv = cache["k"], cache["v"]
+            kk = _expand_kv(cfg, ck)
+            vv = _expand_kv(cfg, cv)
+            kk = shard(kk, "batch", "kv_seq", "heads", None)
+            vv = shard(vv, "batch", "kv_seq", "heads", None)
+            out = _direct_attention(q, kk, vv, bias)
+    return out, cache
 
 
 # --------------------------------------------------------------------------

@@ -143,12 +143,20 @@ def block_cache_init(cfg, kind: str, batch: int, max_len: int,
 # --------------------------------------------------------------------------
 
 
+def _mlp(p, x):
+    """The dense feed-forward, under the profiler scope ``mlp``."""
+    with jax.named_scope("mlp"):
+        return L.swiglu(p, x)
+
+
 def _moe_dispatch(p, cfg, x):
     """Sharded (shard_map all_to_all) MoE when a multi-device mesh context is
-    active; dense capacity-dispatch otherwise (CPU unit tests)."""
-    if MOES.use_sharded_moe(cfg):
-        return MOES.moe_ffn_sharded(p, cfg, x)
-    return MOE.moe_ffn(p, cfg, x)
+    active; dense capacity-dispatch otherwise (CPU unit tests). Scoped
+    ``mlp`` like the dense feed-forward."""
+    with jax.named_scope("mlp"):
+        if MOES.use_sharded_moe(cfg):
+            return MOES.moe_ffn_sharded(p, cfg, x)
+        return MOE.moe_ffn(p, cfg, x)
 
 
 def _res(cfg, x, delta):
@@ -190,7 +198,7 @@ def block_apply(p: Dict, cfg, kind: str, ctx: LayerCtx, x: jax.Array,
         if kind == "moe":
             f, aux = _moe_dispatch(p["moe"], cfg, h2)
         else:
-            f = L.swiglu(p["mlp"], h2)
+            f = _mlp(p["mlp"], h2)
         return _res(cfg, x, f), cache, aux
 
     if kind == "parallel":                       # StableLM-2: parallel residual
@@ -199,7 +207,7 @@ def block_apply(p: Dict, cfg, kind: str, ctx: LayerCtx, x: jax.Array,
                                     window, chunk, cache, ctx.mode,
                                     page_tbl=ctx.page_tbl)
         h2 = _norm3(p["ln2"], x, cfg.norm_eps)
-        f = L.swiglu(p["mlp"], h2)
+        f = _mlp(p["mlp"], h2)
         return _res(cfg, x, a + f), cache, aux
 
     if kind in ("mla", "mla_moe"):
@@ -211,7 +219,7 @@ def block_apply(p: Dict, cfg, kind: str, ctx: LayerCtx, x: jax.Array,
         if kind == "mla_moe":
             f, aux = _moe_dispatch(p["moe"], cfg, h2)
         else:
-            f = L.swiglu(p["mlp"], h2)
+            f = _mlp(p["mlp"], h2)
         return _res(cfg, x, f), cache, aux
 
     if kind == "mamba2":
@@ -233,11 +241,13 @@ def block_apply(p: Dict, cfg, kind: str, ctx: LayerCtx, x: jax.Array,
         x = _res(cfg, x, a)
         cat2 = jnp.concatenate([x, ctx.emb_orig], axis=-1)
         h2 = _norm3(p["ln2"], cat2, cfg.norm_eps)
-        g = jnp.einsum("...d,df->...f", h2, p["mlp"]["w_gate"])
-        u = jnp.einsum("...d,df->...f", h2, p["mlp"]["w_up"])
-        f = jnp.einsum("...f,fd->...d",
-                       jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u,
-                       p["mlp"]["w_down"])
+        with jax.named_scope("mlp"):
+            g = jnp.einsum("...d,df->...f", h2, p["mlp"]["w_gate"])
+            u = jnp.einsum("...d,df->...f", h2, p["mlp"]["w_up"])
+            f = jnp.einsum(
+                "...f,fd->...d",
+                jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u,
+                p["mlp"]["w_down"])
         return _res(cfg, x, f), cache, aux
 
     if kind == "cross":                           # VLM gated cross-attn layer
@@ -247,7 +257,7 @@ def block_apply(p: Dict, cfg, kind: str, ctx: LayerCtx, x: jax.Array,
         a = A.cross_attention(p["attn"], cfg, h, cache)
         x = _res(cfg, x, a)
         h2 = _norm3(p["ln2"], x, cfg.norm_eps)
-        f = L.swiglu(p["mlp"], h2)
+        f = _mlp(p["mlp"], h2)
         f = f * jnp.tanh(p["gate_mlp"].astype(jnp.float32)).astype(f.dtype)
         return _res(cfg, x, f), cache, aux
 
@@ -264,7 +274,7 @@ def block_apply(p: Dict, cfg, kind: str, ctx: LayerCtx, x: jax.Array,
                        p["attn"]["wo"])
         x = _res(cfg, x, a)
         h2 = _norm3(p["ln2"], x, cfg.norm_eps)
-        return _res(cfg, x, L.swiglu(p["mlp"], h2)), None, aux
+        return _res(cfg, x, _mlp(p["mlp"], h2)), None, aux
 
     if kind == "dec":                             # enc-dec decoder layer
         h = _norm3(p["ln1"], x, cfg.norm_eps)
@@ -282,7 +292,7 @@ def block_apply(p: Dict, cfg, kind: str, ctx: LayerCtx, x: jax.Array,
         a2 = A.cross_attention(p["xattn"], cfg, hx, xc)
         x = _res(cfg, x, a2)
         h2 = _norm3(p["ln2"], x, cfg.norm_eps)
-        x = _res(cfg, x, L.swiglu(p["mlp"], h2))
+        x = _res(cfg, x, _mlp(p["mlp"], h2))
         new_cache = None
         if kv_cache is not None:
             new_cache = dict(kv_cache)

@@ -298,8 +298,9 @@ class Model:
                   else jnp.full((Bsz,), S, jnp.int32))
             idx = jnp.maximum(nv - 1, 0)
             x = jnp.take_along_axis(x, idx[:, None, None], axis=1)  # (B,1,d)
-        x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        logits = L.logits(params["lm_head"], params["embed"], cfg, x)
+        with jax.named_scope("lm_head"):
+            x = L.rmsnorm(params["final_norm"], x, cfg.norm_eps)
+            logits = L.logits(params["lm_head"], params["embed"], cfg, x)
         if mode == "decode" and new_caches is not None:
             new_caches["t"] = new_caches["t"] + 1
         elif mode == "chunk" and new_caches is not None:

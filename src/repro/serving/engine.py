@@ -91,7 +91,7 @@ from repro.core.intensity import Region, ci_trace
 from repro.core.meter import CarbonMeter
 from repro.models import Model
 from repro.models.costing import workload_of
-from repro.serving import paged, preempt, sampling
+from repro.serving import paged, preempt, sampling, trace
 from repro.serving.faults import FaultError, InjectedFault
 from repro.serving.request import Request, Response
 
@@ -140,7 +140,8 @@ def _chunk_prefill_fn(model, params, caches, tokens, mask, slots, key, *,
     last, view = model.prefill_chunk(params, view, tokens, mask)
     caches = paged.scatter_slot_view(caches, view, slots)
     logits = last[:, :vocab]
-    first = sampling.sample(logits, key, temperature)
+    with jax.named_scope("sample"):
+        first = sampling.sample(logits, key, temperature)
     return first, logits, caches["paged"]["tbl"][slots], caches
 
 
@@ -518,6 +519,11 @@ class ServingEngine:
             # can never underflow mid-flight)
             self.free_pages = self.num_pages
             self.peak_pages_reserved = 0
+            # per-quantum sums of pages reserved and pages mapped, added at
+            # the end of every step(): their ratio over an interval is the
+            # share of the reservation that holds KV
+            self.reserved_page_quanta = 0
+            self.mapped_page_quanta = 0
             self._slot_pages = [0] * B
             self._resv: Dict[int, int] = {}
 
@@ -905,6 +911,10 @@ class ServingEngine:
         charged once by its popper and credited once by its last holder."""
         if not self.paged or not slots:
             return
+        with trace.span("release"):
+            self._release_pages(slots)
+
+    def _release_pages(self, slots: List[int]) -> None:
         mask = np.zeros((self.cfg.max_batch,), bool)
         mask[slots] = True
         self.caches = dict(self.caches)
@@ -1130,7 +1140,8 @@ class ServingEngine:
         device stack): a request that doesn't fit the REMAINING pool keeps
         waiting; one whose prompt alone can never fit the TOTAL pool is
         rejected outright instead of admitted-and-failed mid-prefill.
-        Returns the number of requests admitted.
+        Returns the number of requests admitted; the ``repro.admit`` span
+        carries their rids.
 
         With ``preemption`` on, a shortfall (no free slot, or not enough
         free pages) for the queue head triggers eviction of ONE armed
@@ -1140,10 +1151,17 @@ class ServingEngine:
         fault site: an injected fault returns every reservation and puts
         the takes back at the queue head, so a failed admission launch is
         indistinguishable from one that never ran."""
+        with trace.span("admit") as sp:
+            take = self._admit_requests()
+            trace.tag(sp, "rids", (r.rid for r in take))
+        return len(take)
+
+    def _admit_requests(self) -> List[Request]:
+        """The admission pass of ``_admit``; returns the requests taken."""
         if self._over_budget() and self.active > 0:
-            return 0                   # defer admissions; drain active work
+            return []                  # defer admissions; drain active work
         if self.queue and self.paged and not self._site_ready("page_alloc"):
-            return 0                   # backing off a faulted reservation
+            return []                  # backing off a faulted reservation
         free = self.free_slots()
         take: List[Request] = []
         share: Dict[int, Tuple[int, List[int], int]] = {}
@@ -1202,13 +1220,13 @@ class ServingEngine:
                     self.free_pages += self._resv.pop(req.rid)
                     self.queue.appendleft(req)
                 self._site_failed("page_alloc")
-                return 0
+                return []
             self._site_ok("page_alloc")
         if self.paged:
             self.peak_pages_reserved = max(self.peak_pages_reserved,
                                            self.num_pages - self.free_pages)
         if not take:
-            return 0
+            return []
         if self.chunked:
             # quantum scheduler: admission only claims the slot + pages and
             # queues the request for chunk-at-a-time prefill — no prefill
@@ -1241,7 +1259,7 @@ class ServingEngine:
                     # so the pages never transit refcount zero
                     if req.rid in self._pins:
                         self._drop_pin(req.rid)
-            return len(take)
+            return take
         # bucket prompts: padded power-of-two buckets when the model masks
         # pad tokens exactly; exact-length groups otherwise (rwkv/enc-dec).
         # Buckets are clamped to max_len — past that the cache ring must
@@ -1261,7 +1279,7 @@ class ServingEngine:
         for bucket, reqs in groups.items():
             slots = [next(slot_iter) for _ in reqs]
             self._prefill_group(bucket, reqs, slots)
-        return len(take)
+        return take
 
     def _prefill_group(self, bucket: int, reqs: List[Request],
                        slots: List[int]) -> None:
@@ -1392,28 +1410,34 @@ class ServingEngine:
         if not self._site_ready("prefill_chunk"):
             return 0                   # backing off a faulted chunk launch
         C = self.cfg.prefill_chunk
-        packed = pack_chunks(self._prefilling, C, self.cfg.prefill_pack)
-        try:
-            self._inject("prefill_chunk")
-        except InjectedFault:
-            # the launch never ran: the packed requests are still at the
-            # head of ``_prefilling`` with their prefill_pos untouched —
-            # the SAME chunks relaunch after backoff, nothing is dropped
-            self._site_failed("prefill_chunk")
-            return 0
-        self._site_ok("prefill_chunk")
-        n = len(packed)
-        tokens = np.zeros((n, C), np.int32)
-        mask = np.zeros((n, C), np.int32)
-        for i, (_, _, _, piece) in enumerate(packed):
-            tokens[i, :len(piece)] = piece
-            mask[i, :len(piece)] = 1
-        slots_a = jnp.asarray([slot for _, slot, _, _ in packed], jnp.int32)
-        first, logits, tbl_rows, self.caches = _CHUNK_PREFILL(
-            self.model, self.params, self.caches, jnp.asarray(tokens),
-            jnp.asarray(mask), slots_a, self._next_key(),
-            vocab=self.model.cfg.vocab, temperature=self.cfg.temperature,
-            page_size=self.cfg.page_size, sharing=self.sharing)
+        with trace.span("prefill.prepare"):
+            packed = pack_chunks(self._prefilling, C, self.cfg.prefill_pack)
+            try:
+                self._inject("prefill_chunk")
+            except InjectedFault:
+                # the launch never ran: the packed requests are still at
+                # the head of ``_prefilling`` with their prefill_pos
+                # untouched — the SAME chunks relaunch after backoff,
+                # nothing is dropped
+                self._site_failed("prefill_chunk")
+                return 0
+            self._site_ok("prefill_chunk")
+            n = len(packed)
+            tokens = np.zeros((n, C), np.int32)
+            mask = np.zeros((n, C), np.int32)
+            for i, (_, _, _, piece) in enumerate(packed):
+                tokens[i, :len(piece)] = piece
+                mask[i, :len(piece)] = 1
+        with trace.span("prefill.launch") as sp:
+            trace.tag(sp, "rids", (req.rid for req, _, _, _ in packed))
+            slots_a = jnp.asarray([slot for _, slot, _, _ in packed],
+                                  jnp.int32)
+            first, logits, tbl_rows, self.caches = _CHUNK_PREFILL(
+                self.model, self.params, self.caches, jnp.asarray(tokens),
+                jnp.asarray(mask), slots_a, self._next_key(),
+                vocab=self.model.cfg.vocab,
+                temperature=self.cfg.temperature,
+                page_size=self.cfg.page_size, sharing=self.sharing)
         self.prefill_chunks += 1
         finished: List[int] = []
         for i, (req, slot, pos0, piece) in enumerate(packed):
@@ -1443,9 +1467,22 @@ class ServingEngine:
             self._prefilling.popleft()
         # last chunks: the sampled tokens are the requests' first emissions
         # — ONE host sync for every request finishing in this launch
-        first_h, rows_h = jax.device_get((first, tbl_rows))
+        with trace.span("prefill.fetch"):
+            first_h, rows_h = jax.device_get((first, tbl_rows))
         first_h, rows_h = np.asarray(first_h), np.asarray(rows_h)
         self.prefill_batches += 1      # one first-token host sync
+        with trace.span("prefill.finish") as sp:
+            trace.tag(sp, "rids", (packed[i][0].rid for i in finished))
+            self._finish_prefill(packed, finished, first_h, rows_h, logits)
+        return 1
+
+    def _finish_prefill(self, packed, finished: List[int],
+                        first_h: np.ndarray, rows_h: np.ndarray,
+                        logits: jax.Array) -> None:
+        """Requests whose last chunk just ran: register their prompt pages
+        in the prefix index, meter their prefill, record their first
+        token, and arm their slots for decode (or release a slot whose
+        budget the first token spent)."""
         now = time.perf_counter()
         released: List[int] = []
         arm: List[Tuple[int, int, int, int]] = []   # slot, tok, budget, eos
@@ -1508,7 +1545,6 @@ class ServingEngine:
                 jnp.asarray([a[2] for a in arm], jnp.int32),
                 jnp.asarray([a[3] for a in arm], jnp.int32))
         self._release_slots(released)
-        return 1
 
     # --------------------------------------------------------------- decode
     def _decode_chunk(self, max_steps: int) -> bool:
@@ -1533,17 +1569,30 @@ class ServingEngine:
                    if self._slot_armed[s]]
         n = min(self.cfg.sync_every, max(max(budgets), 1),
                 max(max_steps - self._steps, 1))
-        (self.caches, self.cur_tokens, self.state, tok_mat,
-         emit_mat) = _FUSED_STEPS(
-            self.model, self.params, self.caches, self.cur_tokens,
-            self.state, self._next_key(), n_steps=n,
-            temperature=self.cfg.temperature,
-            page_size=self.cfg.page_size if self.paged else 0,
-            freeze_inactive=self.chunked)
-        tok_h, emit_h = jax.device_get((tok_mat, emit_mat))
-        now = time.perf_counter()
+        with trace.span("decode.launch"):
+            (self.caches, self.cur_tokens, self.state, tok_mat,
+             emit_mat) = _FUSED_STEPS(
+                self.model, self.params, self.caches, self.cur_tokens,
+                self.state, self._next_key(), n_steps=n,
+                temperature=self.cfg.temperature,
+                page_size=self.cfg.page_size if self.paged else 0,
+                freeze_inactive=self.chunked)
+        with trace.span("decode.fetch"):
+            tok_h, emit_h = jax.device_get((tok_mat, emit_mat))
         self.decode_chunks += 1
         self.peak_active = max(self.peak_active, self.active)
+        with trace.span("decode.emit"):
+            released = self._emit_decoded(n, tok_h, emit_h)
+        # page reclamation at the chunk boundary (finished slots coasted on
+        # the trash page since their done flag rose mid-chunk)
+        self._release_slots(released)
+        return True
+
+    def _emit_decoded(self, n: int, tok_h: np.ndarray,
+                      emit_h: np.ndarray) -> List[int]:
+        """Hand each micro-step's emitted tokens to their responses and
+        meter the step; returns the slots whose request finished."""
+        now = time.perf_counter()
         released: List[int] = []
         for i in range(n):
             act = emit_h[i]
@@ -1578,10 +1627,7 @@ class ServingEngine:
                     self._slot_deadline[slot] = None
                     released.append(int(slot))
             self._steps += 1
-        # page reclamation at the chunk boundary (finished slots coasted on
-        # the trash page since their done flag rose mid-chunk)
-        self._release_slots(released)
-        return True
+        return released
 
     def step(self, max_steps: int = 10_000) -> bool:
         """Run ONE scheduling quantum: deadline sweep (when any request
@@ -1590,13 +1636,47 @@ class ServingEngine:
         server drives this directly so it can interleave submissions and
         stream tokens between quanta."""
         self._quantum += 1
-        released = self._release_deferred() if self.deferred else 0
-        if self._has_deadlines:
-            self._sweep_deadlines()
-        admitted = self._admit()
-        chunks = self._prefill_quantum() if self.chunked else 0
-        decoded = self._decode_chunk(max_steps) if self.decoding else False
+        with trace.quantum(self._quantum) as q:
+            released = self._release_deferred() if self.deferred else 0
+            if self._has_deadlines:
+                self._sweep_deadlines()
+            admitted = self._admit()
+            chunks = self._prefill_quantum() if self.chunked else 0
+            decoded = (self._decode_chunk(max_steps) if self.decoding
+                       else False)
+            if self.paged:
+                self._count_pages(q)
         return bool(released or admitted or chunks or decoded)
+
+    def _count_pages(self, q) -> None:
+        """Add this quantum's pages reserved (admission's worst case) and
+        pages mapped (handed out by the device allocator) to their running
+        totals; a traced quantum carries both as arguments."""
+        reserved = self.num_pages - self.free_pages
+        mapped = self.mapped_pages()
+        self.reserved_page_quanta += reserved
+        self.mapped_page_quanta += mapped
+        if trace.enabled():
+            q.set_metadata(reserved_pages=reserved, mapped_pages=mapped)
+
+    def mapped_pages(self) -> int:
+        """Pages the device allocator has handed out (``num_pages`` minus
+        its free-stack top), mirrored on the host without a device sync:
+        each live slot's written pages less those it adopted, plus every
+        page in the prefix index once (adopted, registered or pinned) less
+        those a live slot registered, which its own count holds."""
+        ps = self.cfg.page_size
+        n = 0
+        for s, rid in enumerate(self.slot_rid):
+            if rid >= 0:
+                written = (int(self._slot_ctx[s]) if self._slot_armed[s]
+                           else self._slot_req[s].prefill_pos)
+                n += -(-written // ps)
+        if self.sharing:
+            n += len(self._page_ref)
+            n -= sum(len(v) for v in self._slot_shared_in.values())
+            n -= sum(len(v) for v in self._slot_own_idx.values())
+        return n
 
     def _resolve_stall(self) -> None:
         """The quantum made no progress, nothing is armed, no fault site
@@ -1683,6 +1763,8 @@ class ServingEngine:
                 "pages_total": self.num_pages,
                 "peak_pages_reserved": self.peak_pages_reserved,
                 "free_pages": self.free_pages,
+                "reserved_page_quanta": self.reserved_page_quanta,
+                "mapped_page_quanta": self.mapped_page_quanta,
                 # provisioned KV rows actually backing peak load — feeds
                 # the embodied-carbon memory model (ROADMAP: paged pool)
                 "peak_kv_rows_reserved":

@@ -78,6 +78,10 @@ def fused_decode_steps(model, params, caches, cur_tokens: jax.Array,
     refcounted-allocator suite (tests/test_prefix_sharing.py) asserts no
     write ever lands in a page with refcount > 1.
 
+    Profiler scopes: the model's own (``attn_decode``, ``attn_proj``,
+    ``mlp``, ``lm_head``), and ``sample`` for sampling and the emission
+    masks.
+
     ``freeze_inactive`` (chunked-prefill engines) restores inactive slots'
     write cursors to their pre-step values after each micro-step
     (``paged.freeze_inactive_cursors``): a slot parked mid-chunked-prefill
@@ -102,13 +106,14 @@ def fused_decode_steps(model, params, caches, cur_tokens: jax.Array,
                                                         active)
         else:
             logits, caches = model.decode_step(params, caches, toks)
-        nxt = sample(logits[:, :vocab], k_i, temperature)
-        nxt = jnp.where(active, nxt, toks[:, 0])
-        emitted = active
-        budget = budget - emitted.astype(jnp.int32)
-        done = emitted & ((budget <= 0) |
-                          ((state["eos"] >= 0) & (nxt == state["eos"])))
-        active = active & ~done
+        with jax.named_scope("sample"):
+            nxt = sample(logits[:, :vocab], k_i, temperature)
+            nxt = jnp.where(active, nxt, toks[:, 0])
+            emitted = active
+            budget = budget - emitted.astype(jnp.int32)
+            done = emitted & ((budget <= 0) |
+                              ((state["eos"] >= 0) & (nxt == state["eos"])))
+            active = active & ~done
         return (caches, nxt[:, None], active, budget), (nxt, emitted)
 
     (caches, cur_tokens, active, budget), (tok_mat, emit_mat) = jax.lax.scan(

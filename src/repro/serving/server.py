@@ -46,6 +46,7 @@ from __future__ import annotations
 import asyncio
 from typing import AsyncIterator, Dict, List, Optional
 
+from repro.serving import trace
 from repro.serving.faults import FaultError
 from repro.serving.request import Request, Response
 
@@ -169,17 +170,18 @@ class AsyncServingServer:
         """Push newly produced tokens into every stream and close streams
         whose requests reached a terminal state. Called with the lock held
         (or during error teardown)."""
-        for rid, q in self._streams.items():
-            resp = self.engine.responses.get(rid)
-            if resp is None or self._ended[rid]:
-                continue
-            sent = self._sent[rid]
-            for tok in resp.tokens[sent:]:
-                q.put_nowait(tok)
-            self._sent[rid] = len(resp.tokens)
-            terminal = (resp.finished or force_end
-                        or resp.finish_reason in ("timeout", "error"))
-            if terminal:
-                self._ended[rid] = True
-                q.put_nowait(_END)
-                self._finished[rid].set()
+        with trace.span("server.pump"):
+            for rid, q in self._streams.items():
+                resp = self.engine.responses.get(rid)
+                if resp is None or self._ended[rid]:
+                    continue
+                sent = self._sent[rid]
+                for tok in resp.tokens[sent:]:
+                    q.put_nowait(tok)
+                self._sent[rid] = len(resp.tokens)
+                terminal = (resp.finished or force_end
+                            or resp.finish_reason in ("timeout", "error"))
+                if terminal:
+                    self._ended[rid] = True
+                    q.put_nowait(_END)
+                    self._finished[rid].set()

@@ -67,7 +67,7 @@ from repro.core.scheduler import (FleetSlice, marginal_request_g,
 from repro.launch.mesh import make_serving_mesh
 from repro.models import Model
 from repro.models.costing import workload_of
-from repro.serving import paged, preempt, sampling
+from repro.serving import paged, preempt, sampling, trace
 from repro.serving.engine import (EngineConfig, ServingEngine,
                                   _chunk_prefill_fn, _prefill_phase_counts,
                                   pack_chunks)
@@ -643,6 +643,10 @@ class ShardedServingEngine:
         single-device engine, applied within each shard)."""
         if not pairs:
             return
+        with trace.span("release"):
+            self._release_pages(pairs)
+
+    def _release_pages(self, pairs: List[Tuple[int, int]]) -> None:
         mask = np.zeros((self.S, self.B), bool)
         for s, b in pairs:
             mask[s, b] = True
@@ -1537,20 +1541,27 @@ class ShardedServingEngine:
         ONE fleet-wide begin program. Never-fits requests (prompt + budget
         exceeding a shard's whole pool or block table) are rejected up
         front — per-shard pools mean per-shard capacity limits."""
+        with trace.span("admit") as sp:
+            take = self._admit_requests()
+            trace.tag(sp, "rids", (r.rid for r in take))
+        return len(take)
+
+    def _admit_requests(self) -> List[Request]:
+        """The admission pass of ``_admit``; returns the requests taken."""
         if self._over_budget() and self.active > 0:
-            return 0
+            return []
         if self.queue:
             # the fleet's reservation pass sits behind the same
             # ``page_alloc`` fault site as the single-device engine's; the
             # injection point is BEFORE any claim, so a fault needs no
             # rollback — the whole pass simply didn't run this quantum
             if not self._site_ready("page_alloc"):
-                return 0
+                return []
             try:
                 self._inject("page_alloc")
             except InjectedFault:
                 self._site_failed("page_alloc")
-                return 0
+                return []
             self._site_ok("page_alloc")
         admitted: List[Tuple[Request, int, int]] = []
         adoptions: List[Tuple[Request, int, int, Tuple]] = []
@@ -1594,7 +1605,7 @@ class ShardedServingEngine:
             if self.sharing:
                 adoptions.append((req, s, slot, share))
         if not admitted:
-            return 0
+            return []
         # one fleet-wide slot-reset program: per-shard slot lists padded
         # with the sentinel id B (out-of-range scatters drop -> idle lanes
         # run the same program and write nothing)
@@ -1614,7 +1625,7 @@ class ShardedServingEngine:
                 # pinned prefix through the ordinary index increfs
                 if req.rid in self._pins:
                     self._drop_pin(req.rid)
-        return len(admitted)
+        return [req for req, _, _ in admitted]
 
     def _adopt_prefix(self, req: Request, s: int, slot: int, n_pg: int,
                       phys: List[int], first_tok: int) -> None:
@@ -1657,33 +1668,39 @@ class ShardedServingEngine:
         if not self._site_ready("prefill_chunk"):
             return 0                   # backing off a faulted chunk launch
         C = self.cfg.prefill_chunk
-        packs = [pack_chunks(self._prefilling[s], C, self.cfg.prefill_pack)
-                 for s in range(self.S)]
-        n = max(len(p) for p in packs)
-        if n == 0:
-            return 0
-        try:
-            self._inject("prefill_chunk")
-        except InjectedFault:
-            # nothing launched: every shard's packed requests are still at
-            # the head of its _prefilling deque, prefill_pos untouched
-            self._site_failed("prefill_chunk")
-            return 0
-        self._site_ok("prefill_chunk")
-        tokens = np.zeros((self.S, n, C), np.int32)
-        mask = np.zeros((self.S, n, C), np.int32)
-        slots = np.full((self.S, n), self.B, np.int32)
-        for s, pk in enumerate(packs):
-            for i, (_, slot, _, piece) in enumerate(pk):
-                tokens[s, i, :len(piece)] = piece
-                mask[s, i, :len(piece)] = 1
-                slots[s, i] = slot
-        first, rows, self.caches = _CHUNK_FLEET(
-            self.model, self.mesh, self.params, self.caches,
-            jnp.asarray(tokens), jnp.asarray(mask), jnp.asarray(slots),
-            self._next_keys(), vocab=self.model.cfg.vocab,
-            temperature=self.cfg.temperature,
-            page_size=self.cfg.page_size, sharing=self.sharing)
+        with trace.span("prefill.prepare"):
+            packs = [pack_chunks(self._prefilling[s], C,
+                                 self.cfg.prefill_pack)
+                     for s in range(self.S)]
+            n = max(len(p) for p in packs)
+            if n == 0:
+                return 0
+            try:
+                self._inject("prefill_chunk")
+            except InjectedFault:
+                # nothing launched: every shard's packed requests are
+                # still at the head of its _prefilling deque, prefill_pos
+                # untouched
+                self._site_failed("prefill_chunk")
+                return 0
+            self._site_ok("prefill_chunk")
+            tokens = np.zeros((self.S, n, C), np.int32)
+            mask = np.zeros((self.S, n, C), np.int32)
+            slots = np.full((self.S, n), self.B, np.int32)
+            for s, pk in enumerate(packs):
+                for i, (_, slot, _, piece) in enumerate(pk):
+                    tokens[s, i, :len(piece)] = piece
+                    mask[s, i, :len(piece)] = 1
+                    slots[s, i] = slot
+        with trace.span("prefill.launch") as sp:
+            trace.tag(sp, "rids", (req.rid for pk in packs
+                                   for req, _, _, _ in pk))
+            first, rows, self.caches = _CHUNK_FLEET(
+                self.model, self.mesh, self.params, self.caches,
+                jnp.asarray(tokens), jnp.asarray(mask), jnp.asarray(slots),
+                self._next_keys(), vocab=self.model.cfg.vocab,
+                temperature=self.cfg.temperature,
+                page_size=self.cfg.page_size, sharing=self.sharing)
         self.prefill_chunks += 1
         finished: List[Tuple[int, int]] = []   # (shard, row)
         for s, pk in enumerate(packs):
@@ -1706,9 +1723,19 @@ class ShardedServingEngine:
         if not finished:
             return 1                   # intermediate chunks: no host sync
         # ONE first-token fetch for every request finishing fleet-wide
-        first_h, rows_h = jax.device_get((first, rows))
+        with trace.span("prefill.fetch"):
+            first_h, rows_h = jax.device_get((first, rows))
         first_h, rows_h = np.asarray(first_h), np.asarray(rows_h)
         self.prefill_batches += 1
+        with trace.span("prefill.finish") as sp:
+            trace.tag(sp, "rids", (packs[s][i][0].rid for s, i in finished))
+            self._finish_prefill(packs, finished, first_h, rows_h)
+        return 1
+
+    def _finish_prefill(self, packs, finished: List[Tuple[int, int]],
+                        first_h: np.ndarray, rows_h: np.ndarray) -> None:
+        """Requests whose last chunk just ran, fleet-wide: prefix index,
+        metering, first tokens, one fleet-wide arm, releases."""
         now = time.perf_counter()
         arm: List[Tuple[int, int, int, int, int]] = []
         released: List[Tuple[int, int]] = []
@@ -1767,7 +1794,6 @@ class ShardedServingEngine:
                 jnp.asarray(slots_a), jnp.asarray(firsts),
                 jnp.asarray(budgets), jnp.asarray(eos_ids))
         self._release_slots(released)
-        return 1
 
     # --------------------------------------------------------------- decode
     def _decode_chunk(self, max_steps: int) -> bool:
@@ -1789,16 +1815,28 @@ class ShardedServingEngine:
                    if self._slot_armed[s][b]]
         n = min(self.cfg.sync_every, max(max(budgets), 1),
                 max(max_steps - self._steps, 1))
-        (self.caches, self.cur_tokens, self.state, tok_mat,
-         emit_mat) = _FUSED_FLEET(
-            self.model, self.mesh, self.params, self.caches,
-            self.cur_tokens, self.state, self._next_keys(), n_steps=n,
-            temperature=self.cfg.temperature,
-            page_size=self.cfg.page_size)
-        tok_h, emit_h = jax.device_get((tok_mat, emit_mat))
-        now = time.perf_counter()
+        with trace.span("decode.launch"):
+            (self.caches, self.cur_tokens, self.state, tok_mat,
+             emit_mat) = _FUSED_FLEET(
+                self.model, self.mesh, self.params, self.caches,
+                self.cur_tokens, self.state, self._next_keys(), n_steps=n,
+                temperature=self.cfg.temperature,
+                page_size=self.cfg.page_size)
+        with trace.span("decode.fetch"):
+            tok_h, emit_h = jax.device_get((tok_mat, emit_mat))
         self.decode_chunks += 1
         self.peak_active = max(self.peak_active, self.active)
+        with trace.span("decode.emit"):
+            released = self._emit_decoded(n, tok_h, emit_h)
+        self._release_slots(released)
+        return True
+
+    def _emit_decoded(self, n: int, tok_h: np.ndarray,
+                      emit_h: np.ndarray) -> List[Tuple[int, int]]:
+        """Hand each micro-step's emitted tokens to their responses and
+        meter each shard's step; returns the finished (shard, slot)
+        pairs."""
+        now = time.perf_counter()
         released: List[Tuple[int, int]] = []
         for i in range(n):
             emitted_any = False
@@ -1838,8 +1876,7 @@ class ShardedServingEngine:
                         released.append((s, int(b)))
             if emitted_any:
                 self._steps += 1
-        self._release_slots(released)
-        return True
+        return released
 
     def _resolve_stall(self) -> None:
         """Fleet twin of ``ServingEngine._resolve_stall``: spill pins or
@@ -1870,6 +1907,10 @@ class ShardedServingEngine:
         wall time (summing per-shard times would run the diurnal day S
         times too fast)."""
         self._quantum += 1
+        with trace.quantum(self._quantum):
+            return self._fleet_quantum(max_steps)
+
+    def _fleet_quantum(self, max_steps: int) -> bool:
         ev0 = self.shard_down_events
         mig0 = self.migrations
         if self.faults is not None:
