@@ -429,6 +429,58 @@ def gather_pages(pages: jax.Array, tbl: jax.Array) -> jax.Array:
     return jnp.moveaxis(gather_pages_hb(pages, tbl), 0, 2)
 
 
+def _pool_key_positions(tbl: jax.Array, pos_ids: jax.Array,
+                        n_pages: int) -> jax.Array:
+    """(B, (P+1)*ps) logical position of every pool row as each slot sees
+    it, -1 where the row's page is not in that slot's block table.
+
+    tbl: (B, M); pos_ids: (B, M*ps) LOGICAL; n_pages = P+1, trash included.
+    One scatter inverts the table (physical page -> the slot's logical
+    page, -1 unmapped; no table maps the trash page), then one
+    page-granular gather reads the slot's logical positions through it.
+    A page shared by several slots maps into each through that slot's own
+    row; stale rows of a recycled page and rows past a slot's length read
+    ``pos_ids`` -1.
+    """
+    B, M = tbl.shape
+    ps = pos_ids.shape[1] // M
+    slot = jnp.arange(B)[:, None]
+    logical = jnp.broadcast_to(jnp.arange(M, dtype=jnp.int32), (B, M))
+    dst = jnp.where(tbl < 0, n_pages, tbl)           # unmapped -> dropped
+    inv = jnp.full((B, n_pages), -1, jnp.int32).at[slot, dst].set(
+        logical, mode="drop")
+    kpos = pos_ids.reshape(B, M, ps)[slot, jnp.maximum(inv, 0)]
+    kpos = jnp.where(inv[:, :, None] >= 0, kpos, -1)  # (B, P+1, ps)
+    return kpos.reshape(B, n_pages * ps)
+
+
+def _pool_decode_attention(q, cache, page_tbl, positions, window, chunk,
+                           gp):
+    """Decode attention of one query per slot against the flat page pool,
+    masked by the block table: per kv head one (B*g) x R score matmul and
+    one value matmul, with no per-slot copy of the pool. The same softmax
+    over the same keys as the gathered view (masked rows add exact zeros),
+    at the same precision: bf16 operands, f32 scores and accumulation."""
+    kf, _, _ = _flat_rows(cache["k_pages"])
+    vf, _, _ = _flat_rows(cache["v_pages"])
+    kpos = _pool_key_positions(page_tbl, cache["pos_ids"],
+                               cache["k_pages"].shape[1])
+    bias = self_attn_bias(positions, kpos, window, chunk)   # (B, 1, R)
+    B, _, Hq, hd = q.shape
+    n_kv = kf.shape[0]
+    # (Hkv, B*g, hd): the queries of one kv head are one matmul's rows
+    qk = jnp.swapaxes(q.reshape(B, n_kv, gp, hd), 0, 1).reshape(
+        n_kv, B * gp, hd)
+    scale = 1.0 / math.sqrt(hd)
+    sc = jnp.einsum("kqd,krd->kqr", qk, kf,
+                    preferred_element_type=jnp.float32) * scale
+    sc = sc.reshape(n_kv, B, gp, -1) + bias[None]
+    w = jax.nn.softmax(sc, axis=-1).astype(q.dtype)
+    out = jnp.einsum("kqr,krd->kqd", w.reshape(n_kv, B * gp, -1), vf)
+    return jnp.swapaxes(out.reshape(n_kv, B, gp, hd), 0, 1).reshape(
+        B, 1, Hq, hd)
+
+
 # --------------------------------------------------------------------------
 # GQA self-attention block
 # --------------------------------------------------------------------------
@@ -572,6 +624,11 @@ def _cached_attention(cfg, q, k, v, positions, layer_window, layer_chunk,
                 positions[:, 0], cache["pos_ids"],
                 window=layer_window, chunk=layer_chunk,
                 impl=cfg.attn_impl)[:, None]    # (B, 1, Hq, hd)
+    elif paged and mode == "decode" and gp is not None:
+        # one query a slot: score the pool in place, masked by the block
+        # table, instead of gathering every slot's W rows out of it
+        out = _pool_decode_attention(q, cache, page_tbl, positions,
+                                     layer_window, layer_chunk, gp)
     else:
         bias = self_attn_bias(positions, cache["pos_ids"],
                               layer_window, layer_chunk)[:, None]
@@ -581,11 +638,11 @@ def _cached_attention(cfg, q, k, v, positions, layer_window, layer_chunk,
             # (perf: the take-based expansion all-gathers the cache
             # over the model axis; EXPERIMENTS.md SSPerf H3). Same
             # math on either layout; only the cache einsum signature
-            # differs: a paged pool is gathered page-granular into the
-            # head-major (Hkv, B, W, hd) view ("kbsd") and contracted
-            # in place — garbage rows carry logical pos -1 and mask to
-            # exactly-zero softmax weight, so this is bit-identical to
-            # the contiguous slot pool ("bskd").
+            # differs: a paged pool (chunk mode here) is gathered
+            # page-granular into the head-major (Hkv, B, W, hd) view
+            # ("kbsd") and contracted in place — garbage rows carry
+            # logical pos -1 and mask to exactly-zero softmax weight, so
+            # this is bit-identical to the contiguous slot pool ("bskd").
             if paged:
                 kk = gather_pages_hb(cache["k_pages"], page_tbl)
                 vv = gather_pages_hb(cache["v_pages"], page_tbl)

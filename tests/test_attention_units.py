@@ -120,3 +120,114 @@ def test_flash_vs_direct_mixed_value_dim():
     b = A._flash_attention(q, k, v, pos, pos, None, None)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
                                atol=1e-5)
+
+
+# ---- paged decode: pool-direct scoring against the gathered view -------
+
+PD_B, PD_M, PD_PS, PD_HKV, PD_G, PD_HD = 3, 4, 4, 2, 2, 8
+PD_W = PD_M * PD_PS                              # 16 logical rows per slot
+
+# (block table, cached length) per slot and the pool's page count; the
+# new token of each slot lands at position ``length``. A slot whose next
+# row has no page coasts: its write lands in the trash page.
+PD_LAYOUTS = {
+    # page 2 holds logical page 0 of slots 0 and 1 (a shared prefix)
+    "shared_page": ([[2, 5, -1, -1], [2, 7, -1, -1], [1, -1, -1, -1]],
+                    [6, 5, 2], 11),
+    # page 9 was recycled: its rows past slot 0's length hold stale keys
+    "recycled_stale_rows": ([[4, 9, -1, -1], [0, 3, 8, -1],
+                             [10, -1, -1, -1]], [5, 9, 1], 11),
+    # unmapped entries between and after mapped pages
+    "unmapped_entries": ([[3, -1, 6, -1], [-1, -1, -1, 0],
+                          [7, 1, -1, -1]], [2, 0, 6], 11),
+    # slot 2 holds no page: its write and its reads hit the trash page
+    "slot_without_pages": ([[6, 2, -1, -1], [5, -1, -1, -1],
+                            [-1, -1, -1, -1]], [7, 3, 0], 11),
+    # two coasting slots write the trash page in the same step
+    "trash_writes": ([[8, 4, 0, -1], [-1, -1, -1, -1], [-1, -1, -1, -1]],
+                     [10, 4, 9], 11),
+    # the equal-memory pool, (B x M + 1) pages, more rows than B x W
+    "equal_memory_pool": ([[2, 5, -1, -1], [2, 7, 11, -1], [1, 0, 3, 4]],
+                          [6, 9, 13], PD_B * PD_M),
+}
+
+
+def _pd_case(name, dtype):
+    tbl, length, pages = PD_LAYOUTS[name]
+    tbl = np.asarray(tbl, np.int32)
+    length = np.asarray(length, np.int32)
+    lp = np.arange(PD_W)
+    mapped = tbl[:, lp // PD_PS] >= 0
+    pos_ids = np.where((lp[None] < length[:, None]) & mapped, lp[None], -1)
+    ks = jax.random.split(jax.random.PRNGKey(sum(map(ord, name))), 5)
+    pool = (PD_HKV, pages + 1, PD_PS, PD_HD)
+    cache = {"k_pages": jax.random.normal(ks[0], pool).astype(dtype),
+             "v_pages": jax.random.normal(ks[1], pool).astype(dtype),
+             "pos_ids": jnp.asarray(pos_ids, jnp.int32),
+             "length": jnp.asarray(length)}
+    q = jax.random.normal(ks[2], (PD_B, 1, PD_HKV * PD_G, PD_HD)).astype(dtype)
+    k = jax.random.normal(ks[3], (PD_B, 1, PD_HKV, PD_HD)).astype(dtype)
+    v = jax.random.normal(ks[4], (PD_B, 1, PD_HKV, PD_HD)).astype(dtype)
+    # a slot is live when the page of its new row is mapped
+    live = tbl[np.arange(PD_B), length // PD_PS] >= 0
+    return cache, jnp.asarray(tbl), q, k, v, jnp.asarray(length)[:, None], live
+
+
+def _pd_cfg():
+    from repro.models import ModelConfig
+    return ModelConfig(name="tiny-pool", family="dense", n_layers=1,
+                       d_model=PD_HKV * PD_G * PD_HD, n_heads=PD_HKV * PD_G,
+                       n_kv_heads=PD_HKV, d_ff=32, vocab=64,
+                       dtype="float32", block_pattern=("dense",),
+                       vocab_pad_multiple=8)
+
+
+def _pd_gathered(cfg, cache, tbl, q, k, v, pos, dtype=None):
+    """The gather path: each slot's logical (W, hd) view copied out of the
+    pool into a contiguous cache, decoded there (optionally upcast)."""
+    contig = {"k": A.gather_pages(cache["k_pages"], tbl),
+              "v": A.gather_pages(cache["v_pages"], tbl),
+              "pos_ids": cache["pos_ids"], "length": cache["length"]}
+    if dtype is not None:
+        contig, q, k, v = jax.tree_util.tree_map(
+            lambda x: x.astype(dtype) if x.dtype == jnp.bfloat16 else x,
+            (contig, q, k, v))
+    out, _ = A._cached_attention(cfg, q, k, v, pos, None, None, contig,
+                                 "decode", None, False)
+    return np.asarray(out.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("layout", sorted(PD_LAYOUTS))
+def test_pool_direct_decode_matches_gather(layout, monkeypatch):
+    """Paged decode attention scores the page pool in place, whatever its
+    size against B x W, and equals each slot's gathered logical view on
+    every live slot: f32 to float tolerance, bf16 within the gather
+    path's own rounding. A slot with no page for its new row (a coasting
+    slot, whose token is never emitted) reads a finite value."""
+    cfg = _pd_cfg()
+    assert A.uniform_gqa_group(cfg) == PD_G
+    calls = []
+    pool_attention = A._pool_decode_attention
+    monkeypatch.setattr(A, "_pool_decode_attention",
+                        lambda *a: calls.append(1) or pool_attention(*a))
+
+    def attend(dtype):
+        cache, tbl, q, k, v, pos, live = _pd_case(layout, dtype)
+        out, _ = A._cached_attention(cfg, q, k, v, pos, None, None, cache,
+                                     "decode", tbl, False)
+        want = _pd_gathered(cfg, cache, tbl, q, k, v, pos)
+        exact = _pd_gathered(cfg, cache, tbl, q, k, v, pos, jnp.float32)
+        return np.asarray(out.astype(jnp.float32)), want, exact, live
+
+    got, want, _, live = attend(jnp.float32)
+    assert calls == [1]
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[live], want[live], rtol=1e-5, atol=1e-6)
+
+    # bf16: the two paths differ by less than the gather path's own
+    # rounding against its f32 result on the same (bf16-valued) inputs
+    got16, want16, exact, live = attend(jnp.bfloat16)
+    rounding = np.abs(want16 - exact)[live].max()
+    assert rounding > 0
+    assert np.abs(got16 - want16)[live].max() <= rounding
+    assert np.isfinite(got16).all()

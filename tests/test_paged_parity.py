@@ -61,15 +61,19 @@ def assert_pool_clean(eng):
     assert eng.free_pages == eng.num_pages
 
 
-def test_mixed_prompt_lengths_token_for_token(parts):
+@pytest.mark.parametrize("num_pages", [None, 12],
+                         ids=["equal_memory_pool", "12_pages"])
+def test_mixed_prompt_lengths_token_for_token(parts, num_pages):
     """More requests than slots, prompts across several pow2 buckets and
-    page counts; continuous batching with slot + page reuse throughout."""
+    page counts; continuous batching with slot + page reuse throughout,
+    on the equal-memory pool and on one small enough that recycled pages
+    carry stale rows into decode's scoring of the pool."""
     _, m, params = parts
     rng = np.random.default_rng(7)
     reqs = [dict(rid=i, prompt=list(rng.integers(0, 256, int(n))),
                  max_new_tokens=9)
             for i, n in enumerate((3, 5, 8, 11, 16, 21, 4, 30))]
-    eng = assert_parity(m, params, reqs)
+    eng = assert_parity(m, params, reqs, num_pages=num_pages)
     assert_pool_clean(eng)
 
 

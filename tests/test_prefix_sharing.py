@@ -136,6 +136,10 @@ def assert_pool_clean(eng):
 
 # ------------------------------------------------------------------ parity
 
+# the equal-memory pool, and one small enough that pages are recycled
+POOLS = pytest.mark.parametrize("num_pages", [None, 20],
+                                ids=["equal_memory_pool", "20_pages"])
+
 
 def test_no_common_prefix_is_inert(parts):
     """Distinct prompts: the sharing machinery must change nothing —
@@ -150,7 +154,8 @@ def test_no_common_prefix_is_inert(parts):
     assert_pool_clean(eng)
 
 
-def test_shared_system_prompt_parity_and_hits(parts):
+@POOLS
+def test_shared_system_prompt_parity_and_hits(parts, num_pages):
     """A long-lived donor plus followers repeating its 2-page system
     prompt: followers admitted after the donor registered must adopt the
     prefix (hits > 0) and still decode token-for-token."""
@@ -160,7 +165,8 @@ def test_shared_system_prompt_parity_and_hits(parts):
     reqs = [dict(rid=0, prompt=prefix + [7, 9, 11], max_new_tokens=30)]
     reqs += [dict(rid=i, prompt=prefix + list(rng.integers(0, 256, 2 + i)),
                   max_new_tokens=5) for i in range(1, 4)]
-    got, eng = assert_parity(m, params, reqs, max_batch=2)
+    got, eng = assert_parity(m, params, reqs, max_batch=2,
+                             num_pages=num_pages)
     # rid 1 rides with the donor (no index yet); rids 2-3 enter later,
     # while the donor still decodes, and hit its registered prefix
     assert eng.prefix_shared_requests >= 2
@@ -168,7 +174,8 @@ def test_shared_system_prompt_parity_and_hits(parts):
     assert_pool_clean(eng)
 
 
-def test_three_requests_share_then_diverge(parts):
+@POOLS
+def test_three_requests_share_then_diverge(parts, num_pages):
     """Chain-keyed matching: a follower matching 2 pages then diverging
     adopts exactly 2; one diverging inside page 1 adopts exactly 1 (rid 1
     rides the donor's admission wave so rids 2-3 enter one at a time
@@ -182,12 +189,14 @@ def test_three_requests_share_then_diverge(parts):
             dict(rid=1, prompt=[99, 98, 97], max_new_tokens=2),
             dict(rid=2, prompt=two_pages, max_new_tokens=5),
             dict(rid=3, prompt=one_page, max_new_tokens=5)]
-    got, eng = assert_parity(m, params, reqs, max_batch=2)
+    got, eng = assert_parity(m, params, reqs, max_batch=2,
+                             num_pages=num_pages)
     assert eng.prefix_hit_tokens == 2 * PS + PS
     assert_pool_clean(eng)
 
 
-def test_prefix_ends_mid_page_tail_is_private(parts):
+@POOLS
+def test_prefix_ends_mid_page_tail_is_private(parts, num_pages):
     """A follower whose prompt extends past the shared pages mid-page:
     only whole pages are adopted; the partial tail is computed into a
     private page (no aliased writes — the checked engine asserts it)."""
@@ -197,7 +206,8 @@ def test_prefix_ends_mid_page_tail_is_private(parts):
     reqs = [dict(rid=0, prompt=prefix + [3], max_new_tokens=40),
             dict(rid=1, prompt=prefix + [17, 19], max_new_tokens=6),
             dict(rid=2, prompt=prefix + [17, 19, 23], max_new_tokens=6)]
-    got, eng = assert_parity(m, params, reqs, max_batch=2)
+    got, eng = assert_parity(m, params, reqs, max_batch=2,
+                             num_pages=num_pages)
     assert eng.prefix_hit_tokens >= 2 * PS
     assert_pool_clean(eng)
 
