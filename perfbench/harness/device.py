@@ -40,10 +40,13 @@ def check_devices(chips: int) -> Dict:
             "count": chips}
 
 
-def memory_peak_bytes() -> Optional[int]:
+def memory_peak_bytes(chips: int = 1) -> Optional[int]:
+    """The peak bytes in use on the fullest of the first ``chips``."""
     import jax
-    stats = jax.devices()[0].memory_stats()
-    return None if stats is None else int(stats["peak_bytes_in_use"])
+    stats = [d.memory_stats() for d in jax.devices()[:chips]]
+    if any(st is None for st in stats):
+        return None
+    return max(int(st["peak_bytes_in_use"]) for st in stats)
 
 
 def enable_compile_cache(root: Path) -> str:

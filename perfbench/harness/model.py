@@ -4,64 +4,56 @@ The benchmark builds the program's ``ModelConfig`` from its own
 configuration file (not from ``repro.configs``), so a change to the
 program's presets cannot move the yardstick. Weights are random and made
 by the benchmark, on the device, in one jitted call from the seed, under
-the benchmark's own names (``canonical``). The program's parameter tree is
-assembled from the same arrays, and the reference reads the canonical
-names, so both see the same numbers and the reference takes nothing the
-program made.
+the benchmark's own names (``canonical_shapes``). The program's parameter
+tree is assembled from the same arrays, and the reference reads the
+canonical names, so both see the same numbers and the reference takes
+nothing the program made.
+
+What depends on the architecture lives in ``architectures/<name>.py``,
+loaded by the configuration file's ``architecture``; its contract is in
+``architectures/dense_decoder.py``.
 """
 from __future__ import annotations
 
-import math
-from typing import Dict
+from pathlib import Path
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from harness import spec
 from repro.models import Model, ModelConfig
+
+_LOADED: Dict[Path, object] = {}
+
+
+def architecture(cfg: Dict):
+    """The module ``architectures/<cfg["architecture"]>.py`` under the
+    benchmark's directory, loaded once per path."""
+    name = cfg["architecture"]
+    path = spec.BENCH_DIR / "architectures" / f"{name}.py"
+    if path not in _LOADED:
+        _LOADED[path] = spec.load_module(path, f"perfbench_arch_{name}")
+    return _LOADED[path]
 
 
 def dims(cfg: Dict) -> Dict[str, int]:
     """The shape numbers every counter and the reference need."""
-    d = cfg["hidden_size"]
-    hq = cfg["num_attention_heads"]
-    return {"L": cfg["num_hidden_layers"], "d": d, "hq": hq,
-            "hkv": cfg["num_key_value_heads"], "hd": d // hq,
-            "ff": cfg["intermediate_size"], "vocab": cfg["vocab_size"],
-            "vocab_pad": padded_vocab(cfg), "tied": bool(cfg["tie_word_embeddings"])}
+    return architecture(cfg).dims(cfg)
 
 
-def padded_vocab(cfg: Dict) -> int:
-    m = cfg["assumed"]["vocab_pad_multiple"]
-    return -(-cfg["vocab_size"] // m) * m
+def canonical_shapes(cfg: Dict) -> Dict[str, tuple]:
+    return architecture(cfg).canonical_shapes(cfg)
+
+
+def program_config(cfg: Dict) -> ModelConfig:
+    """The program's ModelConfig for a configuration file."""
+    return architecture(cfg).program_config(cfg)
 
 
 def dtype_of(cfg: Dict):
     return jnp.dtype(cfg["torch_dtype"])
-
-
-def program_config(cfg: Dict) -> ModelConfig:
-    """The program's ModelConfig for a dense decoder configuration file."""
-    if cfg["architecture"] != "dense_decoder":
-        raise ValueError(f"{cfg['name']}: no program mapping for "
-                         f"architecture {cfg['architecture']!r}")
-    L = cfg["num_hidden_layers"]
-    depth = cfg.get("scale_depth")
-    base = cfg.get("dim_model_base")
-    return ModelConfig(
-        name=cfg["name"], family="dense", n_layers=L,
-        d_model=cfg["hidden_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        block_pattern=("dense",) * L, norm_eps=cfg["rms_norm_eps"],
-        rope_theta=float(cfg["rope_theta"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        dtype=cfg["torch_dtype"],
-        scale_emb=float(cfg.get("scale_emb", 1.0)),
-        residual_scale=(depth / math.sqrt(L)) if depth else 1.0,
-        logit_scale=(base / cfg["hidden_size"]) if base else 1.0,
-        pad_heads_to_multiple=cfg["assumed"]["pad_heads_to_multiple"],
-        vocab_pad_multiple=cfg["assumed"]["vocab_pad_multiple"])
 
 
 def key_from_seed(seed: int, stream: int) -> jax.Array:
@@ -71,56 +63,46 @@ def key_from_seed(seed: int, stream: int) -> jax.Array:
                               int(words[1] >> 1))
 
 
-def canonical_shapes(cfg: Dict) -> Dict[str, tuple]:
-    m = dims(cfg)
-    L, d, ff = m["L"], m["d"], m["ff"]
-    q, kv = m["hq"] * m["hd"], m["hkv"] * m["hd"]
-    shapes = {"embed": (m["vocab_pad"], d), "final_norm": (d,),
-              "ln1": (L, d), "wq": (L, d, q), "wk": (L, d, kv),
-              "wv": (L, d, kv), "wo": (L, q, d), "ln2": (L, d),
-              "w_gate": (L, d, ff), "w_up": (L, d, ff), "w_down": (L, ff, d)}
-    if not m["tied"]:
-        shapes["lm_head"] = (d, m["vocab_pad"])
-    return shapes
-
-
-def make_weights(cfg: Dict, seed: int) -> Dict[str, jax.Array]:
-    """Every weight in one jitted call on the default device, in the
-    served dtype: norms 1, embedding N(0, embedding_std), each projection
-    and the output head N(0, 1/fan_in). The embedding's spread is an
-    assumption of the configuration file: with tied embeddings and a large
-    scale_emb, a wide one makes a random model repeat its last token,
-    whatever the context."""
-    shapes = canonical_shapes(cfg)
+def make_weights(cfg: Dict, seed: int,
+                 sharding: Optional[jax.sharding.Sharding] = None
+                 ) -> Dict[str, jax.Array]:
+    """Every weight in one jitted call, in the served dtype: tensor number
+    i of the sorted names from ``fold_in(key, i)``, by the architecture's
+    ``init_std`` (None: ones). With ``sharding`` (a replicated sharding
+    over a fleet's mesh) each chip draws its own replica in the same call;
+    without, the weights land on the default device."""
+    arch = architecture(cfg)
+    shapes = arch.canonical_shapes(cfg)
     dt = dtype_of(cfg)
-    init_std = float(cfg["assumed"]["embedding_std"])
 
     def build(key):
         out = {}
         for i, (name, shape) in enumerate(sorted(shapes.items())):
-            if name in ("ln1", "ln2", "final_norm"):
+            std = arch.init_std(cfg, name, shape)
+            if std is None:
                 out[name] = jnp.ones(shape, dt)
                 continue
-            std = init_std if name == "embed" else 1.0 / math.sqrt(shape[-2])
             out[name] = (jax.random.normal(jax.random.fold_in(key, i), shape,
                                            dt) * jnp.asarray(std, dt))
         return out
 
-    return jax.jit(build)(key_from_seed(seed, 0))
+    if sharding is None:
+        return jax.jit(build)(key_from_seed(seed, 0))
+    return jax.jit(build, out_shardings=sharding)(key_from_seed(seed, 0))
 
 
-def program_params(weights: Dict[str, jax.Array], model: Model) -> Dict:
+def one_replica(weights: Dict[str, jax.Array]) -> Dict[str, jax.Array]:
+    """The first chip's copy of replicated weights (no copy is made)."""
+    return {k: (v.addressable_shards[0].data
+                if len(v.sharding.device_set) > 1 else v)
+            for k, v in weights.items()}
+
+
+def program_params(cfg: Dict, weights: Dict[str, jax.Array],
+                   model: Model) -> Dict:
     """The program's parameter tree over the same arrays (no copy), checked
     leaf for leaf against the program's own parameter shapes."""
-    layer = {"ln1": {"scale": weights["ln1"]},
-             "attn": {k: weights[k] for k in ("wq", "wk", "wv", "wo")},
-             "ln2": {"scale": weights["ln2"]},
-             "mlp": {k: weights[k] for k in ("w_gate", "w_up", "w_down")}}
-    tree = {"embed": {"tok": weights["embed"]}, "prefix": (),
-            "unit": {"0": layer},
-            "final_norm": {"scale": weights["final_norm"]},
-            "lm_head": ({} if "lm_head" not in weights
-                        else {"out": weights["lm_head"]})}
+    tree = architecture(cfg).program_params(weights)
     want = model.param_shapes()
     got = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
@@ -130,4 +112,3 @@ def program_params(weights: Dict[str, jax.Array], model: Model) -> Dict:
                          f"longer matches the benchmark's mapping:\n{want}\n"
                          f"vs\n{got}")
     return tree
-

@@ -14,23 +14,20 @@ ranks first is read against the float32 reference in the same way.
 """
 from __future__ import annotations
 
-import importlib.util
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
+
+from harness import spec
 
 SAMPLE_MIN_TOKENS = 400
 SAMPLE_MAX_REQUESTS = 8
 
 
 def load_reference(bench_dir: Path, architecture: str):
-    path = bench_dir / "references" / f"{architecture}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"perfbench_ref_{architecture}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return spec.load_module(bench_dir / "references" / f"{architecture}.py",
+                            f"perfbench_ref_{architecture}")
 
 
 def sample(finished: Sequence, seed: int) -> List:

@@ -4,10 +4,10 @@ least time of decode attention's own work. Each returns None where the
 program, or the run, gives it nothing to read."""
 from __future__ import annotations
 
-from typing import Dict, Iterable, Optional
+from typing import Optional
 
 from harness import counts, program_trace as PT
-from harness.readings import DECODE_PROGRAM, PREFILL_PROGRAM, Context
+from harness.readings import DECODE_PROGRAMS, PREFILL_PROGRAM, Context
 
 _MISSING = object()
 
@@ -23,22 +23,16 @@ def reduction(ctx: Context) -> Optional[PT.Reduction]:
     return red
 
 
-def decode_attention(cfg: Dict, positions: Iterable[int]) -> Dict[str, float]:
-    """Attention's own work in one decode micro-step over the live slots at
-    ``positions``: scores and the value sum over p + 1 keys, p rows of K
-    and V read and one written per slot."""
-    positions = list(positions)
-    kvb = counts.kv_bytes_per_token(cfg)
-    return {"flops": float(sum(counts.attention_flops(cfg, p + 1)
-                               for p in positions)),
-            "bytes": float(sum((p + 1) * kvb for p in positions))}
+# attention's own work in one decode micro-step, by the architecture
+decode_attention = counts.decode_attention
 
 
 def _attn_decode_s(ctx: Context) -> Optional[float]:
     red = reduction(ctx)
-    secs = None if red is None else PT.scope_time(red, DECODE_PROGRAM,
-                                                  "attn_decode")
-    return secs or None
+    if red is None:
+        return None
+    return sum(PT.scope_time(red, marker, "attn_decode")
+               for marker in DECODE_PROGRAMS) or None
 
 
 def attn_decode_ms(ctx: Context) -> Optional[float]:

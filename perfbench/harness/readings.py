@@ -10,8 +10,10 @@ from harness import counts
 from harness.serve import Quantum, Record, Run
 from harness.trace import Reduced, program_time
 
-# names the program gives its two served programs (device trace modules)
-DECODE_PROGRAM = "fused_decode_steps"
+# names the program gives its served programs (device trace modules): the
+# single engine's decode scan, then the fleet's; the chunked prefill is
+# ``_chunk_prefill_fn`` in the one and ``_chunk_prefill_fleet`` in the other
+DECODE_PROGRAMS = ("fused_decode_steps", "_fused_steps_fleet")
 PREFILL_PROGRAM = "chunk_prefill"
 
 
@@ -57,7 +59,10 @@ class Context:
         """(device seconds of the decode program, micro-steps it ran)."""
         if self.reduced is None:
             return None
-        secs, runs = program_time(self.reduced, DECODE_PROGRAM)
+        secs = runs = 0
+        for marker in DECODE_PROGRAMS:
+            s, n = program_time(self.reduced, marker)
+            secs, runs = secs + s, runs + n
         steps = sum(len(q.decode) for q in self.traced_quanta())
         return (secs, steps) if runs and steps else None
 
@@ -121,8 +126,9 @@ def batch_occupancy(ctx: Context) -> Optional[float]:
     steps = ctx.counter_delta("steps")
     if steps <= 0:
         return None
-    max_batch = ctx.cfg["engine"]["max_batch"]
-    return 100.0 * ctx.counter_delta("decode_tokens") / (steps * max_batch)
+    # a fleet's micro-step runs max_batch slots on each of its shards
+    slots = ctx.cfg["engine"]["max_batch"] * ctx.run.snap["w1"]["shards"]
+    return 100.0 * ctx.counter_delta("decode_tokens") / (steps * slots)
 
 
 def prefix_hit_share(ctx: Context) -> Optional[float]:

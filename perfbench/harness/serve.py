@@ -1,12 +1,15 @@
 """Drive the program's served path: ``AsyncServingServer.submit`` /
-``stream`` over a ``ServingEngine``, with the cell's traffic.
+``stream`` over a ``ServingEngine`` or a ``ShardedServingEngine``, with
+the cell's traffic.
 
 A run is: warm-up traffic that compiles every program variant the window
 can use, then the cell's own traffic from ``t_traffic``; the window opens
 ``warmup_s`` later and lasts ``seconds``. Open-loop sessions start on
 their schedule whatever the server does; closed-loop workers start their
-next session when the last one ends. No session or turn is sent once its
-due time passes the window's end. Every token's arrival at its client is
+next session when the last one ends. A session's next turn is sent its
+think time after the previous answer's last token, and a history mix
+resends every earlier turn with the answer served for it. No session or
+turn is sent once its due time passes the window's end. Every token's arrival at its client is
 stamped on the host clock.
 
 The engine's ``step`` is wrapped by the benchmark (never edited): at the
@@ -116,7 +119,8 @@ class Run:
         return {"t": now, "decode_tokens": st["decode_tokens"],
                 "steps": st["steps"],
                 "prefix_hit_tokens": st.get("prefix_hit_tokens", 0),
-                "queue_depth": st["queue_depth"]}
+                "queue_depth": st["queue_depth"],
+                "shards": st.get("shards", 1)}
 
     def _progress(self, r: Record):
         resp = self.engine.responses.get(r.rid)
@@ -175,17 +179,19 @@ class Run:
         self._live.pop(rec.rid, None)
 
     async def _session(self, server, session: Session, due: float) -> None:
-        for t in range(len(session.turns)):
+        answers: List[List[int]] = []
+        for t, turn in enumerate(session.turns):
             if t:                      # the next turn follows the answer
-                due = time.perf_counter()
+                due = time.perf_counter() + turn.think_s
             if due >= self.t_w1:
                 return
             await _sleep_until(due)
-            rec = self._request(session, t, self.traffic.prompt(session, t),
-                                due)
+            rec = self._request(session, t,
+                                self.traffic.prompt(session, t, answers), due)
             await self._send(server, rec)
             if rec.finish_reason not in ("length", "eos"):
                 return
+            answers.append(rec.tokens)
 
     async def _open_loop(self, server, tasks: List) -> None:
         i = 0
@@ -307,9 +313,10 @@ async def compile_warmup(run: Run, server) -> None:
     """Serve tiny requests that make the program compile every variant the
     window can launch: the fused decode scan at each step count up to
     ``sync_every`` (a lone request with budget k), admission of every
-    number of requests at once up to ``max_batch``, and, with prefix
-    sharing, an adopted prefix (partial and whole-prompt, which copies on
-    write)."""
+    number of requests at once up to ``max_batch`` per shard (a fleet
+    spreads them over its shards, so every number up to all its slots),
+    and, with prefix sharing, an adopted prefix (partial and
+    whole-prompt, which copies on write)."""
     eng = run.engine
     cfg = eng.cfg
     vocab = eng.model.cfg.vocab
@@ -325,7 +332,7 @@ async def compile_warmup(run: Run, server) -> None:
 
     for k in range(1, cfg.sync_every + 1):
         await serve([[k % vocab]], [k + 1])
-    for m in range(1, cfg.max_batch + 1):
+    for m in range(1, cfg.max_batch * cfg.shards + 1):
         await serve([[(m + i) % vocab] for i in range(m)], [1] * m)
     if cfg.prefix_sharing:
         ps = cfg.page_size

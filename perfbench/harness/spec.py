@@ -58,11 +58,20 @@ def resolve(bench: Dict, workload: str, root: Path = ROOT,
                 traffic=traffic, end_to_end=e2e, per_layer=layer)
 
 
-def reader(kind: str, name: str, bench_dir: Path = BENCH_DIR):
-    """The ``read`` function of ``<kind>/<name>.py``."""
-    path = bench_dir / kind / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"perfbench_{kind}_{name.replace('.', '_')}", path)
+def load_module(path: Path, name: str):
+    """The Python file ``path``, loaded as a module named ``name``; a
+    missing file raises an error that names it. Every file the harness
+    finds by a name in ``BENCHMARK.json`` or a configuration is loaded
+    here: metric readers, architecture modules and references."""
+    if not path.is_file():
+        raise FileNotFoundError(f"no module {path}")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(kind: str, name: str, bench_dir: Path = BENCH_DIR):
+    """The ``read`` function of ``<kind>/<name>.py``."""
+    return load_module(bench_dir / kind / f"{name}.py",
+                       f"perfbench_{kind}_{name.replace('.', '_')}").read

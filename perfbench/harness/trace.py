@@ -1,11 +1,13 @@
 """From a profiler trace to numbers: device busy time, idle gaps and what
 the host was doing in each, and the device time of each program.
 
-``read_xplane`` turns JAX's ``.xplane.pb`` into three plain lists of
-``Event``: device ops, device program executions ("XLA Modules"), and the
-benchmark's own host spans (TraceAnnotations named ``perfbench.*``). The
-reduction below works on those lists only, so it is checked on a small
-recorded trace in the tests.
+``read_xplane`` turns JAX's ``.xplane.pb`` into plain lists of ``Event``:
+device ops, device program executions ("XLA Modules"), and the
+benchmark's own host spans (TraceAnnotations named ``perfbench.*``).
+Programs, op names and idle gaps are read on the first chip; on a fleet,
+whose chips run one program together, busy time is the mean over every
+chip the cell uses. The reduction below works on those lists only, so it
+is checked on a small recorded trace in the tests.
 """
 from __future__ import annotations
 
@@ -37,9 +39,11 @@ class Trace:
     ops: List[Event]        # device ops (busy time), chip 0
     modules: List[Event]    # device program executions, chip 0
     spans: List[Event]      # the benchmark's host spans
+    # device ops of the cell's further chips, one list per chip
+    other_ops: List[List[Event]] = dataclasses.field(default_factory=list)
 
 
-def read_xplane(logdir: str) -> Trace:
+def read_xplane(logdir: str, chips: int = 1) -> Trace:
     from jax.profiler import ProfileData
     paths = sorted(glob.glob(f"{logdir}/plugins/profile/*/*.xplane.pb"))
     if not paths:
@@ -49,7 +53,8 @@ def read_xplane(logdir: str) -> Trace:
     modules: List[Event] = []
     spans: List[Event] = []
     devices = sorted(p.name for p in data.planes
-                     if p.name.startswith("/device:TPU:"))
+                     if p.name.startswith("/device:TPU:"))[:chips]
+    other_ops: List[List[Event]] = [[] for _ in devices[1:]]
     for plane in data.planes:
         if devices and plane.name == devices[0]:
             for line in plane.lines:
@@ -58,12 +63,18 @@ def read_xplane(logdir: str) -> Trace:
                 if into is not None:
                     into.extend(Event(e.name, e.start_ns, e.duration_ns)
                                 for e in line.events)
+        elif plane.name in devices:
+            into = other_ops[devices.index(plane.name) - 1]
+            for line in plane.lines:
+                if line.name == "XLA Ops":
+                    into.extend(Event(e.name, e.start_ns, e.duration_ns)
+                                for e in line.events)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 spans.extend(Event(e.name, e.start_ns, e.duration_ns)
                              for e in line.events
                              if e.name.startswith(SPAN_PREFIX))
-    return Trace(ops=ops, modules=modules, spans=spans)
+    return Trace(ops=ops, modules=modules, spans=spans, other_ops=other_ops)
 
 
 def merge(intervals: Sequence[Tuple[float, float]], lo: float,
@@ -117,7 +128,7 @@ def split(s: float, e: float, spans: Sequence[Event],
 @dataclasses.dataclass
 class Reduced:
     window_ns: float
-    busy_ns: float
+    busy_ns: float                       # mean over the cell's chips
     quanta: List[int]                    # quantum numbers fully traced
     program_ns: Dict[str, float]         # device time per program name
     program_runs: Dict[str, int]
@@ -169,8 +180,12 @@ def reduce(trace: Trace, top: int = 10) -> Optional[Reduced]:
                 add(IN_QUANTUM, ns)
             for x, y in outside:
                 add(OUTSIDE, y - x)
+    busy_ns = sum(e - s for s, e in busy)
+    for ops in trace.other_ops:
+        busy_ns += sum(e - s for s, e in merge(
+            [(o.start, o.end) for o in ops], lo, hi))
     return Reduced(
-        window_ns=hi - lo, busy_ns=sum(e - s for s, e in busy),
+        window_ns=hi - lo, busy_ns=busy_ns / (1 + len(trace.other_ops)),
         quanta=[int(q.name[len(QUANTUM):]) for q in qspans],
         program_ns=program_ns, program_runs=program_runs,
         top_ops=[(n, t * 1e-9) for n, t in

@@ -5,7 +5,10 @@
 
 Builds the cell named in BENCHMARK.json from its configuration, traffic
 and metric files, serves its traffic through the program's
-``AsyncServingServer`` over a ``ServingEngine`` on one chip, measures for
+``AsyncServingServer`` over a ``ServingEngine`` on one chip, or over a
+``ShardedServingEngine`` with one shard per chip where the cell asks for
+more (the configuration's ``max_batch`` and ``num_pages`` are then per
+shard, and every chip holds a replica of the weights), measures for
 ``--seconds`` after set-up and warm-up, then checks the served tokens
 against the configuration's plain reference. With ``--trace 1`` the
 window runs under the profiler and the per-layer metrics are reported;
@@ -48,16 +51,20 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     """Serve one cell once; returns the result object (without printing).
     ``control`` also reads the float8 control on the same sample."""
     import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
     from harness import model as M, oracle
     from harness.device import PEAKS, memory_peak_bytes
     from harness.readings import Context, output_tok_s, p95
     from harness.serve import Run, compile_warmup, log as progress
     from harness.trace import read_xplane, reduce
     from harness.traffic import Traffic
+    from repro.launch.mesh import make_serving_mesh
     from repro.serving import EngineConfig, ServingEngine
+    from repro.serving.sharded import ShardedServingEngine
 
     cfg, mix = cell.config, cell.traffic
-    eng_cfg = EngineConfig(**cfg["engine"])
+    eng_cfg = EngineConfig(**cfg["engine"], shards=cell.chips)
+    mesh = make_serving_mesh(cell.chips) if cell.chips > 1 else None
     traffic = Traffic(mix, seed, cfg["vocab_size"])
     if traffic.worst_case_tokens() > eng_cfg.max_len:
         raise ValueError(f"{cell.name}: a request can hold "
@@ -66,13 +73,17 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     split = {}
     progress(f"{cell.name}: weights")
     t = time.perf_counter()
-    weights = M.make_weights(cfg, seed)
+    weights = M.make_weights(
+        cfg, seed, None if mesh is None else NamedSharding(mesh, P()))
     jax.block_until_ready(weights)
     split["init_s"] = time.perf_counter() - t
     progress("engine")
     t = time.perf_counter()
     model = M.Model(M.program_config(cfg))
-    engine = ServingEngine(model, M.program_params(weights, model), eng_cfg)
+    params = M.program_params(cfg, weights, model)
+    engine = (ServingEngine(model, params, eng_cfg) if mesh is None else
+              ShardedServingEngine(model, params, eng_cfg, mesh=mesh))
+    del params
     jax.block_until_ready(engine.caches)
     split["engine_s"] = time.perf_counter() - t
 
@@ -86,13 +97,14 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     def on_window(edge):
         compiles[edge] = (log.compiles, log.traces)
     run.on_window = on_window
-    workers = int(mix.get("workers_per_slot", 0)) * eng_cfg.max_batch
+    workers = (int(mix.get("workers_per_slot", 0)) * eng_cfg.max_batch
+               * cell.chips)
     t = time.perf_counter()
     asyncio.run(run.serve(compile_warmup, float(mix["warmup_s"]),
                           float(mix.get("drain_s", 0)), workers))
     split["warmup_s"] = run.t_w0 - t
     setup_s = run.t_w0 - t_start
-    peak_bytes = memory_peak_bytes()
+    peak_bytes = memory_peak_bytes(cell.chips)
     n_compiled = compiles["w1"][0] - compiles["w0"][0]
     n_traced = compiles["w1"][1] - compiles["w0"][1]
 
@@ -100,7 +112,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     ctx = Context(cfg=cfg, run=run, setup_s=setup_s, peak=PEAKS.get(kind))
     if trace:
         progress("reading the trace")
-        ctx.reduced = reduce(read_xplane(trace_dir))
+        ctx.reduced = reduce(read_xplane(trace_dir, cell.chips))
     wanted = cell.per_layer if trace else cell.end_to_end
     kind_dir = "metrics" if trace else "end_to_end"
     metrics = {}
@@ -121,6 +133,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     run.engine = None
     del engine, model
     gc.collect()
+    weights = M.one_replica(weights)
     progress(f"reference on {len(finished)} finished requests")
     t = time.perf_counter()
     ref = oracle.load_reference(spec.BENCH_DIR, cfg["architecture"])

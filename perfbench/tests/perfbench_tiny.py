@@ -65,3 +65,28 @@ def run_tiny(seed: int, seconds: float = 1.0) -> dict:
     return run.run_cell(cell(), seed, seconds, False,
                         {"platform": "cpu", "kind": "cpu", "count": 1},
                         CompileLog(), time.perf_counter())
+
+
+# a conversation mix: history resent every turn, with think time
+SESSIONS = {k: v for k, v in MIX.items() if k != "document"} | {
+    "history": True, "turns": {"kind": "uniform", "min": 2, "max": 3},
+    "think_s": {"kind": "uniform_real", "min": 0.05, "max": 0.2}}
+
+
+def run_fleet(seed: int, chips: int, root, seconds: float = 1.5) -> dict:
+    """The tiny cell as a fleet of ``chips`` shards (host devices), traced,
+    with the per-layer metrics that read the engine's counters."""
+    import time
+    from harness import spec
+    from harness.device import CompileLog
+    bench = spec.load_benchmark(ROOT)
+    layer = [m for m in bench["per_layer"]
+             if m["name"] in ("batch_occupancy.tput", "prefix_hit_share.tput",
+                              "kv_mapped_share.tput", "host_stall_ms.tput")]
+    c = spec.Cell(name="tiny-fleet", chips=chips, config=CONFIG,
+                  traffic=SESSIONS, end_to_end=bench["end_to_end"],
+                  per_layer=layer)
+    return load_run().run_cell(
+        c, seed, seconds, True, {"platform": "cpu", "kind": "cpu",
+                                 "count": chips},
+        CompileLog(), time.perf_counter(), root=Path(root))
