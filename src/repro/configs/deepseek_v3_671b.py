@@ -5,9 +5,10 @@
 dense (d_ff 18432), remaining 58 MoE with 1 shared + 256 routed experts of
 d_ff 2048, top-8 routing; multi-token-prediction head. Vocab 129280.
 
-Simplifications vs the paper (noted in DESIGN.md): softmax-over-top-k
-router instead of sigmoid+bias-correction; node-limited routing modeled by
-the capacity factor; depth-1 MTP.
+Departures from the paper: the router is the capacity layer's softmax
+over the top-k logits (models/moe.py), not V3's sigmoid scores with a
+bias correction and node-limited groups, and it models nothing of them;
+tokens past an expert's capacity are dropped; depth-1 MTP.
 """
 from repro.models import MLAConfig, MoEConfig, ModelConfig
 

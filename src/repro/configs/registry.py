@@ -25,6 +25,7 @@ ARCH_IDS = [
     "stablelm-12b",
     "internlm2-20b",
     "llama3.2-1b",
+    "deepseek-v2-lite",
 ]
 
 _MODULES = {a: "repro.configs." + a.replace("-", "_").replace(".", "_")

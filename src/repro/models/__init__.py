@@ -1,6 +1,7 @@
 from repro.models.config import (MLAConfig, MoEConfig, ModelConfig,
-                                 RWKVConfig, SSMConfig, repeat_pattern)
+                                 RWKVConfig, SSMConfig, YarnScaling,
+                                 repeat_pattern)
 from repro.models.model import Model
 
 __all__ = ["MLAConfig", "MoEConfig", "ModelConfig", "RWKVConfig", "SSMConfig",
-           "Model", "repeat_pattern"]
+           "YarnScaling", "Model", "repeat_pattern"]

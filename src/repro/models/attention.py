@@ -120,19 +120,24 @@ def mla_init(key, cfg) -> Dict:
     H = cfg.n_heads_padded
     ks = jax.random.split(key, 7)
     qk_head = m.qk_nope_head_dim + m.qk_rope_head_dim
-    p = {
-        "w_dq": L.dense_init(ks[0], d, m.q_lora_rank, dt),
-        "q_norm": L.rmsnorm_init(m.q_lora_rank, dt),
-        "w_uq": L.dense_init(ks[1], m.q_lora_rank, H * qk_head, dt),
+    if m.q_lora_rank is None:           # one query projection (V2-Lite)
+        p = {"w_q": L.dense_init(ks[0], d, H * qk_head, dt)}
+        q_up = "w_q"
+    else:
+        p = {"w_dq": L.dense_init(ks[0], d, m.q_lora_rank, dt),
+             "q_norm": L.rmsnorm_init(m.q_lora_rank, dt),
+             "w_uq": L.dense_init(ks[1], m.q_lora_rank, H * qk_head, dt)}
+        q_up = "w_uq"
+    p.update({
         "w_dkv": L.dense_init(ks[2], d, m.kv_lora_rank + m.qk_rope_head_dim, dt),
         "kv_norm": L.rmsnorm_init(m.kv_lora_rank, dt),
         "w_uk": L.dense_init(ks[3], m.kv_lora_rank, H * m.qk_nope_head_dim, dt),
         "w_uv": L.dense_init(ks[4], m.kv_lora_rank, H * m.v_head_dim, dt),
         "wo": L.dense_init(ks[5], H * m.v_head_dim, d, dt),
-    }
+    })
     nH = cfg.n_heads
     if H > nH:
-        p["w_uq"] = p["w_uq"].at[:, nH * qk_head:].set(0)
+        p[q_up] = p[q_up].at[:, nH * qk_head:].set(0)
         p["w_uk"] = p["w_uk"].at[:, nH * m.qk_nope_head_dim:].set(0)
         p["w_uv"] = p["w_uv"].at[:, nH * m.v_head_dim:].set(0)
         p["wo"] = p["wo"].at[nH * m.v_head_dim:, :].set(0)
@@ -162,16 +167,18 @@ def self_attn_bias(q_pos: jax.Array, k_pos: jax.Array,
 # --------------------------------------------------------------------------
 
 
-def _direct_attention(q, k, v, bias):
-    """q: (B,Sq,H,hd), k/v: (B,Sk,H,hd), bias: (B,1|H,Sq,Sk) -> (B,Sq,H,hd)."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+def _direct_attention(q, k, v, bias, scale=None):
+    """q: (B,Sq,H,hd), k/v: (B,Sk,H,hd), bias: (B,1|H,Sq,Sk) -> (B,Sq,H,hd).
+    ``scale`` defaults to hd^-0.5."""
+    if scale is None:
+        scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     scores = scores + bias
     w = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", w, v)
 
 
-def _flash_attention(q, k, v, q_pos, k_pos, window, chunk):
+def _flash_attention(q, k, v, q_pos, k_pos, window, chunk, scale=None):
     """Two-level running-softmax scan; O(Q_CHUNK*KV_CHUNK) score memory.
 
     hd (q/k dim) may differ from hd_v (MLA: 192 vs 128).
@@ -179,7 +186,8 @@ def _flash_attention(q, k, v, q_pos, k_pos, window, chunk):
     B, Sq, H, hd = q.shape
     hd_v = v.shape[-1]
     Sk = k.shape[1]
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
     nq = -(-Sq // Q_CHUNK)
     nk = -(-Sk // KV_CHUNK)
     Sq_p, Sk_p = nq * Q_CHUNK, nk * KV_CHUNK
@@ -235,13 +243,14 @@ def _flash_attention(q, k, v, q_pos, k_pos, window, chunk):
     return shard(out[:, :Sq], "batch", "seq", "heads", None)
 
 
-def attention_core(q, k, v, q_pos, k_pos, window=None, chunk=None):
+def attention_core(q, k, v, q_pos, k_pos, window=None, chunk=None,
+                   scale=None):
     """Dispatch between direct and flash-scan attention (same math)."""
     Sk = k.shape[1]
     if Sk <= DIRECT_ATTN_MAX_SEQ:
         bias = self_attn_bias(q_pos, k_pos, window, chunk)[:, None]
-        return _direct_attention(q, k, v, bias)
-    return _flash_attention(q, k, v, q_pos, k_pos, window, chunk)
+        return _direct_attention(q, k, v, bias, scale)
+    return _flash_attention(q, k, v, q_pos, k_pos, window, chunk, scale)
 
 
 # --------------------------------------------------------------------------
@@ -328,9 +337,17 @@ def _flat_rows(pages: jax.Array):
 def paged_decode_write(cache: Dict, tbl: jax.Array, k1: jax.Array,
                        v1: jax.Array) -> Dict:
     """Append one token per slot through the (B, max_pages) block table."""
+    return _paged_decode_write(cache, tbl, {"k_pages": k1, "v_pages": v1})
+
+
+def _paged_decode_write(cache: Dict, tbl: jax.Array, new: Dict) -> Dict:
+    """``paged_decode_write`` for any page leaves: ``new`` maps each page
+    leaf of the group (``k_pages``/``v_pages``, or the latent's
+    ``ckv_pages``/``krope_pages``) to its (B, 1, Hkv, width) row."""
     t = cache["length"]
-    kf, ps, trash = _flat_rows(cache["k_pages"])
-    vf, _, _ = _flat_rows(cache["v_pages"])
+    flat = {n: _flat_rows(cache[n])[0] for n in new}
+    _, P1, ps, _ = cache[next(iter(new))].shape
+    trash = P1 - 1
     B = t.shape[0]
     M = tbl.shape[1]
     W = cache["pos_ids"].shape[1]
@@ -340,11 +357,11 @@ def paged_decode_write(cache: Dict, tbl: jax.Array, k1: jax.Array,
     pg = jnp.where((pg < 0) | (lp >= M), trash, pg)
     rows = pg * ps + t % ps                          # physical flat row (B,)
     t_c = jnp.clip(t, 0, W - 1)
-    kf = kf.at[:, rows].set(jnp.swapaxes(k1[:, 0], 0, 1).astype(kf.dtype))
-    vf = vf.at[:, rows].set(jnp.swapaxes(v1[:, 0], 0, 1).astype(vf.dtype))
+    flat = {n: f.at[:, rows].set(jnp.swapaxes(new[n][:, 0], 0, 1)
+                                 .astype(f.dtype))
+            for n, f in flat.items()}
     return {
-        "k_pages": kf.reshape(cache["k_pages"].shape),
-        "v_pages": vf.reshape(cache["v_pages"].shape),
+        **{n: f.reshape(cache[n].shape) for n, f in flat.items()},
         "pos_ids": cache["pos_ids"].at[bidx, t_c].set(t),
         "length": t + 1,
     }
@@ -360,9 +377,18 @@ def paged_chunk_write(cache: Dict, tbl: jax.Array, k: jax.Array,
     is only written at valid positions, so pads never unmask — the same
     invariant as the single-token decode write, extended to S tokens.
     """
+    return _paged_chunk_write(cache, tbl, {"k_pages": k, "v_pages": v},
+                              positions)
+
+
+def _paged_chunk_write(cache: Dict, tbl: jax.Array, new: Dict,
+                       positions: jax.Array) -> Dict:
+    """``paged_chunk_write`` for any page leaves, as ``_paged_decode_write``
+    (``new``: page leaf -> (B, S, Hkv, width))."""
     t = positions
-    kf, ps, trash = _flat_rows(cache["k_pages"])
-    vf, _, _ = _flat_rows(cache["v_pages"])
+    flat = {n: _flat_rows(cache[n])[0] for n in new}
+    _, P1, ps, _ = cache[next(iter(new))].shape
+    trash = P1 - 1
     B, S = t.shape
     M = tbl.shape[1]
     W = cache["pos_ids"].shape[1]
@@ -372,12 +398,11 @@ def paged_chunk_write(cache: Dict, tbl: jax.Array, k: jax.Array,
     pg = tbl[bidx, jnp.clip(lp, 0, M - 1)]
     pg = jnp.where(valid & (lp < M) & (pg >= 0), pg, trash)
     rows = pg * ps + jnp.where(valid, t % ps, 0)     # (B, S) physical rows
-    kf = kf.at[:, rows].set(jnp.moveaxis(k, 2, 0).astype(kf.dtype))
-    vf = vf.at[:, rows].set(jnp.moveaxis(v, 2, 0).astype(vf.dtype))
+    flat = {n: f.at[:, rows].set(jnp.moveaxis(new[n], 2, 0).astype(f.dtype))
+            for n, f in flat.items()}
     col = jnp.where(valid, jnp.clip(t, 0, W - 1), W)  # W = dropped
     return {
-        "k_pages": kf.reshape(cache["k_pages"].shape),
-        "v_pages": vf.reshape(cache["v_pages"].shape),
+        **{n: f.reshape(cache[n].shape) for n, f in flat.items()},
         "pos_ids": cache["pos_ids"].at[bidx, col].set(t, mode="drop"),
         "length": cache["length"] + valid.sum(axis=1).astype(jnp.int32),
     }
@@ -454,31 +479,45 @@ def _pool_key_positions(tbl: jax.Array, pos_ids: jax.Array,
     return kpos.reshape(B, n_pages * ps)
 
 
-def _pool_decode_attention(q, cache, page_tbl, positions, window, chunk,
-                           gp):
+def _pool_decode_attention(qs, cache, page_tbl, positions, window, chunk,
+                           gp, keys=("k_pages",), values="v_pages",
+                           scale=None):
     """Decode attention of one query per slot against the flat page pool,
     masked by the block table: per kv head one (B*g) x R score matmul and
     one value matmul, with no per-slot copy of the pool. The same softmax
     over the same keys as the gathered view (masked rows add exact zeros),
-    at the same precision: bf16 operands, f32 scores and accumulation."""
-    kf, _, _ = _flat_rows(cache["k_pages"])
-    vf, _, _ = _flat_rows(cache["v_pages"])
+    at the same precision: bf16 operands, f32 scores and accumulation.
+
+    ``qs`` holds one (B, 1, Hq, width) query part per page leaf in
+    ``keys``; the scores are the sum of their products (MLA's absorbed
+    decode scores its latent query against ``ckv_pages`` and its rope
+    query against ``krope_pages``, one latent kv head of group Hq, and
+    sums values from ``ckv_pages``). ``scale`` defaults to width^-0.5 of
+    a single query part."""
+    kfs = [_flat_rows(cache[k])[0] for k in keys]
+    vf, _, _ = _flat_rows(cache[values])
     kpos = _pool_key_positions(page_tbl, cache["pos_ids"],
-                               cache["k_pages"].shape[1])
+                               cache[keys[0]].shape[1])
     bias = self_attn_bias(positions, kpos, window, chunk)   # (B, 1, R)
-    B, _, Hq, hd = q.shape
-    n_kv = kf.shape[0]
-    # (Hkv, B*g, hd): the queries of one kv head are one matmul's rows
-    qk = jnp.swapaxes(q.reshape(B, n_kv, gp, hd), 0, 1).reshape(
-        n_kv, B * gp, hd)
-    scale = 1.0 / math.sqrt(hd)
-    sc = jnp.einsum("kqd,krd->kqr", qk, kf,
-                    preferred_element_type=jnp.float32) * scale
+    B, _, Hq, hd = qs[0].shape
+    n_kv = vf.shape[0]
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
+    sc = None
+    for q, kf in zip(qs, kfs):
+        # (Hkv, B*g, width): the queries of one kv head are one matmul's rows
+        qk = jnp.swapaxes(q.reshape(B, n_kv, gp, q.shape[-1]), 0, 1).reshape(
+            n_kv, B * gp, q.shape[-1])
+        part = jnp.einsum("kqd,krd->kqr", qk, kf,
+                          preferred_element_type=jnp.float32)
+        sc = part if sc is None else sc + part
+    sc = sc * scale
     sc = sc.reshape(n_kv, B, gp, -1) + bias[None]
-    w = jax.nn.softmax(sc, axis=-1).astype(q.dtype)
+    w = jax.nn.softmax(sc, axis=-1).astype(qs[0].dtype)
     out = jnp.einsum("kqr,krd->kqd", w.reshape(n_kv, B * gp, -1), vf)
-    return jnp.swapaxes(out.reshape(n_kv, B, gp, hd), 0, 1).reshape(
-        B, 1, Hq, hd)
+    hd_v = vf.shape[-1]
+    return jnp.swapaxes(out.reshape(n_kv, B, gp, hd_v), 0, 1).reshape(
+        B, 1, Hq, hd_v)
 
 
 # --------------------------------------------------------------------------
@@ -627,7 +666,7 @@ def _cached_attention(cfg, q, k, v, positions, layer_window, layer_chunk,
     elif paged and mode == "decode" and gp is not None:
         # one query a slot: score the pool in place, masked by the block
         # table, instead of gathering every slot's W rows out of it
-        out = _pool_decode_attention(q, cache, page_tbl, positions,
+        out = _pool_decode_attention((q,), cache, page_tbl, positions,
                                      layer_window, layer_chunk, gp)
     else:
         bias = self_attn_bias(positions, cache["pos_ids"],
@@ -701,21 +740,102 @@ def _mla_qkv_latent(p, cfg, x, positions):
     """Returns per-head q (nope+rope) and the shared latent k parts."""
     m = cfg.mla
     H = cfg.n_heads_padded
-    cq = L.rmsnorm(p["q_norm"], jnp.einsum("...d,dr->...r", x, p["w_dq"]),
-                   cfg.norm_eps)
-    q = jnp.einsum("...r,rh->...h", cq, p["w_uq"])
+    if "w_q" in p:                      # no query down-projection
+        q = jnp.einsum("...d,dh->...h", x, p["w_q"])
+    else:
+        cq = L.rmsnorm(p["q_norm"],
+                       jnp.einsum("...d,dr->...r", x, p["w_dq"]),
+                       cfg.norm_eps)
+        q = jnp.einsum("...r,rh->...h", cq, p["w_uq"])
     q = q.reshape(q.shape[:-1] + (H, m.qk_nope_head_dim + m.qk_rope_head_dim))
     q = shard(q, "batch", "seq", "heads", None)
     q_nope, q_rope = q[..., :m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
-    q_rope = L.apply_rope(q_rope, positions, 1.0, cfg.rope_theta)
+    q_rope = L.apply_rope(q_rope, positions, 1.0, cfg.rope_theta,
+                          cfg.rope_scaling)
 
     dkv = jnp.einsum("...d,dr->...r", x, p["w_dkv"])
     ckv = L.rmsnorm(p["kv_norm"], dkv[..., :m.kv_lora_rank], cfg.norm_eps)
     k_rope = dkv[..., m.kv_lora_rank:]
     # shared-rope key (one per token, broadcast over heads)
     k_rope = L.apply_rope(k_rope[..., None, :], positions, 1.0,
-                          cfg.rope_theta)[..., 0, :]
+                          cfg.rope_theta, cfg.rope_scaling)[..., 0, :]
     return q_nope, q_rope, ckv, k_rope
+
+
+def mla_softmax_scale(cfg) -> float:
+    """(nope + rope)^-0.5, times YaRN's mscale(mscale_all_dim)^2 when the
+    rope is scaled (DeepSeek-V2: 192^-0.5 x 1.2608^2 = 0.1147214)."""
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= L.yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
+# the latent's page leaves: the normed latent and the roped key, each as
+# one head of the page pool (1, P+1, page_size, width)
+LATENT_PAGES = ("ckv_pages", "krope_pages")
+MLA_KV_BLOCK = 1024      # history rows per step of the chunked-prefill loop
+
+
+def _latent_rows(ckv, k_rope):
+    """(B, S, width) latent and rope key -> the page leaves' new rows."""
+    return {"ckv_pages": ckv[:, :, None, :],
+            "krope_pages": k_rope[:, :, None, :]}
+
+
+def _mla_chunk_attention(q_nope, q_rope, cache, tbl, positions, w_uk, w_uv,
+                         scale, window):
+    """Chunk-mode MLA over latent pages, in the expanded form: each block
+    of MLA_KV_BLOCK history rows is gathered through the block table,
+    expanded to per-head k_nope and v (``w_uk``, ``w_uv``), scored with
+    the chunk's queries and folded into a running softmax. The loop stops
+    at the block holding the chunk's last position, so a chunk costs the
+    history it has, not ``max_len``.
+
+    q_nope: (B, S, H, n); q_rope: (B, S, H, r); w_uk: (kvr, H, n); w_uv:
+    (kvr, H, v). Returns (B, S, H, v)."""
+    cf = _flat_rows(cache["ckv_pages"])[0][0]          # (R, kvr)
+    rf = _flat_rows(cache["krope_pages"])[0][0]        # (R, r)
+    _, P1, ps, _ = cache["ckv_pages"].shape
+    B, S, H, _ = q_nope.shape
+    vd = w_uv.shape[-1]
+    W = cache["pos_ids"].shape[1]
+    kb = math.gcd(W, MLA_KV_BLOCK)                     # a page multiple
+    pb = kb // ps
+    n_blk = jnp.clip((jnp.max(positions) + kb) // kb, 1, W // kb)
+
+    def body(j, carry):
+        m_, l_, acc = carry
+        pages = jax.lax.dynamic_slice_in_dim(tbl, j * pb, pb, axis=1)
+        pages = jnp.where(pages < 0, P1 - 1, pages)    # unmapped -> trash
+        rows = (pages[:, :, None] * ps + jnp.arange(ps)).reshape(B, kb)
+        ck, kr = cf[rows], rf[rows]                    # (B, kb, kvr|r)
+        kpos = jax.lax.dynamic_slice_in_dim(cache["pos_ids"], j * kb, kb,
+                                            axis=1)
+        k_nope = jnp.einsum("bkr,rhn->bkhn", ck, w_uk)
+        vv = jnp.einsum("bkr,rhv->bkhv", ck, w_uv)
+        sc = (jnp.einsum("bqhn,bkhn->bhqk", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bqhr,bkr->bhqk", q_rope, kr,
+                           preferred_element_type=jnp.float32)) * scale
+        sc = sc + self_attn_bias(positions, kpos, window, None)[:, None]
+        m_new = jnp.maximum(m_, sc.max(axis=-1))
+        pw = jnp.exp(sc - m_new[..., None])
+        corr = jnp.exp(m_ - m_new)
+        l_new = l_ * corr + pw.sum(axis=-1)
+        acc = acc * corr[..., None] + jnp.einsum(
+            "bhqk,bkhv->bhqv", pw.astype(vv.dtype), vv,
+            preferred_element_type=jnp.float32)
+        return m_new, l_new, acc
+
+    init = (jnp.full((B, H, S), -jnp.inf, jnp.float32),
+            jnp.zeros((B, H, S), jnp.float32),
+            jnp.zeros((B, H, S, vd), jnp.float32))
+    _, l_, acc = jax.lax.fori_loop(0, n_blk, body, init)
+    out = acc / jnp.maximum(l_, 1e-30)[..., None]
+    return jnp.moveaxis(out, 1, 2).astype(q_nope.dtype)
 
 
 def _mla_flash_fused(q_nope, q_rope, ckv, k_rope, w_uk, w_uv,
@@ -785,19 +905,63 @@ def _mla_flash_fused(q_nope, q_rope, ckv, k_rope, w_uk, w_uv,
 def mla_attention(p: Dict, cfg, x: jax.Array, positions: jax.Array,
                   layer_window: Optional[int],
                   cache: Optional[Dict] = None, mode: str = "train",
+                  page_tbl: Optional[jax.Array] = None,
                   ) -> Tuple[jax.Array, Optional[Dict]]:
     """Naive (expanded) path for train/prefill; absorbed path for decode.
 
     The absorbed decode computes scores in the 512-dim latent space
     directly against the cached ``ckv`` — this is what makes the MLA cache
     (576 B/token/layer in bf16) pay off at 500k context.
+
+    A paged cache (``ckv_pages`` present: the latent leaf group of
+    ``serving/paged.py``) serves decode and chunk mode through
+    ``page_tbl``. Decode absorbs ``w_uk`` into the query and scores the
+    whole pool in place (``_pool_decode_attention`` with one latent kv
+    head, 576 wide for scores and 512 for values), then projects the
+    latent value sum with ``w_uv``. Chunk mode writes the chunk's latent
+    rows and attends in the expanded form over the history it has
+    (``_mla_chunk_attention``): per key, the expanded form's scores and
+    values cost 16 x (192 + 128) multiply-adds against the absorbed
+    form's 16 x (576 + 512), and a chunk's queries outnumber the once-
+    per-key expansion. Scopes as ``self_attention``: ``attn_proj`` for
+    the projections, ``attn_decode`` / ``attn_prefill`` from the cache
+    write through the value projection (the absorption products
+    included).
     """
     m = cfg.mla
     H = cfg.n_heads_padded
-    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
-    q_nope, q_rope, ckv, k_rope = _mla_qkv_latent(p, cfg, x, positions)
+    scale = mla_softmax_scale(cfg)
+    with jax.named_scope("attn_proj"):
+        q_nope, q_rope, ckv, k_rope = _mla_qkv_latent(p, cfg, x, positions)
+    paged = cache is not None and "ckv_pages" in cache
+    if paged and cfg.attn_impl != "ref":
+        raise ValueError(
+            f"{cfg.name}: attn_impl={cfg.attn_impl!r} has no kernel for "
+            "latent (MLA) pages; use attn_impl='ref'")
+    w_uk = p["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
+    w_uv = p["w_uv"].reshape(m.kv_lora_rank, H, m.v_head_dim)
 
-    if mode == "decode":
+    if paged:
+        assert mode in ("decode", "chunk") and page_tbl is not None, \
+            "latent pages serve decode and chunk mode through page_tbl"
+        with jax.named_scope(_CACHED_SCOPE[mode]):
+            if mode == "decode":
+                cache = _paged_decode_write(cache, page_tbl,
+                                            _latent_rows(ckv, k_rope))
+                q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w_uk)
+                o_lat = _pool_decode_attention(
+                    (q_lat, q_rope), cache, page_tbl, positions,
+                    layer_window, None, H, keys=LATENT_PAGES,
+                    values="ckv_pages", scale=scale)
+                out = jnp.einsum("bqhr,rhv->bqhv", o_lat, w_uv)
+            else:
+                cache = _paged_chunk_write(cache, page_tbl,
+                                           _latent_rows(ckv, k_rope),
+                                           positions)
+                out = _mla_chunk_attention(q_nope, q_rope, cache, page_tbl,
+                                           positions, w_uk, w_uv, scale,
+                                           layer_window)
+    elif mode == "decode":
         assert cache is not None
         B = x.shape[0]
         W = cache["ckv"].shape[1]
@@ -812,7 +976,6 @@ def mla_attention(p: Dict, cfg, x: jax.Array, positions: jax.Array,
         ckv_all = shard(cache["ckv"], "batch", "kv_seq", None)
         krope_all = shard(cache["k_rope"], "batch", "kv_seq", None)
         # absorb: q_lat[h] = q_nope[h] @ w_uk[h]^T  (B,1,H,kvr)
-        w_uk = p["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
         q_lat = jnp.einsum("bqhn,rhn->bqhr", q_nope, w_uk)
         s = (jnp.einsum("bqhr,bkr->bhqk", q_lat, ckv_all)
              + jnp.einsum("bqhn,bkn->bhqk", q_rope, krope_all)
@@ -825,7 +988,6 @@ def mla_attention(p: Dict, cfg, x: jax.Array, positions: jax.Array,
                                layer_window, None)[:, None]
         w = jax.nn.softmax(s, axis=-1).astype(x.dtype)
         o_lat = jnp.einsum("bhqk,bkr->bqhr", w, ckv_all)
-        w_uv = p["w_uv"].reshape(m.kv_lora_rank, H, m.v_head_dim)
         out = jnp.einsum("bqhr,rhv->bqhv", o_lat, w_uv)
     else:
         if mode == "prefill":
@@ -835,8 +997,6 @@ def mla_attention(p: Dict, cfg, x: jax.Array, positions: jax.Array,
                 ckv[..., None, :], k_rope[..., None, :], positions)
             cache = {"ckv": cache["k"][..., 0, :], "k_rope": cache["v"][..., 0, :],
                      "pos_ids": cache["pos_ids"], "length": cache["length"]}
-        w_uk = p["w_uk"].reshape(m.kv_lora_rank, H, m.qk_nope_head_dim)
-        w_uv = p["w_uv"].reshape(m.kv_lora_rank, H, m.v_head_dim)
         if cfg.mla_fused_prefill and x.shape[1] > DIRECT_ATTN_MAX_SEQ:
             out = _mla_flash_fused(q_nope, q_rope, ckv, k_rope, w_uk, w_uv,
                                    positions, positions, layer_window, scale)
@@ -853,9 +1013,10 @@ def mla_attention(p: Dict, cfg, x: jax.Array, positions: jax.Array,
             qq = jnp.concatenate([q_nope, q_rope], axis=-1)
             qq = shard(qq, "batch", "seq", "heads", None)
             out = attention_core(qq, kk, vv, positions, positions,
-                                 layer_window, None)
-    flat = out.reshape(out.shape[:-2] + (-1,))
-    return jnp.einsum("...h,hd->...d", flat, p["wo"]), cache
+                                 layer_window, None, scale)
+    with jax.named_scope("attn_proj"):
+        flat = out.reshape(out.shape[:-2] + (-1,))
+        return jnp.einsum("...h,hd->...d", flat, p["wo"]), cache
 
 
 # --------------------------------------------------------------------------

@@ -150,10 +150,13 @@ def _mlp(p, x):
 
 
 def _moe_dispatch(p, cfg, x):
-    """Sharded (shard_map all_to_all) MoE when a multi-device mesh context is
-    active; dense capacity-dispatch otherwise (CPU unit tests). Scoped
-    ``mlp`` like the dense feed-forward."""
+    """The dropless layer over the held experts when the config asks for
+    it; else sharded (shard_map all_to_all) MoE when a multi-device mesh
+    context is active, dense capacity-dispatch otherwise (CPU unit tests).
+    Scoped ``mlp`` like the dense feed-forward."""
     with jax.named_scope("mlp"):
+        if cfg.moe.dropless:
+            return MOE.moe_ffn_held(p, cfg, x)
         if MOES.use_sharded_moe(cfg):
             return MOES.moe_ffn_sharded(p, cfg, x)
         return MOE.moe_ffn(p, cfg, x)
@@ -213,7 +216,8 @@ def block_apply(p: Dict, cfg, kind: str, ctx: LayerCtx, x: jax.Array,
     if kind in ("mla", "mla_moe"):
         h = _norm3(p["ln1"], x, cfg.norm_eps)
         a, cache = A.mla_attention(p["attn"], cfg, h, ctx.positions,
-                                   window, cache, ctx.mode)
+                                   window, cache, ctx.mode,
+                                   page_tbl=ctx.page_tbl)
         x = _res(cfg, x, a)
         h2 = _norm3(p["ln2"], x, cfg.norm_eps)
         if kind == "mla_moe":

@@ -29,8 +29,10 @@ BLOCK_KINDS = ("dense", "parallel", "moe", "mla", "mla_moe", "mamba2",
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    """Multi-head Latent Attention (DeepSeek-V2/V3)."""
-    q_lora_rank: int = 1536
+    """Multi-head Latent Attention (DeepSeek-V2/V3). ``q_lora_rank`` None:
+    the query is one projection of the hidden state (DeepSeek-V2-Lite), no
+    down-projection and norm."""
+    q_lora_rank: Optional[int] = 1536
     kv_lora_rank: int = 512
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
@@ -39,13 +41,40 @@ class MLAConfig:
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 8
+    n_experts: int = 8                 # experts the router scores
     top_k: int = 2
     d_ff_expert: int = 2048
     n_shared_experts: int = 0          # shared experts always active
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01    # load-balance loss weight
     router_z_weight: float = 1e-3
+    # dropless (``moe.moe_ffn_held``): every token-expert pair routed to a
+    # held expert is computed; the layer holds experts 0..n_held_experts-1
+    # of the n_experts the router scores (0 = all of them). Its router is
+    # DeepSeek-V2's: softmax over all n_experts logits, the greedy top_k,
+    # gates not renormalised. Without ``dropless`` the capacity layer
+    # (``moe.moe_ffn``) runs, with its softmax over the top_k logits.
+    dropless: bool = False
+    n_held_experts: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held_experts or self.n_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling as DeepSeek-V2 publishes it: inverse frequencies
+    interpolated by ``factor`` below the correction range of
+    (beta_fast, beta_slow) rotations over the original context, and the
+    attention softmax scaled by mscale(mscale_all_dim)^2. The published
+    cos/sin factor mscale(mscale) / mscale(mscale_all_dim) is 1 for the
+    configurations served (mscale == mscale_all_dim), so it is left out."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 0.707
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,6 +119,7 @@ class ModelConfig:
     norm_eps: float = 1e-5
     rope_theta: float = 10000.0
     rotary_pct: float = 1.0            # partial rotary (StableLM-2)
+    rope_scaling: Optional[YarnScaling] = None
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
     # attention locality
@@ -141,6 +171,14 @@ class ModelConfig:
             raise ValueError(f"{self.name}: mamba2 blocks need cfg.ssm")
         if "rwkv6" in self.block_pattern and self.rwkv is None:
             raise ValueError(f"{self.name}: rwkv6 blocks need cfg.rwkv")
+        if self.moe is not None and not (
+                0 < self.moe.held <= self.moe.n_experts):
+            raise ValueError(f"{self.name}: n_held_experts must be in "
+                             f"[0, {self.moe.n_experts}]")
+        if self.moe is not None and self.moe.n_held_experts \
+                and not self.moe.dropless:
+            raise ValueError(f"{self.name}: held experts need the dropless "
+                             "layer (moe.dropless)")
 
     # ------------------------------------------------------------------
     @property

@@ -59,20 +59,51 @@ def layernorm(p: Dict, x: jax.Array, eps: float) -> jax.Array:
 # --- rotary embeddings -----------------------------------------------------
 
 
-def rope_frequencies(head_dim: int, rotary_pct: float, theta: float
-                     ) -> Tuple[int, jax.Array]:
-    """(rotary_dim, inv_freq[rotary_dim/2])."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``: 0.1 * mscale * ln(factor) + 1,
+    and 1 without interpolation."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(rot: int, theta: float, scaling
+                          ) -> Tuple[int, int]:
+    """The dims between which YaRN ramps from interpolated to original
+    frequencies: those completing ``beta_fast`` and ``beta_slow``
+    rotations over the original context, floored and ceiled as published
+    (10 and 23 for DeepSeek-V2-Lite's 64 rope dims)."""
+    def dim(rotations):
+        return (rot * math.log(scaling.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = math.floor(dim(scaling.beta_fast))
+    high = math.ceil(dim(scaling.beta_slow))
+    return max(low, 0), min(high, rot - 1)
+
+
+def rope_frequencies(head_dim: int, rotary_pct: float, theta: float,
+                     scaling=None) -> Tuple[int, jax.Array]:
+    """(rotary_dim, inv_freq[rotary_dim/2]); ``scaling`` (a
+    ``YarnScaling``) blends theta^(-2i/rot) and the same over ``factor``
+    by a linear ramp over the correction range."""
     rot = int(head_dim * rotary_pct)
     rot -= rot % 2
     inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
+    if scaling is not None:
+        low, high = yarn_correction_range(rot, theta, scaling)
+        if low == high:
+            high += 0.001
+        ramp = jnp.clip((jnp.arange(rot // 2, dtype=jnp.float32) - low)
+                        / (high - low), 0.0, 1.0)
+        extra = 1.0 - ramp              # 1 where the original frequency stays
+        inv = (inv / scaling.factor) * (1.0 - extra) + inv * extra
     return rot, inv
 
 
 def apply_rope(x: jax.Array, positions: jax.Array, rotary_pct: float,
-               theta: float) -> jax.Array:
+               theta: float, scaling=None) -> jax.Array:
     """x: (..., S, H, hd); positions: broadcastable to (..., S) int32."""
     hd = x.shape[-1]
-    rot, inv = rope_frequencies(hd, rotary_pct, theta)
+    rot, inv = rope_frequencies(hd, rotary_pct, theta, scaling)
     if rot == 0:
         return x
     ang = positions[..., :, None].astype(jnp.float32) * inv  # (..., S, rot/2)

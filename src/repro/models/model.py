@@ -24,6 +24,10 @@ from repro.models.config import ModelConfig
 from repro.sharding import shard
 
 AUX_WEIGHT_KEYS = {"moe_aux": "router_aux_weight", "moe_z": "router_z_weight"}
+# aux entries that count work (summed over layers), which the serving
+# steps hand back per call: the dropless MoE layer's pairs and experts hit
+COUNTER_KEYS = ("moe_pairs", "moe_experts_hit")
+_SCAN_AUX_KEYS = ("moe_aux", "moe_z", "moe_drop_frac")
 
 # Block kinds safe under right-padded batched prefill: attention kinds mask
 # pad keys via pos_ids == -1; mamba2 freezes its state on masked tokens.
@@ -56,13 +60,14 @@ class Model:
     def supports_paged_decode(self) -> bool:
         """True when decode KV state can live in a shared paged pool: every
         block is a full-attention kind (uniform cache width, no ring
-        eviction to translate) or carries fixed-size recurrent state
-        (mamba2, which simply stays slot-addressed). Windowed/chunked
-        attention keeps the contiguous ring; MLA's latent cache is a
-        future extension."""
+        eviction to translate; MLA's latent pages as one head of the
+        latent and one of the rope key) or carries fixed-size recurrent
+        state (mamba2, which simply stays slot-addressed).
+        Windowed/chunked attention keeps the contiguous ring."""
         kinds = set(self.prefix) | set(self.unit)
         return (not self.cfg.is_encdec
-                and kinds <= {"dense", "parallel", "moe", "shared", "mamba2"}
+                and kinds <= {"dense", "parallel", "moe", "shared", "mamba2",
+                              "mla", "mla_moe"}
                 and self.cfg.sliding_window is None
                 and self.cfg.attn_chunk is None)
 
@@ -75,7 +80,8 @@ class Model:
         resume and keep the monolithic prefill path for now."""
         kinds = set(self.prefix) | set(self.unit)
         return (self.supports_paged_decode
-                and kinds <= {"dense", "parallel", "moe", "shared"})
+                and kinds <= {"dense", "parallel", "moe", "shared", "mla",
+                              "mla_moe"})
 
     # ------------------------------------------------------------------ init
     def init(self, key) -> Dict:
@@ -201,7 +207,9 @@ class Model:
                     for k, v in a.items():
                         merged[k] = merged.get(k, 0.0) + v
                 pad_aux = {k: jnp.asarray(merged.get(k, 0.0), jnp.float32)
-                           for k in ("moe_aux", "moe_z", "moe_drop_frac")}
+                           for k in _SCAN_AUX_KEYS}
+                pad_aux.update({k: v for k, v in merged.items()
+                                if k in COUNTER_KEYS})
                 h = shard(h, "batch", "seq", "embed")
                 return (h, emb), (c_entry, pad_aux)
 
@@ -370,22 +378,35 @@ class Model:
             logits, idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
         return last, caches
 
-    def prefill_chunk(self, params, caches, tokens, mask):
+    def prefill_chunk(self, params, caches, tokens, mask,
+                      counters: bool = False):
         """Process one fixed-size prompt chunk against a paged slot view
         (``serving.paged.gather_slot_view``): the chunk's KV is appended to
         the slots' pages and its queries attend over each slot's whole
         logical history (prior chunks + itself, causally). tokens/mask:
         (n, C); positions continue from ``caches['t']``. Returns
-        (last-valid-token logits (n, vocab), caches)."""
-        logits, caches, _ = self.forward(params, tokens, {"mask": mask},
-                                         mode="chunk", caches=caches)
+        (last-valid-token logits (n, vocab), caches), and with
+        ``counters`` the chunk's ``COUNTER_KEYS`` aux (empty for a model
+        without them)."""
+        logits, caches, aux = self.forward(params, tokens, {"mask": mask},
+                                           mode="chunk", caches=caches)
+        if counters:
+            return logits[:, 0], caches, _counters(aux)
         return logits[:, 0], caches
 
-    def decode_step(self, params, caches, tokens):
-        """tokens: (B, 1) -> (logits (B, vocab), caches)."""
-        logits, caches, _ = self.forward(params, tokens, None,
-                                         mode="decode", caches=caches)
+    def decode_step(self, params, caches, tokens, counters: bool = False):
+        """tokens: (B, 1) -> (logits (B, vocab), caches), and with
+        ``counters`` the step's ``COUNTER_KEYS`` aux."""
+        logits, caches, aux = self.forward(params, tokens, None,
+                                           mode="decode", caches=caches)
+        if counters:
+            return logits[:, 0], caches, _counters(aux)
         return logits[:, 0], caches
+
+
+def _counters(aux: Dict) -> Dict:
+    return {k: jnp.asarray(aux[k], jnp.int32) for k in COUNTER_KEYS
+            if k in aux}
 
 
 def _masked_ce(logits: jax.Array, labels: jax.Array, vocab: int):

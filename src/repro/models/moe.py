@@ -1,4 +1,6 @@
-"""Mixture-of-experts FFN with capacity-based scatter dispatch.
+"""Mixture-of-experts FFN: a capacity layer with scatter dispatch
+(``moe_ffn``), and a dropless layer over the experts a chip holds
+(``moe_ffn_held``).
 
 TPU-native design choices:
 
@@ -14,10 +16,25 @@ TPU-native design choices:
 * Tokens beyond an expert's capacity are dropped (standard Switch behavior);
   the router's load-balance auxiliary loss keeps drops rare.
 
-DeepSeek-V3 specifics: 1 shared expert always active; routed top-8 with
-softmax-over-selected gates. (V3's sigmoid+bias-correction router and
-node-limited routing are modeled by the same capacity mechanism; noted in
-DESIGN.md.)
+The capacity layer's router is a softmax over the top-k logits. It is not
+DeepSeek-V3's published router (sigmoid scores with a bias correction,
+node-limited groups) and models nothing of it: the V3 preset runs this
+layer as a stand-in with V3's shapes.
+
+The dropless layer (``MoEConfig.dropless``) is DeepSeek-V2's published
+router and expert layer, cut to one chip's share of an expert-parallel
+deployment: the router (float32) scores all ``n_experts``, takes softmax
+over them and the greedy top-k, with gates not renormalised
+(DeepSeek-V2's ``norm_topk_prob`` false, ``routed_scaling_factor`` 1);
+the layer holds experts ``0 .. n_held_experts-1`` and computes every
+token-expert pair routed to one of them, whatever the batch, as grouped
+products (``jax.lax.ragged_dot``) over the pairs sorted by expert. Pairs
+routed to experts held elsewhere add nothing here. Scopes: ``moe_route``
+(router, top-k, dispatch, combine) and ``moe_experts`` (the held
+experts' products), inside the block's ``mlp`` with the shared experts.
+Its aux counts, summed over layers by the model: ``moe_pairs`` (pairs
+routed to held experts) and ``moe_experts_hit`` (held experts that
+received at least one pair).
 """
 from __future__ import annotations
 
@@ -35,13 +52,17 @@ def moe_init(key, cfg) -> Dict:
     dt = L.dtype_of(cfg)
     d = cfg.d_model
     ks = jax.random.split(key, 5)
+    E = m.held
+    # the dropless router is stored like the other weights (the published
+    # checkpoint's bf16) and computed in float32
     p = {
-        "router": L.dense_init(ks[0], d, m.n_experts, jnp.float32),
-        "experts_gate": L.normal(ks[1], (m.n_experts, d, m.d_ff_expert),
+        "router": L.dense_init(ks[0], d, m.n_experts,
+                               dt if m.dropless else jnp.float32),
+        "experts_gate": L.normal(ks[1], (E, d, m.d_ff_expert),
                                  1.0 / (d ** 0.5), dt),
-        "experts_up": L.normal(ks[2], (m.n_experts, d, m.d_ff_expert),
+        "experts_up": L.normal(ks[2], (E, d, m.d_ff_expert),
                                1.0 / (d ** 0.5), dt),
-        "experts_down": L.normal(ks[3], (m.n_experts, m.d_ff_expert, d),
+        "experts_down": L.normal(ks[3], (E, m.d_ff_expert, d),
                                  1.0 / (m.d_ff_expert ** 0.5), dt),
     }
     if m.n_shared_experts:
@@ -137,4 +158,60 @@ def moe_ffn(p: Dict, cfg, x: jax.Array) -> Tuple[jax.Array, Dict]:
         "moe_z": router_z_loss(rl),
         "moe_drop_frac": 1.0 - jnp.mean(keep.astype(jnp.float32)),
     }
+    return y.reshape(B, S, d), aux
+
+
+def router_softmax_topk(router_logits: jax.Array, top_k: int
+                        ) -> Tuple[jax.Array, jax.Array]:
+    """DeepSeek-V2's router: softmax over every expert's logit, then the
+    greedy top-k of those scores, not renormalised. Returns (gates (T, k)
+    float32, expert ids (T, k))."""
+    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)
+    return jax.lax.top_k(probs, top_k)
+
+
+def held_pairs(ids: jax.Array, gates: jax.Array, n_held: int):
+    """The token-expert pairs routed to held experts, sorted by expert:
+    (order (T*k,) pair index by expert, pairs to other experts last;
+    group_sizes (n_held,); weights (T*k,) float32 in that order, 0 for
+    pairs not held)."""
+    flat = ids.reshape(-1)
+    held = flat < n_held
+    order = jnp.argsort(jnp.where(held, flat, n_held), stable=True)
+    sizes = jnp.zeros((n_held,), jnp.int32).at[flat].add(1, mode="drop")
+    weights = jnp.where(held, gates.reshape(-1), 0.0)[order]
+    return order, sizes, weights
+
+
+def moe_ffn_held(p: Dict, cfg, x: jax.Array) -> Tuple[jax.Array, Dict]:
+    """The dropless layer over the held experts (module docstring).
+    x: (B, S, d) -> (B, S, d), and the counts of its pairs."""
+    m = cfg.moe
+    B, S, d = x.shape
+    xt = x.reshape(B * S, d)
+    with jax.named_scope("moe_route"):
+        rl = jnp.einsum("td,de->te", xt.astype(jnp.float32),
+                        p["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+        gates, ids = router_softmax_topk(rl, m.top_k)
+        order, sizes, weights = held_pairs(ids, gates, m.held)
+        tok = order // m.top_k                       # source token per row
+        xs = jnp.take(xt, tok, axis=0)               # (T*k, d), held first
+    with jax.named_scope("moe_experts"):
+        g = jax.lax.ragged_dot(xs, p["experts_gate"], sizes)
+        u = jax.lax.ragged_dot(xs, p["experts_up"], sizes)
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+        out = jax.lax.ragged_dot(h, p["experts_down"], sizes)
+    with jax.named_scope("moe_route"):
+        # rows past the held pairs belong to no group, and the grouped
+        # product does not promise what it leaves there (any bits, NaN
+        # among them): select them away, do not weigh them by zero
+        held = jnp.arange(out.shape[0]) < sizes.sum()
+        out = jnp.where(held[:, None], out.astype(jnp.float32), 0.0)
+        y = jnp.zeros((B * S, d), jnp.float32).at[tok].add(
+            out * weights[:, None]).astype(x.dtype)
+    if m.n_shared_experts:
+        y = y + L.swiglu(p["shared"], xt)
+    aux = {"moe_pairs": sizes.sum(),
+           "moe_experts_hit": (sizes > 0).sum(dtype=jnp.int32)}
     return y.reshape(B, S, d), aux

@@ -124,7 +124,9 @@ def _chunk_prefill_fn(model, params, caches, tokens, mask, slots, key, *,
     the last prompt token for first-token logits. Returns the chunk's
     last-valid-token logits (n, vocab) and the slots' block-table rows
     too, so the host can register the prompt's pages in the prefix index
-    at the last chunk without an extra sync."""
+    at the last chunk without an extra sync, and the model's counters
+    for the chunk (``Model.prefill_chunk``; {} for a model without
+    them)."""
     nv = mask.sum(axis=1).astype(jnp.int32)              # (n,) valid tokens
     t0 = caches["t"][slots]
     start_pg = (t0 + page_size - 1) // page_size
@@ -137,12 +139,13 @@ def _chunk_prefill_fn(model, params, caches, tokens, mask, slots, key, *,
             caches, slots, t0, nv, page_size,
             span=tokens.shape[1] // page_size + 1)
     view = paged.gather_slot_view(caches, slots)
-    last, view = model.prefill_chunk(params, view, tokens, mask)
+    last, view, counts = model.prefill_chunk(params, view, tokens, mask,
+                                             counters=True)
     caches = paged.scatter_slot_view(caches, view, slots)
     logits = last[:, :vocab]
     with jax.named_scope("sample"):
         first = sampling.sample(logits, key, temperature)
-    return first, logits, caches["paged"]["tbl"][slots], caches
+    return first, logits, caches["paged"]["tbl"][slots], caches, counts
 
 
 _PREFILL = jax.jit(_prefill_fn, static_argnums=(0,),
@@ -423,6 +426,18 @@ class ServingEngine:
         self.decode_chunks = 0                       # device->host syncs
         self.prefill_batches = 0
         self.prefill_chunks = 0                      # chunked-prefill launches
+        # the dropless MoE layer's counts (models/moe.py), summed over
+        # layers: token-expert pairs computed by the held experts and held
+        # experts that received a pair, per decode micro-step, and pairs
+        # of the chunked prefill. Fetched with the tokens the host already
+        # fetches; a chunk's counts wait on device until the next fetch.
+        self.counted = bool(model.cfg.moe is not None
+                            and model.cfg.moe.dropless)
+        self.moe_decode_pairs = 0
+        self.moe_decode_experts_hit = 0
+        self.moe_prefill_pairs = 0
+        self._pending_counts: List[Dict[str, jax.Array]] = []
+        self._quantum_counts: Dict[str, int] = {}
         # rid -> logits at the last prompt position (chunked path), kept on
         # device; None = not recorded. Set to {} to compare attention paths
         # (chip_smoke.py) without an extra host sync per request.
@@ -1432,13 +1447,15 @@ class ServingEngine:
             trace.tag(sp, "rids", (req.rid for req, _, _, _ in packed))
             slots_a = jnp.asarray([slot for _, slot, _, _ in packed],
                                   jnp.int32)
-            first, logits, tbl_rows, self.caches = _CHUNK_PREFILL(
+            first, logits, tbl_rows, self.caches, counts = _CHUNK_PREFILL(
                 self.model, self.params, self.caches, jnp.asarray(tokens),
                 jnp.asarray(mask), slots_a, self._next_key(),
                 vocab=self.model.cfg.vocab,
                 temperature=self.cfg.temperature,
                 page_size=self.cfg.page_size, sharing=self.sharing)
         self.prefill_chunks += 1
+        if counts:
+            self._pending_counts.append(counts)
         finished: List[int] = []
         for i, (req, slot, pos0, piece) in enumerate(packed):
             req.prefill_pos += len(piece)
@@ -1468,7 +1485,9 @@ class ServingEngine:
         # last chunks: the sampled tokens are the requests' first emissions
         # — ONE host sync for every request finishing in this launch
         with trace.span("prefill.fetch"):
-            first_h, rows_h = jax.device_get((first, tbl_rows))
+            first_h, rows_h, pending = jax.device_get(
+                (first, tbl_rows, self._pending_counts))
+        self._add_prefill_counts(pending)
         first_h, rows_h = np.asarray(first_h), np.asarray(rows_h)
         self.prefill_batches += 1      # one first-token host sync
         with trace.span("prefill.finish") as sp:
@@ -1571,14 +1590,23 @@ class ServingEngine:
                 max(max_steps - self._steps, 1))
         with trace.span("decode.launch"):
             (self.caches, self.cur_tokens, self.state, tok_mat,
-             emit_mat) = _FUSED_STEPS(
+             emit_mat, counts) = _FUSED_STEPS(
                 self.model, self.params, self.caches, self.cur_tokens,
                 self.state, self._next_key(), n_steps=n,
                 temperature=self.cfg.temperature,
                 page_size=self.cfg.page_size if self.paged else 0,
                 freeze_inactive=self.chunked)
         with trace.span("decode.fetch"):
-            tok_h, emit_h = jax.device_get((tok_mat, emit_mat))
+            tok_h, emit_h, counts_h, pending = jax.device_get(
+                (tok_mat, emit_mat, counts, self._pending_counts))
+        self._add_prefill_counts(pending)
+        if counts_h:
+            pairs = int(counts_h["moe_pairs"].sum())
+            hit = int(counts_h["moe_experts_hit"].sum())
+            self.moe_decode_pairs += pairs
+            self.moe_decode_experts_hit += hit
+            self._quantum_counts = {"moe_decode_pairs": pairs,
+                                    "moe_decode_experts_hit": hit}
         self.decode_chunks += 1
         self.peak_active = max(self.peak_active, self.active)
         with trace.span("decode.emit"):
@@ -1587,6 +1615,12 @@ class ServingEngine:
         # the trash page since their done flag rose mid-chunk)
         self._release_slots(released)
         return True
+
+    def _add_prefill_counts(self, fetched: List[Dict]) -> None:
+        """Add fetched chunk counts to the totals; the list on device is
+        emptied (they were fetched with this sync's tokens)."""
+        self.moe_prefill_pairs += sum(int(c["moe_pairs"]) for c in fetched)
+        self._pending_counts = []
 
     def _emit_decoded(self, n: int, tok_h: np.ndarray,
                       emit_h: np.ndarray) -> List[int]:
@@ -1646,6 +1680,10 @@ class ServingEngine:
                        else False)
             if self.paged:
                 self._count_pages(q)
+            if self._quantum_counts:
+                if trace.enabled():
+                    q.set_metadata(**self._quantum_counts)
+                self._quantum_counts = {}
         return bool(released or admitted or chunks or decoded)
 
     def _count_pages(self, q) -> None:
@@ -1775,6 +1813,12 @@ class ServingEngine:
                 "chunked": 1.0,
                 "prefill_chunk": self.cfg.prefill_chunk,
                 "prefill_chunks": self.prefill_chunks,
+            })
+        if self.counted:
+            out.update({
+                "moe_decode_pairs": self.moe_decode_pairs,
+                "moe_decode_experts_hit": self.moe_decode_experts_hit,
+                "moe_prefill_pairs": self.moe_prefill_pairs,
             })
         if self.sharing:
             out.update({

@@ -26,6 +26,13 @@ has identical occupancy, so one block table serves all layers)::
     ref  : (num_pages,)   int32 per-page reference count (# block-table
                           entries mapping the page; 0 = free)
 
+An MLA layer's latent leaf group has the same shape with other page
+leaves: ``ckv_pages`` (1, num_pages + 1, page_size, kv_lora_rank) holds
+the normed latent and ``krope_pages`` (1, ..., qk_rope_head_dim) the
+roped key, one head each. Everything below walks a group's page leaves
+(the keys ending in ``_pages``) whatever they are, so the allocator, the
+prefix index, copy-on-write, scrub, export and migrate treat both alike.
+
 Prefix sharing (PR 4): a physical page may be mapped by SEVERAL slots'
 block tables when their prompts share a page-aligned prefix — the engine's
 host-side prefix index maps token-chunk hashes to resident page runs and
@@ -72,9 +79,24 @@ import jax.numpy as jnp
 from repro.models.attention import (copy_page_rows, gather_pages,  # noqa: F401
                                     paged_decode_write)
 
-# keys identifying a pageable attention-KV leaf group inside a cache tree
-_KV_KEYS = {"k", "v", "pos_ids", "length"}
-_PAGED_KV_KEYS = {"k_pages", "v_pages", "pos_ids", "length"}
+# contiguous cache leaves that page, and the page leaf each becomes: an
+# attention KV group's keys and values, an MLA group's latent and rope key
+_PAGE_LEAF = {"k": "k_pages", "v": "v_pages", "ckv": "ckv_pages",
+             "k_rope": "krope_pages"}
+_SRC_LEAF = {v: k for k, v in _PAGE_LEAF.items()}
+_CURSORS = {"pos_ids", "length"}
+_KV_GROUPS = ({"k", "v"}, {"ckv", "k_rope"})
+_PAGES = "_pages"
+
+
+def _page_leaves(group: Dict):
+    """The page leaves of a paged leaf group, in the group's order."""
+    return [k for k in group if k.endswith(_PAGES)]
+
+
+def _is_paged_group(d) -> bool:
+    return isinstance(d, dict) and _CURSORS <= set(d) and any(
+        k.endswith(_PAGES) for k in d)
 
 
 # --------------------------------------------------------------- allocator
@@ -267,7 +289,7 @@ def pages_needed(n_tokens: int, page_size: int) -> int:
 def _walk_paged(leafgroup_fn, plain_fn, paged_fn, *trees):
     """Map parallel paged cache trees with one traversal skeleton.
 
-    ``leafgroup_fn(stacked, *groups)`` handles ``_PAGED_KV_KEYS`` leaf
+    ``leafgroup_fn(stacked, *groups)`` handles paged leaf
     groups, ``plain_fn(stacked, *leaves)`` everything else (e.g. the
     position counter ``t``), ``paged_fn(*allocators)`` the shared
     allocator at key ``"paged"``. ``stacked`` is True under the scanned
@@ -277,7 +299,7 @@ def _walk_paged(leafgroup_fn, plain_fn, paged_fn, *trees):
     """
     def walk(nodes, stacked):
         n0 = nodes[0]
-        if isinstance(n0, dict) and _PAGED_KV_KEYS <= set(n0):
+        if _is_paged_group(n0):
             return leafgroup_fn(stacked, *nodes)
         if isinstance(n0, dict):
             return {k: (paged_fn(*[nd[k] for nd in nodes]) if k == "paged"
@@ -323,29 +345,37 @@ def freeze_inactive_cursors(old: Dict, new: Dict,
 # ----------------------------------------------------------- cache layout
 
 
-def _is_kv_leafgroup(d) -> bool:
-    return isinstance(d, dict) and _KV_KEYS <= set(d) and d["k"].ndim >= 4
+def _data_leaves(d) -> list:
+    """A contiguous cache group's leaves that page: (k, v) of attention,
+    (ckv, k_rope) of MLA; [] for anything else."""
+    if not (isinstance(d, dict) and _CURSORS <= set(d)):
+        return []
+    for names in _KV_GROUPS:
+        if names <= set(d):
+            return [n for n in d if n in names]
+    return []
 
 
 def _paginate_leafgroup(d: Dict, page_size: int, num_pages: int) -> Dict:
-    k = d["k"]                       # ([R,] B, W, Hkv, hd)
-    W, H, hd = k.shape[-3], k.shape[-2], k.shape[-1]
-    assert W % page_size == 0, "cache width must be a page multiple"
-    lead = k.shape[:-4]              # () or (repeats,)
-    hd_v = d["v"].shape[-1]
-    return {
-        "k_pages": jnp.zeros(lead + (H, num_pages + 1, page_size, hd),
-                             k.dtype),
-        "v_pages": jnp.zeros(lead + (H, num_pages + 1, page_size, hd_v),
-                             d["v"].dtype),
-        "pos_ids": d["pos_ids"],     # stays LOGICAL: ([R,] B, W)
-        "length": d["length"],
-    }
+    out = {}
+    lead = d["pos_ids"].shape[:-2]   # () or (repeats,)
+    for name in _data_leaves(d):
+        # ([R,] B, W, Hkv, hd), or ([R,] B, W, width) for the latent
+        x = d[name]
+        heads = x.shape[len(lead) + 2:-1]            # (Hkv,) or ()
+        W = x.shape[len(lead) + 1]
+        assert W % page_size == 0, "cache width must be a page multiple"
+        out[_PAGE_LEAF[name]] = jnp.zeros(
+            lead + (heads or (1,)) + (num_pages + 1, page_size, x.shape[-1]),
+            x.dtype)
+    out["pos_ids"] = d["pos_ids"]    # stays LOGICAL: ([R,] B, W)
+    out["length"] = d["length"]
+    return out
 
 
 def _walk(node, fn):
     """Map ``fn`` over kv leaf-groups of a cache tree, preserving layout."""
-    if _is_kv_leafgroup(node):
+    if _data_leaves(node):
         return fn(node)
     if isinstance(node, dict):
         return {k: _walk(v, fn) for k, v in node.items()}
@@ -359,7 +389,7 @@ def paginate_cache(cache: Dict, max_batch: int, page_size: int,
     """Convert a contiguous slot-pool cache (model.init_cache) into the
     paged layout and attach the shared allocator at cache['paged']."""
     widths = []
-    _walk(cache, lambda d: (widths.append(d["k"].shape[-3]), d)[1])
+    _walk(cache, lambda d: (widths.append(d["pos_ids"].shape[-1]), d)[1])
     assert widths, "model has no attention KV caches to page"
     assert len(set(widths)) == 1, "paged pool needs uniform cache width"
     W = widths[0]
@@ -399,6 +429,8 @@ def insert_prefill_paged(pool, src, slots: jax.Array, cur_tokens: jax.Array,
     def scatter_rows(pages, src, stacked):
         # page-granular scatter: pages ([R,] H, P+1, ps, hd)
         #                        <- src ([R,] n_pad, W, H, hd)
+        if src.ndim == pages.ndim - 1:               # latent: one head
+            src = src[..., None, :]
         trash = pages.shape[-3] - 1
         pg = jnp.where(row_tbl < 0, trash, row_tbl)      # (n, M)
         ps, hd = page_size, pages.shape[-1]
@@ -417,14 +449,14 @@ def insert_prefill_paged(pool, src, slots: jax.Array, cur_tokens: jax.Array,
         else:
             pos = d["pos_ids"].at[slots].set(s["pos_ids"][:n])
             ln = d["length"].at[slots].set(s["length"][:n])
-        return {"k_pages": scatter_rows(d["k_pages"], s["k"], stacked),
-                "v_pages": scatter_rows(d["v_pages"], s["v"], stacked),
+        return {**{leaf: scatter_rows(d[leaf], s[_SRC_LEAF[leaf]], stacked)
+                   for leaf in _page_leaves(d)},
                 "pos_ids": pos, "length": ln}
 
     def walk(p, s, stacked):
         if p is None:
             return None
-        if isinstance(p, dict) and _PAGED_KV_KEYS <= set(p):
+        if _is_paged_group(p):
             return leafgroup(p, s, stacked)
         if isinstance(p, dict):
             return {k: (walk(v, s[k], stacked or k == "unit")
@@ -598,11 +630,17 @@ def cow_chunk_pages(pool: Dict, slots: jax.Array, start_tok: jax.Array,
     dst_pg = jnp.where(ok, fresh, -1).reshape(-1)        # -1 = dropped
 
     def leafgroup(stacked, d):
-        return {**d, "k_pages": copy_page_rows(d["k_pages"], src_pg, dst_pg),
-                "v_pages": copy_page_rows(d["v_pages"], src_pg, dst_pg)}
+        return {**d, **{n: copy_page_rows(d[n], src_pg, dst_pg)
+                        for n in _page_leaves(d)}}
 
     return _walk_paged(leafgroup, lambda stacked, x: x,
                        lambda a: alloc, pool)
+
+
+def _rows_key(page_leaf: str) -> str:
+    """An exported payload's key for a page leaf: ``k_pages`` ->
+    ``k_rows``."""
+    return page_leaf[:-len(_PAGES)] + "_rows"
 
 
 def export_slot(pool: Dict, slot: jax.Array, src_pg: jax.Array) -> Dict:
@@ -610,18 +648,19 @@ def export_slot(pool: Dict, slot: jax.Array, src_pg: jax.Array) -> Dict:
     SEND half of a cross-pool page migration.
 
     ``src_pg``: (M,) int32, the slot's physical pages in logical order,
-    -1 padded (its block-table row). Every KV leaf contributes the page
-    rows at those physical ids (``k_rows``/``v_rows``: ([R,] H, M, ps,
-    hd) — padded entries gather the trash page, whose contents are never
-    read back), plus the slot's cursors (``pos_ids``/``length``/``t``).
+    -1 padded (its block-table row). Every page leaf contributes the
+    page rows at those physical ids (``<name>_rows`` for
+    ``<name>_pages``, e.g. ``k_rows``/``v_rows``: ([R,] H, M, ps, hd) —
+    padded entries gather the trash page, whose contents are never read
+    back), plus the slot's cursors (``pos_ids``/``length``/``t``).
     The payload mirrors the cache tree's structure, so ``migrate_pages``
     can walk both in lockstep. An out-of-range ``slot`` (the fleet
     sentinel ``B``) clamps — callers mask the result before use."""
     def leafgroup(stacked, p):
-        pg = jnp.where(src_pg < 0, p["k_pages"].shape[-3] - 1, src_pg)
+        names = _page_leaves(p)
+        pg = jnp.where(src_pg < 0, p[names[0]].shape[-3] - 1, src_pg)
         return {
-            "k_rows": jnp.take(p["k_pages"], pg, axis=-3),
-            "v_rows": jnp.take(p["v_pages"], pg, axis=-3),
+            **{_rows_key(n): jnp.take(p[n], pg, axis=-3) for n in names},
             "pos_ids": (p["pos_ids"][:, slot] if stacked
                         else p["pos_ids"][slot]),
             "length": (p["length"][:, slot] if stacked
@@ -680,8 +719,8 @@ def migrate_pages(pool: Dict, slot: jax.Array, payload: Dict,
         return d.at[slot].set(value.astype(d.dtype), mode="drop")
 
     def leafgroup(stacked, p, pl):
-        return {"k_pages": scatter_rows(p["k_pages"], pl["k_rows"]),
-                "v_pages": scatter_rows(p["v_pages"], pl["v_rows"]),
+        return {**{n: scatter_rows(p[n], pl[_rows_key(n)])
+                   for n in _page_leaves(p)},
                 "pos_ids": rows_at(p["pos_ids"], pl["pos_ids"], stacked),
                 "length": rows_at(p["length"], pl["length"], stacked)}
 
@@ -716,7 +755,7 @@ def scatter_slot_view(pool: Dict, view: Dict, slots: jax.Array) -> Dict:
         return d.at[:, slots].set(s) if stacked else d.at[slots].set(s)
 
     def leafgroup(stacked, p, v):
-        return {"k_pages": v["k_pages"], "v_pages": v["v_pages"],
+        return {**{n: v[n] for n in _page_leaves(p)},
                 "pos_ids": rows(p["pos_ids"], v["pos_ids"], stacked),
                 "length": rows(p["length"], v["length"], stacked)}
 

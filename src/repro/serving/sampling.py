@@ -60,9 +60,12 @@ def fused_decode_steps(model, params, caches, cur_tokens: jax.Array,
     """Run ``n_steps`` fused engine micro-steps fully on device.
 
     cur_tokens: (B, 1) int32 — last token of every slot.
-    Returns (caches, cur_tokens, state, tok_mat, emit_mat) where
+    Returns (caches, cur_tokens, state, tok_mat, emit_mat, counts) where
     tok_mat/emit_mat are (n_steps, B): the sampled token per step and
-    whether the slot was active (i.e. the token is a real emission).
+    whether the slot was active (i.e. the token is a real emission), and
+    counts maps each of the model's counters (``Model.decode_step``'s
+    ``counters``: the dropless MoE layer's pairs and experts hit, summed
+    over layers) to its (n_steps,) values; {} for a model without them.
     Finished/free slots keep re-feeding their last token; their logits are
     computed but never read (same batch-shape invariance as the seed).
 
@@ -100,12 +103,14 @@ def fused_decode_steps(model, params, caches, cur_tokens: jax.Array,
             caches["paged"] = _paged.alloc_decode_pages(
                 caches["paged"], caches["t"], active, page_size)
             prev = caches
-            logits, caches = model.decode_step(params, caches, toks)
+            logits, caches, counts = model.decode_step(params, caches, toks,
+                                                       counters=True)
             if freeze_inactive:
                 caches = _paged.freeze_inactive_cursors(prev, caches,
                                                         active)
         else:
-            logits, caches = model.decode_step(params, caches, toks)
+            logits, caches, counts = model.decode_step(params, caches, toks,
+                                                       counters=True)
         with jax.named_scope("sample"):
             nxt = sample(logits[:, :vocab], k_i, temperature)
             nxt = jnp.where(active, nxt, toks[:, 0])
@@ -114,12 +119,13 @@ def fused_decode_steps(model, params, caches, cur_tokens: jax.Array,
             done = emitted & ((budget <= 0) |
                               ((state["eos"] >= 0) & (nxt == state["eos"])))
             active = active & ~done
-        return (caches, nxt[:, None], active, budget), (nxt, emitted)
+        return (caches, nxt[:, None], active, budget), (nxt, emitted, counts)
 
-    (caches, cur_tokens, active, budget), (tok_mat, emit_mat) = jax.lax.scan(
-        body, (caches, cur_tokens, state["active"], state["budget"]), keys)
+    (caches, cur_tokens, active, budget), (tok_mat, emit_mat, counts) = \
+        jax.lax.scan(body, (caches, cur_tokens, state["active"],
+                            state["budget"]), keys)
     new_state = {"active": active, "budget": budget, "eos": state["eos"]}
-    return caches, cur_tokens, new_state, tok_mat, emit_mat
+    return caches, cur_tokens, new_state, tok_mat, emit_mat, counts
 
 
 def insert_prefill(pool, src, slots: jax.Array, cur_tokens: jax.Array,

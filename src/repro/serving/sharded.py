@@ -103,7 +103,8 @@ def _fused_steps_fleet(model, mesh, params, caches, cur_tokens, state, keys,
             model, params, _lane(caches), _lane(cur_tokens), _lane(state),
             keys[0], n_steps=n_steps, temperature=temperature,
             page_size=page_size, freeze_inactive=True)
-        return tuple(_unlane(t) for t in out)
+        # the model's counters stay on the single engine (ServingEngine)
+        return tuple(_unlane(t) for t in out[:5])
 
     return shard_map(body, mesh=mesh,
                      in_specs=(P(), _SHARD, _SHARD, _SHARD, _SHARD),
@@ -114,7 +115,7 @@ def _fused_steps_fleet(model, mesh, params, caches, cur_tokens, state, keys,
 def _chunk_prefill_fleet(model, mesh, params, caches, tokens, mask, slots,
                          keys, *, vocab, temperature, page_size, sharing):
     def body(params, caches, tokens, mask, slots, keys):
-        first, _, rows, caches = _chunk_prefill_fn(
+        first, _, rows, caches, _ = _chunk_prefill_fn(
             model, params, _lane(caches), tokens[0], mask[0], slots[0],
             keys[0], vocab=vocab, temperature=temperature,
             page_size=page_size, sharing=sharing)

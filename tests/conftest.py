@@ -54,3 +54,37 @@ def make_extras(cfg, batch, seq, key=None, dtype=jnp.float32):
             key, batch, n_img, cfg.d_model, dtype)
         extras["image_positions"] = frontend.image_positions(batch, n_img, seq)
     return extras
+
+
+def tiny_latent_config(name="tiny-latent", vocab=256):
+    """DeepSeek-V2's block at a size the CPU holds: one dense MLA layer,
+    then two MLA layers with 8 of 16 routed experts held (top 3, dropless,
+    gates not renormalised) and 2 shared experts; YaRN rope."""
+    from repro.models import MLAConfig, MoEConfig, ModelConfig, YarnScaling
+    return ModelConfig(
+        name=name, family="moe", n_layers=3, d_model=64, n_heads=4,
+        n_kv_heads=4, d_ff=128, vocab=vocab, dtype="float32",
+        block_pattern=("mla",) + ("mla_moe",) * 2,
+        mla=MLAConfig(q_lora_rank=None, kv_lora_rank=32,
+                      qk_nope_head_dim=16, qk_rope_head_dim=8,
+                      v_head_dim=16),
+        moe=MoEConfig(n_experts=16, top_k=3, d_ff_expert=32,
+                      n_shared_experts=2, dropless=True, n_held_experts=8),
+        rope_scaling=YarnScaling(), norm_eps=1e-6, vocab_pad_multiple=8)
+
+
+def tiny_model(kind, name, vocab=256):
+    """(cfg, model, params) of the suites' tiny models: ``dense`` (GQA 8/2,
+    two layers) or ``latent`` (``tiny_latent_config``)."""
+    from repro.models import Model, ModelConfig
+    from repro.models.config import repeat_pattern
+    if kind == "latent":
+        cfg = tiny_latent_config(name, vocab)
+    else:
+        cfg = ModelConfig(
+            name=name, family="dense", n_layers=2, d_model=64, n_heads=8,
+            n_kv_heads=2, d_ff=128, vocab=vocab, dtype="float32",
+            block_pattern=repeat_pattern(("dense",), 2),
+            vocab_pad_multiple=8)
+    m = Model(cfg)
+    return cfg, m, m.init(jax.random.PRNGKey(0))

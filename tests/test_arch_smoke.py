@@ -93,6 +93,7 @@ def test_full_config_matches_assignment(arch):
         "stablelm-12b": (40, 5120, 32, 8, 100352),
         "internlm2-20b": (48, 6144, 48, 8, 92544),
         "llama3.2-1b": (16, 2048, 32, 8, 128256),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 102400),
     }[arch]
     assert (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
             cfg.vocab) == spec
@@ -100,7 +101,8 @@ def test_full_config_matches_assignment(arch):
            "seamless-m4t-large-v2": 8192, "zamba2-7b": 14336,
            "llama4-maverick-400b-a17b": 8192, "minicpm-2b": 5760,
            "rwkv6-1.6b": 7168, "stablelm-12b": 13824,
-           "internlm2-20b": 16384, "llama3.2-1b": 8192}[arch]
+           "internlm2-20b": 16384, "llama3.2-1b": 8192,
+           "deepseek-v2-lite": 1408}[arch]
     if cfg.moe is not None and arch != "llama4-maverick-400b-a17b":
         assert cfg.moe.d_ff_expert == dff
     else:
@@ -109,5 +111,8 @@ def test_full_config_matches_assignment(arch):
         assert cfg.moe.n_experts == 256 and cfg.moe.top_k == 8 and cfg.mtp
     if arch == "llama4-maverick-400b-a17b":
         assert cfg.moe.n_experts == 128 and cfg.moe.top_k == 1
+    if arch == "deepseek-v2-lite":
+        assert (cfg.moe.n_experts, cfg.moe.top_k, cfg.moe.held) == (64, 6, 8)
+        assert cfg.mla.q_lora_rank is None and cfg.d_ff == 10944
     if arch == "zamba2-7b":
         assert cfg.ssm.state_dim == 64

@@ -17,24 +17,19 @@ import jax
 import numpy as np
 import pytest
 
-from repro.models import Model, ModelConfig
-from repro.models.config import repeat_pattern
+from conftest import tiny_model
 from repro.serving import EngineConfig, Request, ServingEngine
 from repro.serving.preempt import pick_victim
 
 PS = 4                                 # page size exercised in the suite
 CH = 8                                 # prefill chunk size
+# dense KV pages and MLA's latent pages through the same eviction paths
+KINDS = pytest.mark.parametrize("parts", ["dense", "latent"], indirect=True)
 
 
 @pytest.fixture(scope="module")
-def parts():
-    cfg = ModelConfig(
-        name="tiny-preempt", family="dense", n_layers=2, d_model=64,
-        n_heads=8, n_kv_heads=2, d_ff=128, vocab=256, dtype="float32",
-        block_pattern=repeat_pattern(("dense",), 2), vocab_pad_multiple=8)
-    m = Model(cfg)
-    params = m.init(jax.random.PRNGKey(0))
-    return cfg, m, params
+def parts(request):
+    return tiny_model(getattr(request, "param", "dense"), "tiny-preempt")
 
 
 class CheckedPreemptEngine(ServingEngine):
@@ -114,6 +109,7 @@ def _reqs(rids, lens, max_new=16, **kw):
 # ------------------------------------------------------------------ parity
 
 
+@KINDS
 def test_preemption_parity_and_invariants(parts):
     """Two long low-priority decodes occupy both slots; a high-priority
     arrival evicts one. Every request's tokens match the unpreempted
@@ -173,6 +169,7 @@ def test_partially_shared_victim_parity(parts):
     assert eng.free_pages == eng.num_pages
 
 
+@KINDS
 def test_resume_hits_pinned_prefix(parts):
     """The pin does its job: the resumed request's prefill skips the
     pinned pages (prefix hit) instead of recomputing the whole prompt."""

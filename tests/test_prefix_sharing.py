@@ -20,24 +20,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.models import Model, ModelConfig
-from repro.models.config import repeat_pattern
+from conftest import tiny_model
 from repro.serving import EngineConfig, Request, ServingEngine
 from repro.serving import paged as PG
 
 PS = 4                                 # page size exercised in the suite
 CH = 8                                 # prefill chunk size
+# dense KV pages and MLA's latent pages through the same sharing paths
+KINDS = pytest.mark.parametrize("parts", ["dense", "latent"], indirect=True)
 
 
 @pytest.fixture(scope="module")
-def parts():
-    cfg = ModelConfig(
-        name="tiny-prefix", family="dense", n_layers=2, d_model=64,
-        n_heads=8, n_kv_heads=2, d_ff=128, vocab=256, dtype="float32",
-        block_pattern=repeat_pattern(("dense",), 2), vocab_pad_multiple=8)
-    m = Model(cfg)
-    params = m.init(jax.random.PRNGKey(0))
-    return cfg, m, params
+def parts(request):
+    return tiny_model(getattr(request, "param", "dense"), "tiny-prefix")
 
 
 class CheckedEngine(ServingEngine):
@@ -154,6 +149,7 @@ def test_no_common_prefix_is_inert(parts):
     assert_pool_clean(eng)
 
 
+@KINDS
 @POOLS
 def test_shared_system_prompt_parity_and_hits(parts, num_pages):
     """A long-lived donor plus followers repeating its 2-page system
@@ -215,6 +211,7 @@ def test_prefix_ends_mid_page_tail_is_private(parts, num_pages):
 # ------------------------------------------------- whole-prompt share + CoW
 
 
+@KINDS
 def test_whole_prompt_shared_triggers_cow(parts):
     """Follower prompt == 3 whole shared pages: the last token is
     recomputed for first-token logits, which writes into the shared tail
@@ -259,9 +256,14 @@ def test_whole_prompt_shared_triggers_cow(parts):
     assert_pool_clean(eng)
 
 
-def test_cow_copies_page_rows_exactly(parts):
-    """Allocator+pool level: cow_chunk_pages must copy the page's KV rows
-    bit-for-bit into the fresh page and leave the original untouched."""
+@pytest.mark.parametrize("leaves", [("k_pages", "v_pages"),
+                                    ("ckv_pages", "krope_pages")],
+                         ids=["kv", "latent"])
+def test_cow_copies_page_rows_exactly(leaves):
+    """Allocator+pool level: cow_chunk_pages must copy the page's rows in
+    every page leaf bit-for-bit into the fresh page and leave the original
+    untouched (a KV group's keys and values, a latent group's latent and
+    rope key)."""
     P, B, M = 6, 2, 3
     alloc = PG.init_allocator(B, M, P)
     alloc = PG.alloc_prefill_pages(alloc, jnp.asarray([0]),
@@ -270,7 +272,8 @@ def test_cow_copies_page_rows_exactly(parts):
     alloc = PG.map_shared_pages(alloc, jnp.asarray(1), pages)
     rng = np.random.default_rng(0)
     kv = jnp.asarray(rng.normal(size=(1, P + 1, PS, 2)), jnp.float32)
-    tree = {"layer": {"k_pages": kv, "v_pages": kv + 1.0,
+    first, second = leaves
+    tree = {"layer": {first: kv, second: kv + 1.0,
                       "pos_ids": jnp.full((B, M * PS), -1, jnp.int32),
                       "length": jnp.zeros((B,), jnp.int32)},
             "t": jnp.zeros((B,), jnp.int32), "paged": alloc}
@@ -285,11 +288,11 @@ def test_cow_copies_page_rows_exactly(parts):
     assert int(np.asarray(a["ref"])[old]) == 1
     assert int(np.asarray(a["ref"])[new]) == 1
     assert int(np.asarray(a["tbl"])[1, 0]) == int(np.asarray(a["tbl"])[0, 0])
-    got = jax.device_get(out["layer"]["k_pages"])
-    np.testing.assert_array_equal(np.asarray(got)[:, new],
-                                  np.asarray(got)[:, old])
-    np.testing.assert_array_equal(np.asarray(got)[:, old],
-                                  np.asarray(jax.device_get(kv))[:, old])
+    for name, src in ((first, kv), (second, kv + 1.0)):
+        got = np.asarray(jax.device_get(out["layer"][name]))
+        np.testing.assert_array_equal(got[:, new], got[:, old])
+        np.testing.assert_array_equal(got[:, old],
+                                      np.asarray(jax.device_get(src))[:, old])
     # untouched pages are bit-identical
     assert int(a["top"]) == P - 3
 

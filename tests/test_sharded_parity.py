@@ -19,8 +19,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.models import Model, ModelConfig
-from repro.models.config import repeat_pattern
+from conftest import tiny_model
 from repro.serving import (EngineConfig, Request, ServingEngine,
                            ShardedServingEngine)
 
@@ -34,15 +33,13 @@ def _fleet_devices(host_devices):
     host_devices(S)
 
 
+# dense KV pages and MLA's latent pages through the same fleet programs
+KINDS = pytest.mark.parametrize("parts", ["dense", "latent"], indirect=True)
+
+
 @pytest.fixture(scope="module")
-def parts():
-    cfg = ModelConfig(
-        name="tiny-sharded", family="dense", n_layers=2, d_model=64,
-        n_heads=8, n_kv_heads=2, d_ff=128, vocab=256, dtype="float32",
-        block_pattern=repeat_pattern(("dense",), 2), vocab_pad_multiple=8)
-    m = Model(cfg)
-    params = m.init(jax.random.PRNGKey(0))
-    return cfg, m, params
+def parts(request):
+    return tiny_model(getattr(request, "param", "dense"), "tiny-sharded")
 
 
 def run_single(m, params, reqs, **kw):
@@ -97,6 +94,7 @@ def _reqs(rng, lens, max_new=9):
 # ------------------------------------------------------------------ parity
 
 
+@KINDS
 def test_mid_stream_admission_token_for_token(parts):
     """More requests than fleet slots (12 > 4 shards x 2): later requests
     admit mid-stream onto whichever shard frees pages first, interleaving
@@ -220,6 +218,7 @@ def test_requires_paged_and_chunked(parts):
 # ----------------------------------------------------- shard-local sharing
 
 
+@KINDS
 def test_prefix_sharing_is_shard_local(parts):
     """Followers of a resident prefix are steered to the shard HOLDING it
     and adopt its pages by refcount; parity vs the unshared single-device
