@@ -320,18 +320,72 @@ def decode_write_cache(cache: Dict, k1: jax.Array, v1: jax.Array) -> Dict:
 # ---- live here with the rest of the cache code) ---------------------------
 #
 # Paged cache leaf group: {"k_pages","v_pages": (Hkv, num_pages+1, ps, *),
-# "pos_ids": (B, W) LOGICAL (-1 empty), "length": (B,)}. Head-major so a
-# (Hkv, (num_pages+1)*ps, *) reshape makes every append/gather a single
-# flat-row advanced index. The last physical page is a TRASH page: writes
-# by slots with no mapped page (finished slots coasting inside a fused
-# chunk) land there, and unmapped logical pages gather from there — always
-# masked because the logical pos_ids row is -1.
+# "pos_ids": (B, W) LOGICAL (-1 empty), "length": (B,)}. Head-major, so a
+# row is addressed by (page, offset) and a (Hkv, (num_pages+1)*ps, *)
+# reshape is the flat pool that decode scores in place. The last physical
+# page is a TRASH page: writes by slots with no mapped page (finished slots
+# coasting inside a fused chunk) land there, and unmapped logical pages
+# gather from there — always masked because the logical pos_ids row is -1.
+#
+# Inside the model's layer scan a group's page leaves are the whole STACKED
+# pool ([L,] Hkv, num_pages+1, ps, *), carried from layer to layer, and the
+# group holds its layer's index under ``PAGE_LAYER``: writes scatter the
+# new rows straight into [layer, :, page, offset] and reads take the layer
+# where it lies, so no layer's pages are sliced out and written back.
+
+PAGES = "_pages"         # suffix of a paged group's page leaves
+PAGE_LAYER = "layer"     # a scanned group's index into its stacked leaves
 
 
-def _flat_rows(pages: jax.Array):
-    """(Hkv, P+1, ps, hd) -> ((Hkv, (P+1)*ps, hd) view, ps, trash page)."""
+def page_leaf_names(group) -> list:
+    """The page leaves of a paged leaf group, in the group's order; [] for
+    anything else (a contiguous cache group, a recurrent state, None)."""
+    if not isinstance(group, dict):
+        return []
+    return [k for k in group if k.endswith(PAGES)]
+
+
+def _page_dims(cache: Dict, name: str) -> Tuple[int, int]:
+    """(pages incl. trash, page size) of one page leaf, stacked or not."""
+    return cache[name].shape[-3], cache[name].shape[-2]
+
+
+def _layer_pages(cache: Dict, name: str) -> jax.Array:
+    """One layer's (Hkv, P+1, ps, *) page leaf: the leaf itself, or the
+    group's layer of the stacked pool (a dynamic index the consuming op
+    reads through)."""
+    leaf = cache[name]
+    layer = cache.get(PAGE_LAYER)
+    return leaf if layer is None else leaf[layer]
+
+
+def _write_rows(cache: Dict, name: str, pg: jax.Array, off: jax.Array,
+                rows: jax.Array) -> jax.Array:
+    """Scatter ``rows`` (*idx, Hkv, width) into page leaf ``name`` at
+    physical (page, offset) pairs ``pg``/``off`` (*idx): one scatter into
+    the leaf as it lies, the stacked pool at the group's layer included.
+
+    Into the stacked pool, one slot's row (decode, ``pg`` of shape (B,))
+    is written as Hkv rows of ``width`` (the head an index, like the
+    page), and a chunk's rows (``pg`` (B, S)) each as one (Hkv, width)
+    window: the forms under which XLA's TPU scatter updates the pool in
+    its own layout, where the other form makes it copy the pool to
+    another layout first (AOT compile for v5e; PERF.md §3)."""
+    leaf = cache[name]
+    rows = rows.astype(leaf.dtype)
+    layer = cache.get(PAGE_LAYER)
+    if layer is None:                   # indexed shape (Hkv, *idx, width)
+        return leaf.at[:, pg, off].set(jnp.moveaxis(rows, -2, 0))
+    if pg.ndim == 1:                    # indexed shape (B, Hkv, width)
+        h = jnp.arange(leaf.shape[1])
+        return leaf.at[layer, h, pg[:, None], off[:, None]].set(rows)
+    return leaf.at[layer, :, pg, off].set(rows)   # (B, S, Hkv, width)
+
+
+def _flat_rows(pages: jax.Array) -> jax.Array:
+    """(Hkv, P+1, ps, hd) -> the flat pool (Hkv, (P+1)*ps, hd)."""
     H, P1, ps, hd = pages.shape
-    return pages.reshape(H, P1 * ps, hd), ps, P1 - 1
+    return pages.reshape(H, P1 * ps, hd)
 
 
 def paged_decode_write(cache: Dict, tbl: jax.Array, k1: jax.Array,
@@ -345,8 +399,7 @@ def _paged_decode_write(cache: Dict, tbl: jax.Array, new: Dict) -> Dict:
     leaf of the group (``k_pages``/``v_pages``, or the latent's
     ``ckv_pages``/``krope_pages``) to its (B, 1, Hkv, width) row."""
     t = cache["length"]
-    flat = {n: _flat_rows(cache[n])[0] for n in new}
-    _, P1, ps, _ = cache[next(iter(new))].shape
+    P1, ps = _page_dims(cache, next(iter(new)))
     trash = P1 - 1
     B = t.shape[0]
     M = tbl.shape[1]
@@ -354,14 +407,12 @@ def _paged_decode_write(cache: Dict, tbl: jax.Array, new: Dict) -> Dict:
     bidx = jnp.arange(B)
     lp = t // ps
     pg = tbl[bidx, jnp.clip(lp, 0, M - 1)]
-    pg = jnp.where((pg < 0) | (lp >= M), trash, pg)
-    rows = pg * ps + t % ps                          # physical flat row (B,)
+    pg = jnp.where((pg < 0) | (lp >= M), trash, pg)  # physical page (B,)
     t_c = jnp.clip(t, 0, W - 1)
-    flat = {n: f.at[:, rows].set(jnp.swapaxes(new[n][:, 0], 0, 1)
-                                 .astype(f.dtype))
-            for n, f in flat.items()}
     return {
-        **{n: f.reshape(cache[n].shape) for n, f in flat.items()},
+        **cache,
+        **{n: _write_rows(cache, n, pg, t % ps, r[:, 0])
+           for n, r in new.items()},
         "pos_ids": cache["pos_ids"].at[bidx, t_c].set(t),
         "length": t + 1,
     }
@@ -386,8 +437,7 @@ def _paged_chunk_write(cache: Dict, tbl: jax.Array, new: Dict,
     """``paged_chunk_write`` for any page leaves, as ``_paged_decode_write``
     (``new``: page leaf -> (B, S, Hkv, width))."""
     t = positions
-    flat = {n: _flat_rows(cache[n])[0] for n in new}
-    _, P1, ps, _ = cache[next(iter(new))].shape
+    P1, ps = _page_dims(cache, next(iter(new)))
     trash = P1 - 1
     B, S = t.shape
     M = tbl.shape[1]
@@ -396,13 +446,12 @@ def _paged_chunk_write(cache: Dict, tbl: jax.Array, new: Dict,
     valid = t >= 0
     lp = jnp.where(valid, t // ps, M)                # pads -> out of range
     pg = tbl[bidx, jnp.clip(lp, 0, M - 1)]
-    pg = jnp.where(valid & (lp < M) & (pg >= 0), pg, trash)
-    rows = pg * ps + jnp.where(valid, t % ps, 0)     # (B, S) physical rows
-    flat = {n: f.at[:, rows].set(jnp.moveaxis(new[n], 2, 0).astype(f.dtype))
-            for n, f in flat.items()}
+    pg = jnp.where(valid & (lp < M) & (pg >= 0), pg, trash)   # (B, S)
+    off = jnp.where(valid, t % ps, 0)
     col = jnp.where(valid, jnp.clip(t, 0, W - 1), W)  # W = dropped
     return {
-        **{n: f.reshape(cache[n].shape) for n, f in flat.items()},
+        **cache,
+        **{n: _write_rows(cache, n, pg, off, r) for n, r in new.items()},
         "pos_ids": cache["pos_ids"].at[bidx, col].set(t, mode="drop"),
         "length": cache["length"] + valid.sum(axis=1).astype(jnp.int32),
     }
@@ -436,16 +485,30 @@ def copy_page_rows(pages: jax.Array, src_pg: jax.Array,
     return pages.at[:, :, dst].set(src, mode="drop")
 
 
-def gather_pages_hb(pages: jax.Array, tbl: jax.Array) -> jax.Array:
+def gather_pages_hb(pages: jax.Array, tbl: jax.Array,
+                    layer: Optional[jax.Array] = None) -> jax.Array:
     """Head-major logical view (Hkv, B, W, hd) of a page pool, as ONE
     page-granular gather with no transpose — the decode hot path's layout
     (the attention einsums contract it in place). The Pallas path instead
     chases the block table inside the kernel (kernels/decode_attention.py).
+    With ``layer``, ``pages`` is the stacked (L, Hkv, P+1, ps, hd) pool and
+    the gather reads that layer's pages from it directly.
     """
-    H, P1, ps, hd = pages.shape
+    P1, ps, hd = pages.shape[-3:]
     safe = jnp.where(tbl < 0, P1 - 1, tbl)           # (B, M)
-    g = pages[:, safe]                               # (H, B, M, ps, hd)
-    return g.reshape(H, tbl.shape[0], tbl.shape[1] * ps, hd)
+    if layer is None:
+        g = pages[:, safe]                           # (H, B, M, ps, hd)
+    else:
+        H = pages.shape[1]
+        idx = jnp.stack([jnp.broadcast_to(layer, safe.shape), safe], -1)
+        g = jax.lax.gather(
+            pages, idx.astype(jnp.int32),
+            jax.lax.GatherDimensionNumbers(
+                offset_dims=(0, 3, 4), collapsed_slice_dims=(0, 2),
+                start_index_map=(0, 2)),
+            slice_sizes=(1, H, 1, ps, hd),
+            mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS)
+    return g.reshape(g.shape[0], tbl.shape[0], tbl.shape[1] * ps, hd)
 
 
 def gather_pages(pages: jax.Array, tbl: jax.Array) -> jax.Array:
@@ -493,11 +556,12 @@ def _pool_decode_attention(qs, cache, page_tbl, positions, window, chunk,
     decode scores its latent query against ``ckv_pages`` and its rope
     query against ``krope_pages``, one latent kv head of group Hq, and
     sums values from ``ckv_pages``). ``scale`` defaults to width^-0.5 of
-    a single query part."""
-    kfs = [_flat_rows(cache[k])[0] for k in keys]
-    vf, _, _ = _flat_rows(cache[values])
+    a single query part. A scanned group's leaves are read at its layer
+    of the stacked pool."""
+    kfs = [_flat_rows(_layer_pages(cache, k)) for k in keys]
+    vf = _flat_rows(_layer_pages(cache, values))
     kpos = _pool_key_positions(page_tbl, cache["pos_ids"],
-                               cache[keys[0]].shape[1])
+                               _page_dims(cache, keys[0])[0])
     bias = self_attn_bias(positions, kpos, window, chunk)   # (B, 1, R)
     B, _, Hq, hd = qs[0].shape
     n_kv = vf.shape[0]
@@ -638,8 +702,8 @@ def _cached_attention(cfg, q, k, v, positions, layer_window, layer_chunk,
             # group, independent of chunk size
             out = jnp.moveaxis(
                 KOPS.chunked_prefill_attention(
-                    jnp.moveaxis(q, 1, 2), cache["k_pages"],
-                    cache["v_pages"], page_tbl, positions,
+                    jnp.moveaxis(q, 1, 2), _layer_pages(cache, "k_pages"),
+                    _layer_pages(cache, "v_pages"), page_tbl, positions,
                     cache["pos_ids"], window=layer_window,
                     chunk=layer_chunk, impl=cfg.attn_impl),
                 1, 2)                           # (B, S, Hq, hd_v)
@@ -648,7 +712,8 @@ def _cached_attention(cfg, q, k, v, positions, layer_window, layer_chunk,
             # redirects each program's page DMA — still one HBM read
             # per (batch, kv head, logical page)
             out = KOPS.paged_decode_attention(
-                q[:, 0], cache["k_pages"], cache["v_pages"], page_tbl,
+                q[:, 0], _layer_pages(cache, "k_pages"),
+                _layer_pages(cache, "v_pages"), page_tbl,
                 positions[:, 0], cache["pos_ids"],
                 window=layer_window, chunk=layer_chunk,
                 impl=cfg.attn_impl)[:, None]    # (B, 1, Hq, hd)
@@ -683,8 +748,9 @@ def _cached_attention(cfg, q, k, v, positions, layer_window, layer_chunk,
             # logical pos -1 and mask to exactly-zero softmax weight, so
             # this is bit-identical to the contiguous slot pool ("bskd").
             if paged:
-                kk = gather_pages_hb(cache["k_pages"], page_tbl)
-                vv = gather_pages_hb(cache["v_pages"], page_tbl)
+                layer = cache.get(PAGE_LAYER)
+                kk = gather_pages_hb(cache["k_pages"], page_tbl, layer)
+                vv = gather_pages_hb(cache["v_pages"], page_tbl, layer)
                 kv_layout, n_kv = "kbsd", kk.shape[0]
             else:
                 kk = shard(cache["k"], "batch", "kv_seq", "kv_heads",
@@ -707,8 +773,8 @@ def _cached_attention(cfg, q, k, v, positions, layer_window, layer_chunk,
             out = out.reshape(B_, Sq_, -1, hd)
         else:
             if paged:
-                ck = gather_pages(cache["k_pages"], page_tbl)
-                cv = gather_pages(cache["v_pages"], page_tbl)
+                ck = gather_pages(_layer_pages(cache, "k_pages"), page_tbl)
+                cv = gather_pages(_layer_pages(cache, "v_pages"), page_tbl)
             else:
                 ck, cv = cache["k"], cache["v"]
             kk = _expand_kv(cfg, ck)
@@ -795,10 +861,12 @@ def _mla_chunk_attention(q_nope, q_rope, cache, tbl, positions, w_uk, w_uv,
     history it has, not ``max_len``.
 
     q_nope: (B, S, H, n); q_rope: (B, S, H, r); w_uk: (kvr, H, n); w_uv:
-    (kvr, H, v). Returns (B, S, H, v)."""
-    cf = _flat_rows(cache["ckv_pages"])[0][0]          # (R, kvr)
-    rf = _flat_rows(cache["krope_pages"])[0][0]        # (R, r)
-    _, P1, ps, _ = cache["ckv_pages"].shape
+    (kvr, H, v). Returns (B, S, H, v). A block's rows are gathered from
+    the page leaves as they lie (a scanned group's layer of the stacked
+    pool included)."""
+    P1, ps = _page_dims(cache, "ckv_pages")
+    layer = cache.get(PAGE_LAYER)
+    lead = (0,) if layer is None else (layer, 0)       # the one latent head
     B, S, H, _ = q_nope.shape
     vd = w_uv.shape[-1]
     W = cache["pos_ids"].shape[1]
@@ -810,8 +878,9 @@ def _mla_chunk_attention(q_nope, q_rope, cache, tbl, positions, w_uk, w_uv,
         m_, l_, acc = carry
         pages = jax.lax.dynamic_slice_in_dim(tbl, j * pb, pb, axis=1)
         pages = jnp.where(pages < 0, P1 - 1, pages)    # unmapped -> trash
-        rows = (pages[:, :, None] * ps + jnp.arange(ps)).reshape(B, kb)
-        ck, kr = cf[rows], rf[rows]                    # (B, kb, kvr|r)
+        at = lead + (pages[:, :, None], jnp.arange(ps))
+        ck, kr = (cache[n][at].reshape(B, kb, -1)      # (B, kb, kvr|r)
+                  for n in LATENT_PAGES)
         kpos = jax.lax.dynamic_slice_in_dim(cache["pos_ids"], j * kb, kb,
                                             axis=1)
         k_nope = jnp.einsum("bkr,rhn->bkhn", ck, w_uk)

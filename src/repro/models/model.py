@@ -7,6 +7,15 @@ with stacked params — compile time is O(prefix + unit), not O(depth), which
 is what makes the 100-layer dry-runs compile in minutes. Zamba2's
 weight-shared attention block is closed over by the scan body (one param
 set, per-repeat KV caches ride through the scan's xs/ys).
+
+A paged KV group's page leaves are the exception: they ride in the scan's
+CARRY, stacked as they are, with the layer index in xs, and each layer
+writes its new rows into the stacked pool and reads its layer where it
+lies (``attention.PAGE_LAYER``). As xs/ys, every layer would slice its
+pages out and write them back whole, and the scan's output pool could not
+alias its input, so a fused decode loop would copy the pool every
+micro-step. Cursors (``pos_ids``/``length``) and every other cache keep
+the xs/ys form.
 """
 from __future__ import annotations
 
@@ -184,21 +193,29 @@ class Model:
             needs_emb = "shared" in unit
 
             def unit_body(carry, xs):
-                h, emb = carry
+                h, emb, pages = carry
                 # pin the residual-stream sharding: conflicting uses inside
                 # the body (head-sharded attention vs all-axes-sharded MoE
                 # shard_map) otherwise degrade the scan carry to replicated
                 # (EXPERIMENTS.md SSPerf H1 iter 3)
                 h = shard(h, "batch", "seq", "embed")
-                p_entry, c_entry = xs
+                p_entry, c_entry, layer = xs
+                pages = dict(pages)
                 aux_list = []
                 for i, kind in enumerate(unit):
                     ctx_i = dataclasses.replace(
                         ctx, layer_idx=self.prefix_len + i, emb_orig=emb)
                     p_i = shared_p if kind == "shared" else p_entry[str(i)]
                     c_i = None if c_entry is None else c_entry[str(i)]
+                    pg_i = pages.get(str(i))
+                    if pg_i is not None:    # the stacked pool at this layer
+                        c_i = {**c_i, **pg_i, A.PAGE_LAYER: layer}
                     h, c_i, aux = B.block_apply(p_i, cfg, kind, ctx_i, h, c_i)
                     aux_list.append(aux)
+                    if pg_i is not None:
+                        pages[str(i)] = {n: c_i[n] for n in pg_i}
+                        c_i = {n: v for n, v in c_i.items()
+                               if n not in pg_i and n != A.PAGE_LAYER}
                     if c_entry is not None:
                         c_entry = dict(c_entry)
                         c_entry[str(i)] = c_i
@@ -211,7 +228,7 @@ class Model:
                 pad_aux.update({k: v for k, v in merged.items()
                                 if k in COUNTER_KEYS})
                 h = shard(h, "batch", "seq", "embed")
-                return (h, emb), (c_entry, pad_aux)
+                return (h, emb, pages), (c_entry, pad_aux)
 
             body = unit_body
             if remat:
@@ -226,14 +243,25 @@ class Model:
 
                 def body_nc(carry, p_entry_and_pad):
                     p_entry, _ = p_entry_and_pad
-                    return body(carry, (p_entry, None))
+                    return body(carry, (p_entry, None, None))
 
-                (x, _), (_, aux_scan) = jax.lax.scan(
-                    body_nc, (x, ctx.emb_orig), xs)
+                (x, _, _), (_, aux_scan) = jax.lax.scan(
+                    body_nc, (x, ctx.emb_orig, {}), xs)
                 new_unit = None
             else:
-                (x, _), (new_unit, aux_scan) = jax.lax.scan(
-                    body, (x, ctx.emb_orig), (params["unit"], unit_caches))
+                # page leaves ride in the carry (module docstring)
+                pages = {k: {n: g[n] for n in A.page_leaf_names(g)}
+                         for k, g in unit_caches.items()
+                         if A.page_leaf_names(g)}
+                rest = {k: ({n: v for n, v in g.items() if n not in pages[k]}
+                            if k in pages else g)
+                        for k, g in unit_caches.items()}
+                (x, _, pages), (new_unit, aux_scan) = jax.lax.scan(
+                    body, (x, ctx.emb_orig, pages),
+                    (params["unit"], rest,
+                     jnp.arange(self.repeats, dtype=jnp.int32)))
+                new_unit = {k: ({**g, **pages[k]} if k in pages else g)
+                            for k, g in new_unit.items()}
             for k, v in aux_scan.items():
                 aux_tot[k] = aux_tot.get(k, 0.0) + jnp.sum(v)
         else:

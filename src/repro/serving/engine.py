@@ -150,16 +150,23 @@ def _chunk_prefill_fn(model, params, caches, tokens, mask, slots, key, *,
 
 _PREFILL = jax.jit(_prefill_fn, static_argnums=(0,),
                    static_argnames=("max_len", "vocab", "temperature"))
+# the decode scan and the chunk program donate the cache tree (argument
+# 2): the pool they return is written in place of the one they were given,
+# so no launch copies the pool and no copy queues while the host runs
+# ahead. The engine replaces ``self.caches`` with each output at once, and
+# no host path keeps a reference to an earlier tree.
 _FUSED_STEPS = jax.jit(sampling.fused_decode_steps, static_argnums=(0,),
                        static_argnames=("n_steps", "temperature",
-                                        "page_size", "freeze_inactive"))
+                                        "page_size", "freeze_inactive"),
+                       donate_argnums=(2,))
 _INSERT = jax.jit(sampling.insert_prefill)
 _INSERT_PAGED = jax.jit(paged.insert_prefill_paged,
                         static_argnames=("page_size",))
 _RELEASE = jax.jit(paged.release_slots)
 _CHUNK_PREFILL = jax.jit(_chunk_prefill_fn, static_argnums=(0,),
                          static_argnames=("vocab", "temperature",
-                                          "page_size", "sharing"))
+                                          "page_size", "sharing"),
+                         donate_argnums=(2,))
 _BEGIN_CHUNKED = jax.jit(paged.begin_chunked_prefill)
 _MAP_PREFIX = jax.jit(paged.map_shared_prefix)
 _ARM = jax.jit(sampling.arm_slots)

@@ -76,7 +76,8 @@ import jax.numpy as jnp
 
 # layout ops live with the rest of the KV-cache code; re-exported here so
 # serving code has one import surface for everything paged
-from repro.models.attention import (copy_page_rows, gather_pages,  # noqa: F401
+from repro.models.attention import (PAGES, copy_page_rows,  # noqa: F401
+                                    gather_pages, page_leaf_names,
                                     paged_decode_write)
 
 # contiguous cache leaves that page, and the page leaf each becomes: an
@@ -86,17 +87,11 @@ _PAGE_LEAF = {"k": "k_pages", "v": "v_pages", "ckv": "ckv_pages",
 _SRC_LEAF = {v: k for k, v in _PAGE_LEAF.items()}
 _CURSORS = {"pos_ids", "length"}
 _KV_GROUPS = ({"k", "v"}, {"ckv", "k_rope"})
-_PAGES = "_pages"
-
-
-def _page_leaves(group: Dict):
-    """The page leaves of a paged leaf group, in the group's order."""
-    return [k for k in group if k.endswith(_PAGES)]
 
 
 def _is_paged_group(d) -> bool:
-    return isinstance(d, dict) and _CURSORS <= set(d) and any(
-        k.endswith(_PAGES) for k in d)
+    return (isinstance(d, dict) and _CURSORS <= set(d)
+            and bool(page_leaf_names(d)))
 
 
 # --------------------------------------------------------------- allocator
@@ -450,7 +445,7 @@ def insert_prefill_paged(pool, src, slots: jax.Array, cur_tokens: jax.Array,
             pos = d["pos_ids"].at[slots].set(s["pos_ids"][:n])
             ln = d["length"].at[slots].set(s["length"][:n])
         return {**{leaf: scatter_rows(d[leaf], s[_SRC_LEAF[leaf]], stacked)
-                   for leaf in _page_leaves(d)},
+                   for leaf in page_leaf_names(d)},
                 "pos_ids": pos, "length": ln}
 
     def walk(p, s, stacked):
@@ -631,7 +626,7 @@ def cow_chunk_pages(pool: Dict, slots: jax.Array, start_tok: jax.Array,
 
     def leafgroup(stacked, d):
         return {**d, **{n: copy_page_rows(d[n], src_pg, dst_pg)
-                        for n in _page_leaves(d)}}
+                        for n in page_leaf_names(d)}}
 
     return _walk_paged(leafgroup, lambda stacked, x: x,
                        lambda a: alloc, pool)
@@ -640,7 +635,7 @@ def cow_chunk_pages(pool: Dict, slots: jax.Array, start_tok: jax.Array,
 def _rows_key(page_leaf: str) -> str:
     """An exported payload's key for a page leaf: ``k_pages`` ->
     ``k_rows``."""
-    return page_leaf[:-len(_PAGES)] + "_rows"
+    return page_leaf[:-len(PAGES)] + "_rows"
 
 
 def export_slot(pool: Dict, slot: jax.Array, src_pg: jax.Array) -> Dict:
@@ -657,7 +652,7 @@ def export_slot(pool: Dict, slot: jax.Array, src_pg: jax.Array) -> Dict:
     can walk both in lockstep. An out-of-range ``slot`` (the fleet
     sentinel ``B``) clamps — callers mask the result before use."""
     def leafgroup(stacked, p):
-        names = _page_leaves(p)
+        names = page_leaf_names(p)
         pg = jnp.where(src_pg < 0, p[names[0]].shape[-3] - 1, src_pg)
         return {
             **{_rows_key(n): jnp.take(p[n], pg, axis=-3) for n in names},
@@ -720,7 +715,7 @@ def migrate_pages(pool: Dict, slot: jax.Array, payload: Dict,
 
     def leafgroup(stacked, p, pl):
         return {**{n: scatter_rows(p[n], pl[_rows_key(n)])
-                   for n in _page_leaves(p)},
+                   for n in page_leaf_names(p)},
                 "pos_ids": rows_at(p["pos_ids"], pl["pos_ids"], stacked),
                 "length": rows_at(p["length"], pl["length"], stacked)}
 
@@ -755,7 +750,7 @@ def scatter_slot_view(pool: Dict, view: Dict, slots: jax.Array) -> Dict:
         return d.at[:, slots].set(s) if stacked else d.at[slots].set(s)
 
     def leafgroup(stacked, p, v):
-        return {**{n: v[n] for n in _page_leaves(p)},
+        return {**{n: v[n] for n in page_leaf_names(p)},
                 "pos_ids": rows(p["pos_ids"], v["pos_ids"], stacked),
                 "length": rows(p["length"], v["length"], stacked)}
 

@@ -287,12 +287,16 @@ def _migrate_fleet(mesh, caches, cur_tokens, state, is_src, is_dst,
         src_pg, n_pages)
 
 
+# the fleet's decode scan and chunk program donate the stacked cache tree
+# (argument 3), as the single engine's do (engine.py)
 _FUSED_FLEET = jax.jit(_fused_steps_fleet, static_argnums=(0, 1),
                        static_argnames=("n_steps", "temperature",
-                                        "page_size"))
+                                        "page_size"),
+                       donate_argnums=(3,))
 _CHUNK_FLEET = jax.jit(_chunk_prefill_fleet, static_argnums=(0, 1),
                        static_argnames=("vocab", "temperature", "page_size",
-                                        "sharing"))
+                                        "sharing"),
+                       donate_argnums=(3,))
 _BEGIN_FLEET = jax.jit(_begin_fleet, static_argnums=(0,))
 _ARM_FLEET = jax.jit(_arm_fleet, static_argnums=(0,))
 _RELEASE_FLEET = jax.jit(_release_fleet, static_argnums=(0,))

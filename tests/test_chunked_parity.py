@@ -8,10 +8,17 @@ same attention math chunk by chunk, so greedy decoding must be EXACTLY
 equal to the monolithic paged engine — any drift means a chunk wrote the
 wrong page, a stale row unmasked, a cursor moved during an interleaved
 decode scan, or positions skewed at a partial chunk.
+
+The stacked-pool cases at the end run the GQA and the latent (MLA) tiny
+models against the contiguous cache: a prefix-shared page after
+copy-on-write, and a slot frozen mid-chunked-prefill while decode scans
+write the pool around it.
 """
 import jax
 import numpy as np
 import pytest
+
+from conftest import tiny_model
 
 from repro.core.energy import prefill_counts, step_energy
 from repro.models import Model, ModelConfig
@@ -382,3 +389,91 @@ def test_jit_entries_reused_across_engines(parts):
     assert all(s > 0 for s in sizes[:2])
     exercise()                         # same config: zero new traces
     assert [f._cache_size() for f in entries] == sizes
+
+
+# ------------------------------------------------------- the stacked pool
+
+
+class WatchedEngine(ServingEngine):
+    """Counts the chunk launches that copy-on-write a shared page and the
+    decode scans that run while a slot is frozen mid-chunked-prefill."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self.cow_launches = self.frozen_scans = 0
+
+    def _prefill_quantum(self):
+        cow = any(req.cow_pending for req, _ in self._prefilling)
+        ran = super()._prefill_quantum()
+        self.cow_launches += bool(ran and cow)
+        return ran
+
+    def _decode_chunk(self, max_steps):
+        frozen = any(0 < req.prefill_pos for req, _ in self._prefilling)
+        ran = super()._decode_chunk(max_steps)
+        self.frozen_scans += bool(ran and frozen)
+        return ran
+
+
+@pytest.fixture(scope="module")
+def kind_parts(request):
+    return tiny_model(request.param, f"tiny-chunk-{request.param}")
+
+
+def _contiguous(m, params, reqs, max_batch):
+    eng = ServingEngine(m, params, EngineConfig(
+        max_batch=max_batch, max_len=64, sync_every=8))
+    for r in reqs:
+        eng.submit(Request(**r))
+    return {r.rid: r.tokens for r in eng.run()}
+
+
+def _watched(m, params, first, later, **kw):
+    """Serve ``first``, step until one of them decodes, then submit
+    ``later`` and drain."""
+    eng = WatchedEngine(m, params, EngineConfig(
+        max_len=64, sync_every=8, paged=True, page_size=PS,
+        prefill_chunk=CH, **kw))
+    for r in first:
+        eng.submit(Request(**r))
+    while not eng.decoding:
+        eng.step()
+    for r in later:
+        eng.submit(Request(**r))
+    return {r.rid: r.tokens for r in eng.run()}, eng
+
+
+@pytest.mark.parametrize("kind_parts", ["dense", "latent"], indirect=True)
+def test_shared_page_after_cow_matches_contiguous(kind_parts):
+    """A follower whose whole prompt (3 pages) is resident adopts the
+    donor's pages and recomputes its last token, so that chunk copies the
+    shared tail page on write into the stacked pool; both then decode,
+    token for token as on the contiguous cache."""
+    _, m, params = kind_parts
+    rng = np.random.default_rng(23)
+    prompt = rng.integers(0, 256, 3 * PS).tolist()
+    donor = dict(rid=0, prompt=prompt, max_new_tokens=24)
+    follower = dict(rid=1, prompt=list(prompt), max_new_tokens=12)
+    got, eng = _watched(m, params, [donor], [follower], max_batch=2,
+                        prefix_sharing=True, num_pages=12)
+    assert eng.cow_launches == 1 and eng.prefix_hit_tokens == 3 * PS - 1
+    assert got == _contiguous(m, params, [donor, follower], 2)
+    assert_pool_clean(eng)
+
+
+@pytest.mark.parametrize("kind_parts", ["dense", "latent"], indirect=True)
+def test_slot_frozen_mid_chunked_prefill_matches_contiguous(kind_parts):
+    """A 4-chunk prompt admitted while another slot decodes: the fused
+    scans between its chunks run with its slot inactive and its cursors
+    frozen, writing garbage to the row its next chunk overwrites; every
+    token matches the contiguous cache."""
+    _, m, params = kind_parts
+    rng = np.random.default_rng(29)
+    runner = dict(rid=0, prompt=rng.integers(0, 256, 5).tolist(),
+                  max_new_tokens=40)
+    long = dict(rid=1, prompt=rng.integers(0, 256, 3 * CH + 5).tolist(),
+                max_new_tokens=10)
+    got, eng = _watched(m, params, [runner], [long], max_batch=2)
+    assert eng.frozen_scans >= 2
+    assert got == _contiguous(m, params, [runner, long], 2)
+    assert_pool_clean(eng)

@@ -1,8 +1,9 @@
 """DeepSeek-V2's pieces in the program: YaRN rope and its softmax scale
 against the published formulas, the dropless layer over held experts (its
 shares add up to the uncut layer, its counts), latent pages refused by the
-Pallas path, and a two-shard fleet over latent pages token for token with
-the single engine.
+Pallas path, a two-shard fleet over latent pages token for token with
+the single engine, and the stacked pool's writes and reads (a layer of
+the scanned pool, latent and GQA alike) against the per-layer form.
 
 The whole model against its float32 reference (chunked prefill, then paged
 decode, and the departures the comparison must catch) is in
@@ -230,6 +231,90 @@ def test_latent_pages_match_the_contiguous_cache():
                        prefix_sharing=True, num_pages=16)
     assert paged == want and chunked == want
     assert eng.prefix_hit_tokens > 0
+
+
+# ------------------------------------------------------- the stacked pool
+
+# page leaves (name, heads, width) of an attention and of a latent group
+STACK_GROUPS = {"gqa": (("k_pages", 2, 8), ("v_pages", 2, 8)),
+                "latent": (("ckv_pages", 1, 16), ("krope_pages", 1, 8))}
+
+
+def _stacked_case(group):
+    """A 3-layer stacked pool, its layer-1 slice as a per-layer group, and
+    a block table in which slot 1 maps no page (a coasting slot)."""
+    L_, B, M, ps, P = 3, 3, 4, 4, 9
+    rng = np.random.default_rng(31)
+    tbl = np.full((B, M), -1, np.int32)
+    pages = rng.permutation(P)
+    tbl[0, :3], tbl[2, :4] = pages[:3], pages[3:7]
+    length = np.asarray([9, 0, 14], np.int32)
+    pos = np.arange(M * ps)[None].repeat(B, 0)
+    pos = np.where(pos < length[:, None], pos, -1).astype(np.int32)
+    leaves = {n: jnp.asarray(rng.normal(size=(L_, h, P + 1, ps, w)),
+                             jnp.float32) for n, h, w in STACK_GROUPS[group]}
+    cursors = {"pos_ids": jnp.asarray(pos), "length": jnp.asarray(length)}
+    stacked = {**leaves, **cursors, A.PAGE_LAYER: jnp.int32(1)}
+    layer = {**{n: x[1] for n, x in leaves.items()}, **cursors}
+    return stacked, layer, jnp.asarray(tbl), rng
+
+
+@pytest.mark.parametrize("op", ["decode_write", "chunk_write", "read"])
+@pytest.mark.parametrize("group", sorted(STACK_GROUPS))
+def test_stacked_pool_matches_the_layer_form(group, op):
+    """Writing rows into a layer of the stacked pool changes exactly what
+    the same write changes in that layer's own leaves, and nothing of the
+    other layers; reading the layer where it lies (the pool scored in
+    place, a slot's gathered view, the latent chunk loop) gives the
+    per-layer form's numbers exactly."""
+    stacked, layer, tbl, rng = _stacked_case(group)
+    names = [n for n, _, _ in STACK_GROUPS[group]]
+    B = tbl.shape[0]
+    if op != "read":
+        S = 1 if op == "decode_write" else 5
+        new = {n: jnp.asarray(rng.normal(size=(B, S) + layer[n].shape[::3]),
+                              jnp.float32) for n in names}
+        if op == "decode_write":
+            got = A._paged_decode_write(stacked, tbl, new)
+            want = A._paged_decode_write(layer, tbl, new)
+        else:
+            t0 = stacked["length"]
+            positions = jnp.where(jnp.arange(S)[None] < jnp.asarray(
+                [[5], [0], [2]]), t0[:, None] + jnp.arange(S), -1)
+            got = A._paged_chunk_write(stacked, tbl, new, positions)
+            want = A._paged_chunk_write(layer, tbl, new, positions)
+        for n in names:
+            np.testing.assert_array_equal(got[n][1], want[n])
+            np.testing.assert_array_equal(np.delete(got[n], 1, 0),
+                                          np.delete(stacked[n], 1, 0))
+        for n in ("pos_ids", "length"):
+            np.testing.assert_array_equal(got[n], want[n])
+        return
+    positions = stacked["length"][:, None] - 1
+    for n in names:
+        np.testing.assert_array_equal(
+            A.gather_pages_hb(stacked[n], tbl, stacked[A.PAGE_LAYER]),
+            A.gather_pages_hb(layer[n], tbl))
+    if group == "gqa":
+        q = jnp.asarray(rng.normal(size=(B, 1, 4, 8)), jnp.float32)
+        reads = [lambda c: A._pool_decode_attention(
+            (q,), c, tbl, positions, None, None, 2)]
+    else:
+        H = 4
+        ql = jnp.asarray(rng.normal(size=(B, 1, H, 16)), jnp.float32)
+        qr = jnp.asarray(rng.normal(size=(B, 1, H, 8)), jnp.float32)
+        qn = jnp.asarray(rng.normal(size=(B, 3, H, 6)), jnp.float32)
+        qc = jnp.asarray(rng.normal(size=(B, 3, H, 8)), jnp.float32)
+        w_uk = jnp.asarray(rng.normal(size=(16, H, 6)), jnp.float32)
+        w_uv = jnp.asarray(rng.normal(size=(16, H, 5)), jnp.float32)
+        chunk_pos = positions + jnp.arange(3) - 2
+        reads = [lambda c: A._pool_decode_attention(
+                     (ql, qr), c, tbl, positions, None, None, H,
+                     keys=A.LATENT_PAGES, values="ckv_pages", scale=0.3),
+                 lambda c: A._mla_chunk_attention(
+                     qn, qc, c, tbl, chunk_pos, w_uk, w_uv, 0.3, None)]
+    for read in reads:
+        np.testing.assert_array_equal(read(stacked), read(layer))
 
 
 # --------------------------------------------------------------- fleet

@@ -232,6 +232,65 @@ def test_faults_with_sharing_and_preemption(parts):
     assert_pool_clean(eng)
 
 
+def _watch_donation(monkeypatch):
+    """Wrap the two launches that donate the cache tree: count launches,
+    and those after which every page leaf handed in is deleted (the pool
+    went to the output in place, so nothing may read the old tree)."""
+    from repro.serving import engine as E
+    seen = {"launches": 0, "donated": 0}
+
+    def watch(real):
+        def launch(model, params, caches, *a, **kw):
+            pages = [x for path, x in
+                     jax.tree_util.tree_leaves_with_path(caches)
+                     if jax.tree_util.keystr(path).endswith("_pages']")]
+            out = real(model, params, caches, *a, **kw)
+            seen["launches"] += 1
+            seen["donated"] += all(x.is_deleted() for x in pages)
+            return out
+        return launch
+    for name in ("_FUSED_STEPS", "_CHUNK_PREFILL"):
+        monkeypatch.setattr(E, name, watch(getattr(E, name)))
+    return seen
+
+
+@pytest.mark.parametrize("site", ["prefill_chunk", "decode_scan"])
+def test_donated_pool_through_retry_preemption_and_stats(parts, site,
+                                                         monkeypatch):
+    """The decode scan and the chunk program donate the pool: a launch
+    that follows a faulted one (the fault fires before the launch, so
+    the engine still holds the live tree), a preemption that pins the
+    victim's prefix and re-prefills it, and ``stats()`` all run on the
+    tree the last launch returned; no host path reads a deleted array,
+    and the tokens are the fault-free run's."""
+    _, m, params = parts
+    common = list(RNG.integers(0, 256, 8))
+    reqs = [dict(rid=0, prompt=common + [3, 1], max_new_tokens=24),
+            dict(rid=1, prompt=common + [4, 1, 5], max_new_tokens=24)]
+    high = dict(rid=2, prompt=[6, 2, 8], max_new_tokens=4, priority=1)
+    want, _ = run_with_faults(m, params, reqs + [dict(**high)], [],
+                              max_batch=4, prefix_sharing=True)
+    seen = _watch_donation(monkeypatch)
+    eng = make_engine(m, params, prefix_sharing=True, preemption=True)
+    eng.faults = FaultInjector([FaultPlan(site, at_quantum=q)
+                                for q in (2, 7)])
+    for r in reqs:
+        eng.submit(Request(**r))
+    for _ in range(6):
+        eng.step()
+    eng.submit(Request(**high))
+    got = {r.rid: r for r in eng.run()}
+    assert {s for s, _ in eng.faults.fired} == {site}
+    assert eng.stats()["fault_retries"] >= 1
+    assert eng.stats()["preemption_count"] >= 1
+    assert seen["launches"] > 0 and seen["donated"] == seen["launches"]
+    assert not any(x.is_deleted()
+                   for x in jax.tree_util.tree_leaves(eng.caches))
+    for rid in want:
+        assert got[rid].tokens == want[rid].tokens, f"request {rid} diverged"
+    assert_pool_clean(eng)
+
+
 def test_fault_plan_validation():
     with pytest.raises(ValueError, match="unknown fault site"):
         FaultPlan("warp_core", at_quantum=0)

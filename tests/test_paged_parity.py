@@ -7,16 +7,31 @@ boundary crossed wrong. Cases cover mixed prompt-length buckets, a slot
 exhausting max_new_tokens mid-chunk, a page boundary crossed inside a
 sync_every scan chunk, EOS stops, pool-pressure queueing, and a hybrid
 model whose mamba2 state stays slot-addressed while attention KV pages.
+
+The stacked pool (page leaves carried through the layer scan, written in
+place and donated to the served programs) has its own cases below, each
+for the GQA and the latent (MLA) tiny model: the scan's structure and the
+programs' donation, and decode parity across a page boundary inside an
+8-step fused scan and for a finished slot writing to the trash page.
 """
+import re
+
 import jax
+import jax.extend
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import tiny_model
 from repro.models import Model, ModelConfig
 from repro.models.config import SSMConfig, repeat_pattern
 from repro.serving import EngineConfig, Request, ServingEngine
+from repro.serving import engine as E
+from repro.serving import paged as PG
+from repro.serving import sampling
 
 PS = 8                                 # page size exercised in the suite
+KINDS = ("dense", "latent")            # GQA and MLA tiny models
 
 
 @pytest.fixture(scope="module")
@@ -226,3 +241,135 @@ def test_trash_page_writes_stay_shard_local():
     for s in range(S):
         assert (kp[s, :, P] != 0).any(), "trash write missing on own shard"
         assert (kp[s, :, :P] == 0).all(), "write leaked into a real page"
+
+
+# ------------------------------------------------------- the stacked pool
+
+
+@pytest.fixture(scope="module")
+def kind_parts(request):
+    return tiny_model(request.param, f"tiny-stacked-{request.param}")
+
+
+def _scans(jaxpr):
+    """Every scan equation in ``jaxpr`` and the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    yield from _scans(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    yield from _scans(sub)
+
+
+def _aliased_outputs(lowered) -> set:
+    """``jax.result_info`` of every output of the lowered program's main
+    function that an input is donated to (``tf.aliasing_output``)."""
+    text = lowered.as_text()
+    main = next(l for l in text.splitlines() if "func.func public @main" in l)
+    args, results = main.split(") -> (", 1)
+    info = re.findall(r'jax.result_info = "([^"]*)"', results)
+    return {info[int(n)] for n in
+            re.findall(r"tf.aliasing_output = (\d+)", args)}
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+@pytest.mark.parametrize("kind_parts", KINDS, indirect=True)
+def test_stacked_pool_rides_the_carry_and_is_donated(kind_parts, program):
+    """No layer scan of the decode step or the prompt chunk takes a page
+    leaf of the stacked pool among its xs or hands one back among its ys
+    (it rides the carry, written in place), and the served program
+    (``_FUSED_STEPS`` / ``_CHUNK_PREFILL``) donates every page leaf to
+    the output it becomes."""
+    _, m, params = kind_parts
+    B, W, P = 2, 32, 10
+    caches = PG.paginate_cache(m.init_cache(B, W), B, PS, P)
+    stacked = {tuple(g[n].shape) for g in caches["unit"].values()
+               for n in g if n.endswith("_pages")}
+    assert stacked, "the model scans no paged group"
+    if program == "decode":
+        jaxpr = jax.make_jaxpr(m.decode_step)(
+            params, caches, jnp.zeros((B, 1), jnp.int32)).jaxpr
+    else:
+        view = PG.gather_slot_view(caches, jnp.zeros((1,), jnp.int32))
+        jaxpr = jax.make_jaxpr(m.prefill_chunk)(
+            params, view, jnp.zeros((1, 8), jnp.int32),
+            jnp.ones((1, 8), jnp.int32)).jaxpr
+    carried = False
+    for eqn in _scans(jaxpr):
+        nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+        xs = [v.aval.shape for v in eqn.invars[nc + nk:]]
+        ys = [v.aval.shape for v in eqn.outvars[nk:]]
+        assert not stacked & set(xs + ys), "a page leaf rides xs/ys"
+        carried |= bool(stacked & {v.aval.shape
+                                   for v in eqn.invars[nc:nc + nk]})
+    assert carried, "no scan carries the stacked pool"
+
+    key = jax.random.PRNGKey(0)
+    if program == "decode":
+        lowered = E._FUSED_STEPS.lower(
+            m, params, caches, jnp.zeros((B, 1), jnp.int32),
+            sampling.init_slot_state(B), key, n_steps=8, temperature=0.0,
+            page_size=PS, freeze_inactive=True)
+        out = "result[0]"
+    else:
+        tok = jnp.zeros((1, 8), jnp.int32)
+        lowered = E._CHUNK_PREFILL.lower(
+            m, params, caches, tok, tok, jnp.zeros((1,), jnp.int32), key,
+            vocab=m.cfg.vocab, temperature=0.0, page_size=PS,
+            sharing=True)
+        out = "result[3]"
+    # the cache tree: argument 2, the second of the non-static arguments
+    donated = lowered.args_info[0][1]
+    pages = {jax.tree_util.keystr(path) for path, _ in
+             jax.tree_util.tree_leaves_with_path(caches)
+             if jax.tree_util.keystr(path).endswith("_pages']")}
+    assert all(a.donated for a in jax.tree_util.tree_leaves(donated))
+    assert {out + p for p in pages} <= _aliased_outputs(lowered)
+
+
+def _contiguous_parity(m, params, reqs, **kw):
+    """Serve ``reqs`` on the contiguous cache and on the paged pool with
+    the same settings; every request's tokens must agree."""
+    want, _ = run_engine(m, params, reqs, paged=False, **kw)
+    got, eng = run_engine(m, params, reqs, paged=True, **kw)
+    for rid in want:
+        assert got[rid].tokens == want[rid].tokens, f"request {rid} diverged"
+        assert got[rid].finished == want[rid].finished
+    return eng
+
+
+@pytest.mark.parametrize("kind_parts", KINDS, indirect=True)
+def test_page_boundary_inside_8_step_scan_stacked(kind_parts):
+    """A 6-token prompt with 8-token pages: the fused 8-micro-step scan
+    allocates and writes the slot's second page at t = 8 inside the scan,
+    on the stacked pool of either attention kind."""
+    _, m, params = kind_parts
+    reqs = [dict(rid=0, prompt=[5, 4, 3, 2, 1, 6], max_new_tokens=12),
+            dict(rid=1, prompt=[7, 1, 7], max_new_tokens=14)]
+    eng = _contiguous_parity(m, params, reqs, sync_every=8)
+    assert eng.stats()["peak_pages_reserved"] >= 5
+    assert_pool_clean(eng)
+
+
+@pytest.mark.parametrize("kind_parts", KINDS, indirect=True)
+def test_finished_slot_writes_the_trash_page_stacked(kind_parts):
+    """A request whose budget ends at micro-step 2 of an 8-step scan
+    coasts to the scan's end writing into the trash page of every
+    layer's slice of the stacked pool; the request beside it, and the
+    next tenant of the slot, read none of it."""
+    _, m, params = kind_parts
+    reqs = [dict(rid=0, prompt=[9, 8, 7], max_new_tokens=3),
+            dict(rid=1, prompt=[1, 2, 3, 4], max_new_tokens=20),
+            dict(rid=2, prompt=[4, 4, 2], max_new_tokens=9)]
+    eng = _contiguous_parity(m, params, reqs, sync_every=8, max_batch=2)
+    trash = eng.num_pages
+    for g in eng.caches["unit"].values():
+        for n in g:
+            if n.endswith("_pages"):
+                rows = np.asarray(g[n])[:, :, trash]
+                assert (rows != 0).any(axis=(1, 2, 3)).all(), \
+                    f"{n}: a layer's trash page was never written"
+    assert_pool_clean(eng)
