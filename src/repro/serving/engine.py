@@ -1,5 +1,7 @@
 """Serving engine: request queue, continuous batching, prefill/decode phase
-split, per-phase carbon metering.
+split, per-phase carbon metering — on one device or on a fleet of S
+data-parallel shards over a device mesh (one class: a single device is
+the one-shard case; the fleet operations live in serving/sharded.py).
 
 The engine runs the *model* for real (CPU here, TPU in production) while the
 *energy/carbon* of each step is attributed via the calibrated analytical
@@ -85,22 +87,23 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.energy import decode_counts, prefill_counts, step_energy
-from repro.core.forecast import CIForecaster
 from repro.core.hardware import HardwareProfile, get_profile
-from repro.core.intensity import Region, ci_trace
-from repro.core.meter import CarbonMeter
 from repro.models import Model
 from repro.models.costing import workload_of
 from repro.serving import paged, preempt, sampling, trace
-from repro.serving.faults import FaultError, InjectedFault
+from repro.serving.faults import InjectedFault
 from repro.serving.request import Request, Response
+from repro.serving.sharded import FleetOps, over_mesh, stack_lanes
 
 
 # Module-level jitted entry points with the model as a STATIC argument:
 # every ServingEngine instance sharing a Model instance reuses the same
 # compiled executables (fresh engines used to rebuild jax.jit wrappers
 # around per-engine partials, so each one re-paid every trace+compile —
-# which dominated short-lived engines' wall time).
+# which dominated short-lived engines' wall time). Each program the
+# engine launches has a single-device entry and its shard_map form
+# (``over_mesh``, with the mesh static too); ``ServingEngine._launch``
+# picks one by the shard count.
 
 
 def _prefill_fn(model, params, tokens, mask, key, *, max_len, vocab,
@@ -150,29 +153,45 @@ def _chunk_prefill_fn(model, params, caches, tokens, mask, slots, key, *,
 
 _PREFILL = jax.jit(_prefill_fn, static_argnums=(0,),
                    static_argnames=("max_len", "vocab", "temperature"))
-# the decode scan and the chunk program donate the cache tree (argument
-# 2): the pool they return is written in place of the one they were given,
-# so no launch copies the pool and no copy queues while the host runs
-# ahead. The engine replaces ``self.caches`` with each output at once, and
-# no host path keeps a reference to an earlier tree.
-_FUSED_STEPS = jax.jit(sampling.fused_decode_steps, static_argnums=(0,),
-                       static_argnames=("n_steps", "temperature",
-                                        "page_size", "freeze_inactive"),
-                       donate_argnums=(2,))
 _INSERT = jax.jit(sampling.insert_prefill)
 _INSERT_PAGED = jax.jit(paged.insert_prefill_paged,
                         static_argnames=("page_size",))
-_RELEASE = jax.jit(paged.release_slots)
-_CHUNK_PREFILL = jax.jit(_chunk_prefill_fn, static_argnums=(0,),
-                         static_argnames=("vocab", "temperature",
-                                          "page_size", "sharing"),
-                         donate_argnums=(2,))
-_BEGIN_CHUNKED = jax.jit(paged.begin_chunked_prefill)
-_MAP_PREFIX = jax.jit(paged.map_shared_prefix)
-_ARM = jax.jit(sampling.arm_slots)
-_RELEASE_KEEP = jax.jit(paged.release_slots_keep)
-_DECREF = jax.jit(paged.decref_pages)
-_DISARM = jax.jit(sampling.disarm_slots)
+
+
+def _pair(fn, fleet_name=None, runs_model=False, static=(), donate=False):
+    """The single-device jit entry of program ``fn`` and of its mesh form
+    (``over_mesh``), with the model (and the mesh) static."""
+    lead = int(runs_model)
+    return (jax.jit(fn, static_argnums=tuple(range(lead)),
+                    static_argnames=static,
+                    donate_argnums=(lead + 1,) if donate else ()),
+            jax.jit(over_mesh(fn, fleet_name or f"_{fn.__name__}_fleet",
+                              runs_model),
+                    static_argnums=tuple(range(lead + 1)),
+                    static_argnames=static,
+                    donate_argnums=(lead + 2,) if donate else ()))
+
+
+# the decode scan and the chunk program donate the cache tree (argument 2;
+# argument 3 of the mesh forms, after the mesh): the pool they return is
+# written in place of the one they were given, so no launch copies the
+# pool and no copy queues while the host runs ahead. The engine replaces
+# ``self.caches`` with each output at once, and no host path keeps a
+# reference to an earlier tree.
+_FUSED_STEPS, _FUSED_FLEET = _pair(
+    sampling.fused_decode_steps, "_fused_steps_fleet", runs_model=True,
+    static=("n_steps", "temperature", "page_size", "freeze_inactive"),
+    donate=True)
+_CHUNK_PREFILL, _CHUNK_FLEET = _pair(
+    _chunk_prefill_fn, "_chunk_prefill_fleet", runs_model=True,
+    static=("vocab", "temperature", "page_size", "sharing"), donate=True)
+_BEGIN_CHUNKED, _BEGIN_FLEET = _pair(paged.begin_chunked_prefill)
+_MAP_PREFIX, _MAP_PREFIX_FLEET = _pair(paged.map_shared_prefix)
+_ARM, _ARM_FLEET = _pair(sampling.arm_slots)
+_RELEASE, _RELEASE_FLEET = _pair(paged.release_slots)
+_RELEASE_KEEP, _RELEASE_KEEP_FLEET = _pair(paged.release_slots_keep)
+_DECREF, _DECREF_FLEET = _pair(paged.decref_pages)
+_DISARM, _DISARM_FLEET = _pair(sampling.disarm_slots)
 
 
 def pack_chunks(prefilling, chunk: int, pack: int):
@@ -220,28 +239,6 @@ def pack_chunks(prefilling, chunk: int, pack: int):
     return take
 
 
-def _prefill_phase_counts(workload, batch: int, seq: int,
-                          useful_seq: Optional[float] = None, skip: int = 0):
-    """Step counts for one prefill launch of ``batch`` sequences padded to
-    ``seq``, with ``skip`` leading tokens already resident (prefix sharing:
-    their compute and KV writes never ran — the difference
-    prefill(seq) - prefill(skip) is exactly the cost of computing the
-    suffix with attention over the full prefix). Shared by the single
-    engine and every shard of a heterogeneous fleet, which price the SAME
-    counts at their own profiles."""
-    counts = prefill_counts(workload, batch, seq, useful_seq=useful_seq)
-    if skip > 0:
-        base = prefill_counts(workload, batch, skip)
-        counts = dataclasses.replace(
-            counts, flops=counts.flops - base.flops,
-            # the suffix launch still streams the weights once
-            hbm_bytes=(counts.hbm_bytes - base.hbm_bytes
-                       + workload.params_bytes),
-            kv_bytes=counts.kv_bytes - base.kv_bytes,
-            compute_tokens=counts.compute_tokens - base.compute_tokens)
-    return counts
-
-
 @dataclasses.dataclass
 class EngineConfig:
     """Every serving knob, single-device and fleet. Per-knob semantics are
@@ -275,8 +272,9 @@ class EngineConfig:
     FaultError — or a shard-down conversion on a fleet (PR 8);
     ``tenant_quota`` (None) rate-limits per tenant at submit().
 
-    Fleet (ShardedServingEngine): ``shards`` (1), per-shard
-    ``shard_profiles`` / ``shard_regions`` (None = homogeneous),
+    Fleet: ``shards`` (1 = one device; S > 1 shards over a mesh need
+    paged + chunked), per-shard ``shard_profiles`` / ``shard_regions``
+    (None = homogeneous),
     ``routing`` ("free_pages"; "carbon" = marginal-gCO2 placement, exact
     free-pages parity on a homogeneous fleet), and the deferral queue
     ``defer_below_priority`` (None) / ``defer_horizon_h`` (24) /
@@ -320,8 +318,8 @@ class EngineConfig:
     # never any request's chunk boundaries, so greedy parity and the
     # per-request metering are exactly invariant to this knob.
     prefill_pack: int = 1
-    # mesh-sharded serving (ShardedServingEngine): data-parallel shard
-    # count. The base ServingEngine is single-device and ignores it.
+    # data-parallel shard count: one shard per device of the engine's
+    # mesh, each with its own slots and page pool; 1 = a single device
     shards: int = 1
     # ---- front-door robustness (async server, PR 6) ----
     # bounded admission queue: a submission arriving with the queue at
@@ -362,9 +360,8 @@ class EngineConfig:
     # paged engine is the token-for-token parity oracle.
     prefix_sharing: bool = False
     # ---- heterogeneous fleet + carbon routing (PR 7) ----
-    # per-shard hardware profile / grid region names for the
-    # ShardedServingEngine (length must equal `shards`; None = every shard
-    # uses `profile` / `region`). The model runs identically everywhere —
+    # per-shard hardware profile / grid region names (length must equal
+    # `shards`; None = every shard uses `profile` / `region`). The model runs identically everywhere —
     # heterogeneity lives in the energy/carbon attribution and in
     # placement, never in the token streams.
     shard_profiles: Optional[Sequence[str]] = None
@@ -404,87 +401,26 @@ class EngineConfig:
     tenant_quota: Optional[Dict[str, Tuple[float, float]]] = None
 
 
-class ServingEngine:
-    def __init__(self, model: Model, params, cfg: EngineConfig):
-        self.model = model
-        self.params = params
-        self.cfg = cfg
-        self.profile: HardwareProfile = get_profile(cfg.profile)
-        self.meter = CarbonMeter(self.profile, cfg.region,
-                                 lifetime_years=cfg.lifetime_years,
-                                 n_devices=cfg.n_devices,
-                                 use_diurnal_ci=cfg.use_diurnal_ci)
-        self.workload = workload_of(model.cfg)
-        self.queue: deque = deque()
-        self.responses: Dict[int, Response] = {}
-        B = cfg.max_batch
-        self.caches = model.init_cache(B, cfg.max_len)
-        self.cur_tokens = jnp.zeros((B, 1), jnp.int32)
-        self.state = sampling.init_slot_state(B)     # device-side slot state
-        # host mirrors (bookkeeping only; the device state drives the chunk)
-        self.slot_rid = [-1] * B                     # -1 = free
-        self.slot_budget = [0] * B
-        self.slot_eos: List[Optional[int]] = [None] * B
-        self._slot_ctx = [0.0] * B                   # context length mirror
-        self._slo = [None] * B
-        self._req_slo: Dict[int, Optional[float]] = {}
-        self._key = jax.random.PRNGKey(0)
-        self._steps = 0
-        self.decode_chunks = 0                       # device->host syncs
-        self.prefill_batches = 0
-        self.prefill_chunks = 0                      # chunked-prefill launches
-        # the dropless MoE layer's counts (models/moe.py), summed over
-        # layers: token-expert pairs computed by the held experts and held
-        # experts that received a pair, per decode micro-step, and pairs
-        # of the chunked prefill. Fetched with the tokens the host already
-        # fetches; a chunk's counts wait on device until the next fetch.
-        self.counted = bool(model.cfg.moe is not None
-                            and model.cfg.moe.dropless)
-        self.moe_decode_pairs = 0
-        self.moe_decode_experts_hit = 0
-        self.moe_prefill_pairs = 0
-        self._pending_counts: List[Dict[str, jax.Array]] = []
-        self._quantum_counts: Dict[str, int] = {}
-        # rid -> logits at the last prompt position (chunked path), kept on
-        # device; None = not recorded. Set to {} to compare attention paths
-        # (chip_smoke.py) without an extra host sync per request.
-        self.prompt_logits: Optional[Dict[int, jax.Array]] = None
-        self.peak_active = 0                         # max concurrent requests
-        # host mirror of which slots are ARMED for decode (device
-        # state["active"] at chunk boundaries): in chunked mode a slot is
-        # occupied (slot_rid >= 0) during its whole prefill but must not
-        # trigger decode scans until its last chunk arms it
-        self._slot_armed = [False] * B
-        # front-door mirrors: the Request occupying each slot (eviction
-        # and deadline cancellation mutate it in place), its priority
-        # class, and its absolute deadline
-        self._slot_req: List[Optional[Request]] = [None] * B
-        self._slot_prio = [0] * B
-        self._slot_deadline: List[Optional[float]] = [None] * B
-        self._has_deadlines = False    # skip the sweep when nobody set one
-        # scheduling-quantum counter (one per step()) — the fault
-        # injector's clock and the backoff schedule's time base
-        self._quantum = 0
-        self._run_q0 = 0               # quantum at the current run()'s start
-        self.faults = None             # Optional[faults.FaultInjector]
-        self._backoff: Dict[str, Tuple[int, int]] = {}   # site -> (fails, retry_at)
-        self.fault_retries = 0
-        self.fault_retry_site: Dict[str, int] = {}       # site -> retries
-        # per-tenant token buckets: tenant -> [tokens, last_refill_t]
-        self._tenant_buckets: Dict[str, List[float]] = {}
-        self.rate_limited = 0
-        # front-door counters (stats())
-        self.shed_count = 0
-        self._shed_by_class: Dict[int, int] = {}
-        self.preemption_count = 0
-        self.deadline_cancelled = 0
-        self.clamped_requests = 0
-        self.preempted_recompute_j = 0.0
-        self._wait_samples: Dict[int, List[float]] = {}  # class -> waits (s)
-        # preemption pins: rid -> physical pages whose refcounts were
-        # transferred out of the evicted slot (kept resident + indexed for
-        # the resume's prefix hit); dropped after re-adoption or cancel
-        self._pins: Dict[int, List[int]] = {}
+class ServingEngine(FleetOps):
+    """Continuous batching over ``cfg.shards`` = S data-parallel shards
+    behind one queue, one per device of ``mesh`` (None = the first S
+    devices); a single device is S = 1. Each shard has its own slots
+    (``cfg.max_batch``), page pool (``cfg.num_pages``) and prefix index;
+    every host mirror is indexed by shard (``slot_rid[s][b]``), and the
+    fleet operations are ``FleetOps`` (serving/sharded.py). S > 1 needs
+    the paged pool and chunked prefill: the quantum scheduler is what
+    packs every shard's chunk and decode scan into single programs."""
+
+    def __init__(self, model: Model, params, cfg: EngineConfig, mesh=None):
+        S, B = cfg.shards, cfg.max_batch
+        if S < 1:
+            raise ValueError("shards must be >= 1")
+        if S > 1 and (not cfg.paged or cfg.prefill_chunk is None):
+            raise ValueError(
+                "mesh-sharded serving requires the paged pool + chunked "
+                "prefill (paged=True, prefill_chunk set): the quantum "
+                "scheduler is what packs every shard's prefill chunk and "
+                "decode scan into single fleet-wide programs")
         if cfg.shed_policy not in ("reject_newest", "reject_lowest"):
             raise ValueError(f"unknown shed_policy {cfg.shed_policy!r}")
         if cfg.max_queue is not None and cfg.max_queue < 1:
@@ -509,17 +445,6 @@ class ServingEngine:
                     raise ValueError(
                         f"tenant_quota[{name!r}]: capacity must be >= 1 "
                         "and refill_per_s >= 0")
-        # temporal deferral: held requests own NOTHING (no slot, no pages,
-        # no queue position) until the CI forecaster's greenest window
-        # opens at the virtual clock, or deadline pressure forces release
-        self.deferred: deque = deque()
-        self.deferred_rids: set = set()
-        self._defer_release_h: Dict[int, float] = {}
-        self._forecasters: Dict[str, CIForecaster] = {}
-        self.deferred_total = 0
-        self.deferred_released = 0
-        self.deferred_forced = 0
-
         self.paged = cfg.paged
         if cfg.paged:
             if not model.supports_paged_decode:
@@ -534,21 +459,6 @@ class ServingEngine:
                               if cfg.num_pages is None else cfg.num_pages)
             if self.num_pages < 1:
                 raise ValueError("num_pages must be >= 1")
-            self.caches = paged.paginate_cache(
-                self.caches, B, cfg.page_size, self.num_pages)
-            # host mirror of worst-case page RESERVATIONS (>= device usage,
-            # so admission by reservation means the on-device free stack
-            # can never underflow mid-flight)
-            self.free_pages = self.num_pages
-            self.peak_pages_reserved = 0
-            # per-quantum sums of pages reserved and pages mapped, added at
-            # the end of every step(): their ratio over an interval is the
-            # share of the reservation that holds KV
-            self.reserved_page_quanta = 0
-            self.mapped_page_quanta = 0
-            self._slot_pages = [0] * B
-            self._resv: Dict[int, int] = {}
-
         self.chunked = cfg.prefill_chunk is not None
         if self.chunked:
             if cfg.prefill_chunk < 1:
@@ -564,66 +474,193 @@ class ServingEngine:
                     f"{model.cfg.name}: chunked prefill requires all "
                     "stateful blocks to keep their KV in the paged pool "
                     "(recurrent blocks need carried-state chunk resume)")
-            # FCFS queue of (request, slot) mid-prefill; req.prefill_pos
-            # tracks how many prompt tokens are already in the pool
-            self._prefilling: deque = deque()
         if cfg.preemption and not self.chunked:
             raise ValueError(
                 "preemption requires chunked prefill (prefill_chunk set): "
                 "a preempted request resumes through the chunked admission "
                 "path, adopting its pinned prefix and recomputing only the "
                 "unshared tail")
-
         self.sharing = cfg.prefix_sharing
+        if self.sharing and not self.chunked:
+            raise ValueError(
+                "prefix_sharing requires chunked prefill (prefill_chunk "
+                "set): sharing works by starting the chunk schedule at "
+                "the first unshared token")
+
+        self.model, self.cfg = model, cfg
+        self.S, self.B = S, B
+        self.workload = workload_of(model.cfg)
+        self.profile: HardwareProfile = get_profile(cfg.profile)
+        self._init_fleet()
+
+        # ---- device state: one shard's tree; a fleet stacks it S-wide
+        caches = model.init_cache(B, cfg.max_len)
+        if cfg.paged:
+            caches = paged.paginate_cache(caches, B, cfg.page_size,
+                                          self.num_pages)
+        state = (caches, jnp.zeros((B, 1), jnp.int32),
+                 sampling.init_slot_state(B))
+        (self.caches, self.cur_tokens, self.state), self.params = (
+            self._place_over_mesh(state, params, mesh))
+
+        # ---- host mirrors (bookkeeping only; the device state drives the
+        # chunk), one row of B slots per shard
+        def rows(v):
+            return [[v] * B for _ in range(S)]
+
+        self.queue: deque = deque()
+        self.responses: Dict[int, Response] = {}
+        self.slot_rid = rows(-1)                     # -1 = free
+        self.slot_budget = rows(0)
+        self.slot_eos: List[List[Optional[int]]] = rows(None)
+        self._slot_ctx = rows(0.0)                   # context length mirror
+        # which slots are ARMED for decode (device state["active"] at
+        # chunk boundaries): in chunked mode a slot is occupied
+        # (slot_rid >= 0) during its whole prefill but must not trigger
+        # decode scans until its last chunk arms it
+        self._slot_armed = rows(False)
+        self._slo: List[List[Optional[float]]] = rows(None)
+        # front-door mirrors: the Request occupying each slot (eviction
+        # and deadline cancellation mutate it in place), its priority
+        # class, and its absolute deadline
+        self._slot_req: List[List[Optional[Request]]] = rows(None)
+        self._slot_prio = rows(0)
+        self._slot_deadline: List[List[Optional[float]]] = rows(None)
+        self._req_slo: Dict[int, Optional[float]] = {}
+        self._key = jax.random.PRNGKey(0)
+        self._steps = 0                # micro-steps in which a slot emitted
+        self.decode_chunks = 0                       # device->host syncs
+        self.prefill_batches = 0
+        self.prefill_chunks = 0                      # chunked-prefill launches
+        # the dropless MoE layer's counts (models/moe.py), summed over
+        # layers and shards: token-expert pairs computed by the held
+        # experts and held experts that received a pair, per decode
+        # micro-step, and pairs of the chunked prefill. Fetched with the
+        # tokens the host already fetches; a chunk's counts wait on
+        # device until the next fetch.
+        self.counted = bool(model.cfg.moe is not None
+                            and model.cfg.moe.dropless)
+        self.moe_decode_pairs = 0
+        self.moe_decode_experts_hit = 0
+        self.moe_prefill_pairs = 0
+        self._pending_counts: List[Dict[str, jax.Array]] = []
+        self._quantum_counts: Dict[str, int] = {}
+        # rid -> logits at the last prompt position (chunked path), kept on
+        # device; None = not recorded. Set to {} to compare attention paths
+        # (chip_smoke.py) without an extra host sync per request.
+        self.prompt_logits: Optional[Dict[int, jax.Array]] = None
+        self.peak_active = 0                         # max concurrent requests
+        self._has_deadlines = False    # skip the sweep when nobody set one
+        # scheduling-quantum counter (one per step()) — the fault
+        # injector's clock and the backoff schedule's time base
+        self._quantum = 0
+        self._run_q0 = 0               # quantum at the current run()'s start
+        self.faults = None             # Optional[faults.FaultInjector]
+        self._backoff: Dict[str, Tuple[int, int]] = {}   # site -> (fails, retry_at)
+        self.fault_retries = 0
+        self.fault_retry_site: Dict[str, int] = {}       # site -> retries
+        # per-tenant token buckets: tenant -> [tokens, last_refill_t]
+        self._tenant_buckets: Dict[str, List[float]] = {}
+        self.rate_limited = 0
+        # front-door counters (stats())
+        self.shed_count = 0
+        self._shed_by_class: Dict[int, int] = {}
+        self.preemption_count = 0
+        self.deadline_cancelled = 0
+        self.clamped_requests = 0
+        self.preempted_recompute_j = 0.0
+        self._wait_samples: Dict[int, List[float]] = {}  # class -> waits (s)
+        # preemption pins: rid -> (shard, physical pages whose refcounts
+        # were transferred out of the evicted slot, kept resident +
+        # indexed for the resume's prefix hit); dropped after re-adoption
+        # or cancel
+        self._pins: Dict[int, Tuple[int, List[int]]] = {}
+        if self.paged:
+            # host mirror of worst-case page RESERVATIONS (>= device usage,
+            # so admission by reservation means the on-device free stack
+            # can never underflow mid-flight)
+            self.free_pages = [self.num_pages] * S
+            self.peak_pages_reserved = [0] * S
+            # per-quantum sums of pages reserved and pages mapped, added at
+            # the end of every step(): their ratio over an interval is the
+            # share of the reservation that holds KV
+            self.reserved_page_quanta = 0
+            self.mapped_page_quanta = 0
+            self._slot_pages = rows(0)
+            self._resv: Dict[int, int] = {}
+        if self.chunked:
+            # per shard, FCFS queue of (request, slot) mid-prefill;
+            # req.prefill_pos tracks how many prompt tokens are in the pool
+            self._prefilling: List[deque] = [deque() for _ in range(S)]
         if self.sharing:
-            if not self.chunked:
-                raise ValueError(
-                    "prefix_sharing requires chunked prefill (prefill_chunk "
-                    "set): sharing works by starting the chunk schedule at "
-                    "the first unshared token")
-            # host-side prefix index: SHA-256 chain digest of the first
-            # (i+1) page-size token chunks -> physical page holding chunk i.
-            # WEAK entries: an index page is always mapped by >= 1 live
-            # slot; _page_ref mirrors the device refcount for indexed pages
-            # (all sharing traffic originates host-side, so the mirror is
-            # exact) and the entry drops at decref-to-zero.
-            self._prefix_index: Dict[bytes, int] = {}
-            self._page_key: Dict[int, bytes] = {}        # reverse map
-            self._page_ref: Dict[int, int] = {}
+            # SHARD-LOCAL prefix index (adoption never crosses shards):
+            # SHA-256 chain digest of the first (i+1) page-size token
+            # chunks -> physical page holding chunk i. WEAK entries: an
+            # index page is always mapped by >= 1 live slot; _page_ref
+            # mirrors the device refcount for indexed pages (all sharing
+            # traffic originates host-side, so the mirror is exact) and
+            # the entry drops at decref-to-zero.
+            self._prefix_index: List[Dict[bytes, int]] = [
+                {} for _ in range(S)]
+            self._page_key: List[Dict[int, bytes]] = [{} for _ in range(S)]
+            self._page_ref: List[Dict[int, int]] = [{} for _ in range(S)]
             # per-slot indexed pages: adopted from the index at admission
             # (not in this slot's reservation) vs registered by this slot
             # (popped under its reservation) — release accounting differs
-            self._slot_shared_in: Dict[int, List[int]] = {}
-            self._slot_own_idx: Dict[int, List[int]] = {}
+            self._slot_shared_in: List[Dict[int, List[int]]] = [
+                {} for _ in range(S)]
+            self._slot_own_idx: List[Dict[int, List[int]]] = [
+                {} for _ in range(S)]
             self.prefix_hit_tokens = 0     # prompt tokens never recomputed
             self.prefix_shared_requests = 0
             self.peak_shared_mappings = 0  # extra mappings beyond 1st copy
 
+    # ------------------------------------------------------- device programs
+    def _disarm(self, pairs: List[Tuple[int, int]]) -> None:
+        """Stop the device sampling from these (shard, slot) pairs."""
+        rows = [[b for t, b in pairs if t == s] for s in range(self.S)]
+        self.state = self._launch(_DISARM, _DISARM_FLEET, (self.state,),
+                                  (stack_lanes(rows, self.B),))
+
     # ------------------------------------------------------------- metering
     def _meter_prefill(self, batch: int, seq: int,
                        useful_seq: Optional[float] = None, skip: int = 0,
-                       phase: str = "prefill"):
+                       phase: str = "prefill", shard: int = 0):
         """Meter one prefill launch of ``batch`` sequences padded to
-        ``seq``; ``useful_seq`` (mean real tokens per row) attributes only
-        the real tokens while the energy covers the whole padded launch.
-        ``skip`` > 0 (prefix sharing, batch 1) removes the cost of the
-        first ``skip`` tokens — their compute and KV writes never ran;
-        the difference prefill(seq) - prefill(skip) is exactly the cost
-        of computing the suffix with attention over the full prefix.
-        ``phase`` names the meter bucket: a preempted request's resume
-        prefill is charged to ``"recompute"`` so the prefill phase's
-        J/token — and every non-preempted request's modeled energy — is
-        invariant to the preemption policy."""
-        counts = _prefill_phase_counts(self.workload, batch, seq,
-                                       useful_seq=useful_seq, skip=skip)
-        rep = step_energy(self.profile, counts)
-        self.meter.record(phase, rep.tokens, rep.t_total, rep.energy_j)
-        return rep
+        ``seq`` on ``shard``, at its profile; ``useful_seq`` (mean real
+        tokens per row) attributes only the real tokens while the energy
+        covers the whole padded launch. ``skip`` > 0 (prefix sharing,
+        batch 1) removes the cost of the first ``skip`` tokens — their
+        compute and KV writes never ran; the difference prefill(seq) -
+        prefill(skip) is exactly the cost of computing the suffix with
+        attention over the full prefix. ``phase`` names the meter bucket:
+        a preempted request's resume prefill is charged to
+        ``"recompute"`` so the prefill phase's J/token — and every
+        non-preempted request's modeled energy — is invariant to the
+        preemption policy."""
+        counts = prefill_counts(self.workload, batch, seq,
+                                useful_seq=useful_seq)
+        if skip > 0:
+            base = prefill_counts(self.workload, batch, skip)
+            counts = dataclasses.replace(
+                counts, flops=counts.flops - base.flops,
+                # the suffix launch still streams the weights once
+                hbm_bytes=(counts.hbm_bytes - base.hbm_bytes
+                           + self.workload.params_bytes),
+                kv_bytes=counts.kv_bytes - base.kv_bytes,
+                compute_tokens=counts.compute_tokens - base.compute_tokens)
+        return self._record(shard, phase, counts)
 
-    def _meter_decode(self, batch: int, context: float):
-        counts = decode_counts(self.workload, batch, context)
-        rep = step_energy(self.profile, counts)
-        self.meter.record("decode", rep.tokens, rep.t_total, rep.energy_j)
+    def _meter_decode(self, batch: int, context: float, shard: int = 0):
+        return self._record(shard, "decode",
+                            decode_counts(self.workload, batch, context))
+
+    def _record(self, s: int, phase: str, counts):
+        """Price ``counts`` at shard ``s``'s profile, record them in its
+        meter's ``phase`` and add their modeled time to its quantum."""
+        rep = step_energy(self.shard_profile[s], counts)
+        self.meters[s].record(phase, rep.tokens, rep.t_total, rep.energy_j)
+        self._q_time[s] += rep.t_total
         return rep
 
     # ------------------------------------------------------------ lifecycle
@@ -745,31 +782,34 @@ class ServingEngine:
         """Release a preemption pin: decref the pinned pages on device and
         mirror the last-holder-credits-once flow on the host (pins only
         exist with prefix sharing — the pin IS an index residency)."""
-        pins = self._pins.pop(rid, None)
-        if not pins:
+        pin = self._pins.pop(rid, None)
+        if pin is None:
             return
-        pages = np.full((self.max_pages_slot,), -1, np.int32)
-        pages[:len(pins)] = pins
-        self.caches = dict(self.caches)
-        self.caches["paged"] = _DECREF(self.caches["paged"],
-                                       jnp.asarray(pages))
+        s, pins = pin
+        if self.health.is_dead(s):
+            # defensive: declaration already invalidated dead-shard pins;
+            # never send a decref to a dead pool
+            return
+        pages = np.full((self.S, self.max_pages_slot), -1, np.int32)
+        pages[s, :len(pins)] = pins
+        self._launch_alloc(_DECREF, _DECREF_FLEET, pages)
         for p in pins:
-            self._page_ref[p] -= 1
-            if self._page_ref[p] <= 0:
-                self._drop_index_page(p)
-                self.free_pages += 1   # the pin was the last holder
+            self._page_ref[s][p] -= 1
+            if self._page_ref[s][p] <= 0:
+                self._drop_index_page(s, p)
+                self.free_pages[s] += 1   # the pin was the last holder
 
-    def free_slots(self) -> List[int]:
-        return [i for i, r in enumerate(self.slot_rid) if r < 0]
+    def free_slots(self, s: int) -> List[int]:
+        return [i for i, r in enumerate(self.slot_rid[s]) if r < 0]
 
     @property
     def active(self) -> int:
-        return sum(1 for r in self.slot_rid if r >= 0)
+        return sum(1 for row in self.slot_rid for r in row if r >= 0)
 
     @property
     def decoding(self) -> int:
         """Slots armed for decode (excludes slots still mid-prefill)."""
-        return sum(self._slot_armed)
+        return sum(sum(row) for row in self._slot_armed)
 
     def _over_budget(self) -> bool:
         b = self.cfg.carbon_budget_g_per_ktok
@@ -783,100 +823,6 @@ class ServingEngine:
     def _next_key(self) -> jax.Array:
         self._key, sub = jax.random.split(self._key)
         return sub
-
-    # ---------------------------------------------------- temporal deferral
-    # Batch-class requests wait for the grid's greenest window (paper §4's
-    # temporal lever): the CI forecaster picks the lowest-mean-CI window in
-    # the look-ahead horizon at submit time, the request is parked owning
-    # nothing, and it re-enters the FCFS queue when the engine's virtual
-    # clock reaches that window — or earlier, when defer_deadline_frac of
-    # its deadline budget has elapsed (the rest is reserved for service).
-
-    def _clock_hours(self) -> float:
-        """Virtual fleet time in hours — the deferral time base."""
-        return self.meter.clock_hours
-
-    def _advance_clock_to(self, hours: float) -> None:
-        self.meter.clock_hours = max(self.meter.clock_hours, hours)
-
-    def _defer_regions(self) -> List[Region]:
-        return [self.meter.region]
-
-    def _forecaster(self, region: Region) -> CIForecaster:
-        fc = self._forecasters.get(region.name)
-        if fc is None:
-            # fit on two synthetic days of the region's diurnal trace —
-            # the stand-in for yesterday's telemetry feed
-            hours = np.arange(0.0, 48.0)
-            fc = CIForecaster().fit(hours, ci_trace(region, hours))
-            self._forecasters[region.name] = fc
-        return fc
-
-    def _defer(self, req: Request) -> None:
-        """Park ``req`` until the greenest forecast window across the
-        fleet's regions opens (fixed at submit — a day-ahead commitment,
-        so release order within a class stays FCFS)."""
-        now_h = self._clock_hours()
-        best = now_h
-        best_ci = None
-        for region in self._defer_regions():
-            start, mean_ci = self._forecaster(region).greenest_window(
-                now_h, horizon_h=self.cfg.defer_horizon_h)
-            if best_ci is None or mean_ci < best_ci:
-                best, best_ci = start, mean_ci
-        self._defer_release_h[req.rid] = best
-        self.deferred.append(req)
-        self.deferred_rids.add(req.rid)
-        self.deferred_total += 1
-
-    def _release_deferred(self) -> int:
-        """Move due (window open) or forced (deadline pressure) requests
-        from the deferral queue into the admission queue. Releases are
-        prefix-closed per priority class: if request i of a class is
-        released, everything of that class ahead of it is too — deferral
-        can never reorder same-class FCFS."""
-        if not self.deferred:
-            return 0
-        now_h = self._clock_hours()
-        now_s = time.perf_counter()
-        frac = self.cfg.defer_deadline_frac
-        last_eligible: Dict[int, int] = {}
-        forced_rids: set = set()
-        for i, req in enumerate(self.deferred):
-            due = now_h >= self._defer_release_h[req.rid]
-            forced = (req.deadline_s is not None
-                      and now_s - req.t_submit >= frac * req.deadline_s)
-            if due or forced:
-                last_eligible[req.priority] = i
-                if forced and not due:
-                    forced_rids.add(req.rid)
-        if not last_eligible:
-            return 0
-        kept: deque = deque()
-        released = 0
-        for i, req in enumerate(self.deferred):
-            cut = last_eligible.get(req.priority, -1)
-            if i <= cut:
-                self.deferred_rids.discard(req.rid)
-                self._defer_release_h.pop(req.rid, None)
-                self.deferred_released += 1
-                if req.rid in forced_rids:
-                    self.deferred_forced += 1
-                self._enqueue(req)
-                released += 1
-            else:
-                kept.append(req)
-        self.deferred = kept
-        return released
-
-    def _fast_forward_deferred(self) -> None:
-        """Nothing runnable remains but deferred work is parked: sleep the
-        virtual clock forward to the earliest release window and release.
-        (The modeled clock only advances with work, so an otherwise-idle
-        engine must jump to the window rather than busy-wait toward it.)"""
-        h = min(self._defer_release_h[r.rid] for r in self.deferred)
-        self._advance_clock_to(h)
-        self._release_deferred()
 
     # ------------------------------------------------------- prefix sharing
     def _prompt_page_keys(self, req: Request) -> List[bytes]:
@@ -896,22 +842,22 @@ class ServingEngine:
             req.prefix_keys = keys
         return req.prefix_keys
 
-    def _match_prefix(self, req: Request) -> Tuple[int, List[int]]:
-        """Longest resident prefix of the prompt: (#shared whole pages,
-        their physical ids, in logical order)."""
+    def _match_prefix(self, req: Request, s: int) -> Tuple[int, List[int]]:
+        """Longest prefix of the prompt resident in shard ``s``: (#shared
+        whole pages, their physical ids, in logical order)."""
         phys: List[int] = []
         for k in self._prompt_page_keys(req):
-            p = self._prefix_index.get(k)
+            p = self._prefix_index[s].get(k)
             if p is None:
                 break
             phys.append(p)
         return len(phys), phys
 
-    def _drop_index_page(self, p: int) -> None:
-        key = self._page_key.pop(p, None)
+    def _drop_index_page(self, s: int, p: int) -> None:
+        key = self._page_key[s].pop(p, None)
         if key is not None:
-            self._prefix_index.pop(key, None)
-        self._page_ref.pop(p, None)
+            self._prefix_index[s].pop(key, None)
+        self._page_ref[s].pop(p, None)
 
     def _reject(self, req: Request) -> None:
         """Fail a request that can never fit the pool (prompt alone exceeds
@@ -921,43 +867,50 @@ class ServingEngine:
         resp.rejected = True
         resp.finish_reason = "rejected"
 
-    def _release_slots(self, slots: List[int]) -> None:
-        """Return finished slots' pages to the pool: device free stack
-        (actual mapped pages, decref-to-zero) + host reservation mirror.
-
-        With prefix sharing the per-slot flows are asymmetric but the
-        global mirror stays exact: a page this slot POPPED (reserved) that
-        others still reference is NOT freed (give back one page fewer),
-        and a page this slot merely adopted whose refcount just hit zero
-        IS freed (give back one page more) — every physical page is
-        charged once by its popper and credited once by its last holder."""
-        if not self.paged or not slots:
+    def _release_slots(self, pairs: List[Tuple[int, int]]) -> None:
+        """Return finished (shard, slot) pairs' pages to their pools: one
+        release program for every shard (actual mapped pages, decref-to-
+        zero) + the host reservation mirror (``_return_pages``)."""
+        if not self.paged or not pairs:
             return
         with trace.span("release"):
-            self._release_pages(slots)
+            mask = np.zeros((self.S, self.B), bool)
+            for s, b in pairs:
+                mask[s, b] = True
+            self._launch_alloc(_RELEASE, _RELEASE_FLEET, mask)
+            for s, b in pairs:
+                self._return_pages(s, b)
 
-    def _release_pages(self, slots: List[int]) -> None:
-        mask = np.zeros((self.cfg.max_batch,), bool)
-        mask[slots] = True
-        self.caches = dict(self.caches)
-        self.caches["paged"] = _RELEASE(self.caches["paged"],
-                                        jnp.asarray(mask))
-        for s in slots:
-            ret = self._slot_pages[s]
-            if self.sharing:
-                for p in self._slot_own_idx.pop(s, []):
-                    self._page_ref[p] -= 1
-                    if self._page_ref[p] <= 0:
-                        self._drop_index_page(p)
-                    else:
-                        ret -= 1       # survives under someone else's map
-                for p in self._slot_shared_in.pop(s, []):
-                    self._page_ref[p] -= 1
-                    if self._page_ref[p] <= 0:
-                        self._drop_index_page(p)
-                        ret += 1       # last holder frees the original
-            self.free_pages += ret
-            self._slot_pages[s] = 0
+    def _return_pages(self, s: int, b: int, keep=()) -> None:
+        """Host mirror of slot (s, b)'s page release. With prefix sharing
+        the per-slot flows are asymmetric but the shard's mirror stays
+        exact: a page this slot POPPED (reserved) that others still
+        reference is NOT freed (give back one page fewer), and a page
+        this slot merely adopted whose refcount just hit zero IS freed
+        (give back one page more) — every physical page is charged once
+        by its popper and credited once by its last holder. ``keep``
+        pages (a preemption pin) stay resident: their references transfer
+        to the pin, as ``paged.release_slots_keep`` does on device."""
+        ret = self._slot_pages[s][b]
+        if self.sharing:
+            for p in self._slot_own_idx[s].pop(b, []):
+                if p in keep:
+                    ret -= 1           # stays resident under the pin
+                    continue
+                self._page_ref[s][p] -= 1
+                if self._page_ref[s][p] <= 0:
+                    self._drop_index_page(s, p)
+                else:
+                    ret -= 1           # survives under someone else's map
+            for p in self._slot_shared_in[s].pop(b, []):
+                if p in keep:
+                    continue           # adopted ref transferred to the pin
+                self._page_ref[s][p] -= 1
+                if self._page_ref[s][p] <= 0:
+                    self._drop_index_page(s, p)
+                    ret += 1           # last holder frees the original
+        self.free_pages[s] += ret
+        self._slot_pages[s][b] = 0
 
     # ---------------------------------------------------------------- faults
     # The three injectable launch sites (serving/faults.py) all follow the
@@ -966,7 +919,8 @@ class ServingEngine:
     # queued (admission re-queues its takes explicitly; prefill/decode work
     # was never dequeued) and is retried after an exponential backoff of
     # 2**fails quanta. max_retries consecutive failures raise FaultError
-    # out of run() with every reservation returned.
+    # out of run() with every reservation returned — or, on a fleet whose
+    # suspect shards leave a survivor, become shard loss.
 
     def _inject(self, site: str) -> None:
         if self.faults is not None:
@@ -975,20 +929,6 @@ class ServingEngine:
     def _site_ready(self, site: str) -> bool:
         return self._backoff.get(site, (0, 0))[1] <= self._quantum
 
-    def _site_failed(self, site: str) -> None:
-        fails = self._backoff.get(site, (0, 0))[0] + 1
-        self.fault_retries += 1
-        self.fault_retry_site[site] = self.fault_retry_site.get(site, 0) + 1
-        if fails > self.cfg.max_retries:
-            raise FaultError(
-                f"site {site!r} failed {fails} consecutive launches "
-                f"(max_retries={self.cfg.max_retries}); in-flight requests "
-                "are re-queued and reservations returned")
-        self._backoff[site] = (fails, self._quantum + 2 ** fails)
-
-    def _site_ok(self, site: str) -> None:
-        self._backoff.pop(site, None)
-
     def _faults_pending(self) -> bool:
         return bool(self._backoff)
 
@@ -996,94 +936,89 @@ class ServingEngine:
     def _try_preempt(self, req: Request) -> bool:
         """Evict ONE armed slot of a strictly lower priority class so
         ``req`` (the queue head) can be admitted; True if a slot was
-        freed. Admission re-evaluates the head afterwards — repeated calls
+        freed. The victim is the best over shards (lowest class, least
+        progress, then lowest shard); its pages return to its own shard's
+        pool. Admission re-evaluates the head afterwards — repeated calls
         evict at most one victim per shortfall, lowest class first."""
         if not self.cfg.preemption:
             return False
-        B = self.cfg.max_batch
-        progress = [
-            (self._slot_req[s].max_new_tokens - self.slot_budget[s])
-            if self._slot_req[s] is not None else 0
-            for s in range(B)]
-        victim = preempt.pick_victim(self._slot_armed, self._slot_prio,
-                                     progress, req.priority)
-        if victim is None:
+        best = None
+        for s in range(self.S):
+            progress = [
+                (self._slot_req[s][b].max_new_tokens
+                 - self.slot_budget[s][b])
+                if self._slot_req[s][b] is not None else 0
+                for b in range(self.B)]
+            b = preempt.pick_victim(self._slot_armed[s],
+                                    self._slot_prio[s], progress,
+                                    req.priority)
+            if b is None:
+                continue
+            key = (self._slot_prio[s][b], progress[b], -s)
+            if best is None or key < best[0]:
+                best = (key, s, b)
+        if best is None:
             return False
-        self._evict_slot(victim)
+        self._evict_slot(best[1], best[2])
         return True
 
-    def _evict_slot(self, slot: int) -> None:
-        """Evict the ARMED ``slot`` mid-decode (see serving/preempt.py for
-        the full contract): disarm its device state, release its pages
-        except the leading indexed run (refcounts transfer to a host pin,
-        keeping the computed prefix resident and adoptable), fold the
-        tokens generated so far into the request's prompt, and requeue it
-        at the front of its priority band. Resume is ordinary re-admission:
-        the folded prompt's leading pages hit the (pinned) prefix index, so
-        only the unshared tail is recomputed — metered as 'recompute'."""
-        req = self._slot_req[slot]
+    def _evict_slot(self, s: int, slot: int) -> None:
+        """Evict the ARMED slot (s, slot) mid-decode (see
+        serving/preempt.py for the full contract): disarm its device
+        state, release its pages except the leading indexed run
+        (refcounts transfer to a host pin, keeping the computed prefix
+        resident and adoptable), fold the tokens generated so far into
+        the request's prompt, and requeue it at the front of its priority
+        band. Resume is ordinary re-admission: the folded prompt's
+        leading pages hit the (pinned) prefix index, so only the unshared
+        tail is recomputed — metered as 'recompute'."""
+        req = self._slot_req[s][slot]
         resp = self.responses[req.rid]
-        remaining = self.slot_budget[slot]
+        remaining = self.slot_budget[s][slot]
         preempt.fold_for_resume(req, resp, remaining)
         pinned: List[int] = []
         if self.sharing:
-            held = set(self._slot_shared_in.get(slot, []))
-            held |= set(self._slot_own_idx.get(slot, []))
+            held = set(self._slot_shared_in[s].get(slot, []))
+            held |= set(self._slot_own_idx[s].get(slot, []))
             pinned = preempt.pinned_run(self._prompt_page_keys(req),
-                                        self._prefix_index, held)
-        mask = np.zeros((self.cfg.max_batch,), bool)
-        mask[slot] = True
-        n_keep = np.zeros((self.cfg.max_batch,), np.int32)
-        n_keep[slot] = len(pinned)
-        self.caches = dict(self.caches)
-        self.caches["paged"] = _RELEASE_KEEP(self.caches["paged"],
-                                             jnp.asarray(mask),
-                                             jnp.asarray(n_keep))
-        self.state = _DISARM(self.state, jnp.asarray([slot], jnp.int32))
-        self._account_eviction(slot, pinned)
+                                        self._prefix_index[s], held)
+        mask = np.zeros((self.S, self.B), bool)
+        mask[s, slot] = True
+        n_keep = np.zeros((self.S, self.B), np.int32)
+        n_keep[s, slot] = len(pinned)
+        self._launch_alloc(_RELEASE_KEEP, _RELEASE_KEEP_FLEET, mask, n_keep)
+        self._disarm([(s, slot)])
+        self._return_pages(s, slot, keep=set(pinned))
         if pinned:
-            self._pins[req.rid] = pinned
-        self._clear_slot(slot)
+            self._pins[req.rid] = (s, pinned)
+        self._clear_slot(s, slot)
+        self._req_shard.pop(req.rid, None)
         self.preemption_count += 1
         self._enqueue(req, resume=True)
 
-    def _account_eviction(self, slot: int, pinned: List[int]) -> None:
-        """Host mirror of ``release_slots_keep``: pinned pages' references
-        transfer to the pin (``_page_ref`` unchanged — the device refcount
-        didn't move either); everything else follows the ordinary
-        popper-charges-once / last-holder-credits-once release flows."""
-        ret = self._slot_pages[slot]
-        if self.sharing:
-            keep = set(pinned)
-            for p in self._slot_own_idx.pop(slot, []):
-                if p in keep:
-                    ret -= 1           # stays resident under the pin
-                    continue
-                self._page_ref[p] -= 1
-                if self._page_ref[p] <= 0:
-                    self._drop_index_page(p)
-                else:
-                    ret -= 1           # survives under someone else's map
-            for p in self._slot_shared_in.pop(slot, []):
-                if p in keep:
-                    continue           # adopted ref transferred to the pin
-                self._page_ref[p] -= 1
-                if self._page_ref[p] <= 0:
-                    self._drop_index_page(p)
-                    ret += 1           # last holder frees the original
-        self.free_pages += ret
-        self._slot_pages[slot] = 0
+    def _occupy(self, s: int, slot: int, req: Request, budget: int,
+                ctx: float, armed: bool) -> None:
+        """Point slot (s, slot)'s host mirrors at ``req``."""
+        self.slot_rid[s][slot] = req.rid
+        self.slot_budget[s][slot] = budget
+        self.slot_eos[s][slot] = req.eos_id
+        self._slot_ctx[s][slot] = ctx
+        self._slot_armed[s][slot] = armed
+        self._slo[s][slot] = req.slo_s
+        self._slot_req[s][slot] = req
+        self._slot_prio[s][slot] = req.priority
+        self._slot_deadline[s][slot] = req.deadline_s
 
-    def _clear_slot(self, slot: int) -> None:
-        self.slot_rid[slot] = -1
-        self.slot_budget[slot] = 0
-        self.slot_eos[slot] = None
-        self._slot_ctx[slot] = 0.0
-        self._slot_armed[slot] = False
-        self._slo[slot] = None
-        self._slot_req[slot] = None
-        self._slot_prio[slot] = 0
-        self._slot_deadline[slot] = None
+    def _clear_slot(self, s: int, slot: int) -> None:
+        self.slot_rid[s][slot] = -1
+        self.slot_budget[s][slot] = 0
+        self.slot_eos[s][slot] = None
+        self._slot_ctx[s][slot] = 0.0
+        self._slot_armed[s][slot] = False
+        self._slo[s][slot] = None
+        self._slot_req[s][slot] = None
+        self._slot_prio[s][slot] = 0
+        self._slot_deadline[s][slot] = None
 
     # ------------------------------------------------------------- deadlines
     def _cancel(self, rid: int, reason: str) -> None:
@@ -1110,20 +1045,22 @@ class ServingEngine:
             self.queue.remove(req)
             self._cancel(req.rid, "deadline")
         if self.chunked:
-            for req, slot in [p for p in self._prefilling
-                              if expired(p[0])]:
-                self._prefilling.remove((req, slot))
-                self._clear_slot(slot)
-                self._release_slots([slot])
-                self._cancel(req.rid, "deadline")
-        doomed = [s for s in range(self.cfg.max_batch)
-                  if self._slot_armed[s] and self._slot_req[s] is not None
-                  and expired(self._slot_req[s])]
-        for s in doomed:
-            self.state = _DISARM(self.state, jnp.asarray([s], jnp.int32))
-            rid = self.slot_rid[s]
-            self._clear_slot(s)
-            self._release_slots([s])
+            for s in range(self.S):
+                for req, slot in [p for p in self._prefilling[s]
+                                  if expired(p[0])]:
+                    self._prefilling[s].remove((req, slot))
+                    self._clear_slot(s, slot)
+                    self._release_slots([(s, slot)])
+                    self._cancel(req.rid, "deadline")
+        doomed = [(s, b) for s in range(self.S) for b in range(self.B)
+                  if self._slot_armed[s][b]
+                  and self._slot_req[s][b] is not None
+                  and expired(self._slot_req[s][b])]
+        for s, b in doomed:
+            self._disarm([(s, b)])
+            rid = self.slot_rid[s][b]
+            self._clear_slot(s, b)
+            self._release_slots([(s, b)])
             self._cancel(rid, "deadline")
 
     # ------------------------------------------------------------ admission
@@ -1155,15 +1092,16 @@ class ServingEngine:
         self._wait_samples.setdefault(req.priority, []).append(wait)
 
     def _admit(self) -> int:
-        """Batch-prefill waiting requests into free slots (phase 1).
+        """Admit waiting requests into free slots (phase 1), FCFS from the
+        queue head onto the shard placement picks (``_place``).
 
-        Paged mode admits FCFS by worst-case page reservation (prompt +
-        full decode budget, so alloc-on-write can never underflow the
-        device stack): a request that doesn't fit the REMAINING pool keeps
-        waiting; one whose prompt alone can never fit the TOTAL pool is
-        rejected outright instead of admitted-and-failed mid-prefill.
-        Returns the number of requests admitted; the ``repro.admit`` span
-        carries their rids.
+        Paged mode admits by worst-case page reservation (prompt + full
+        decode budget, so alloc-on-write can never underflow the device
+        stack): a request that doesn't fit the REMAINING pool of any shard
+        keeps waiting; one whose prompt alone can never fit a shard's
+        TOTAL pool is rejected outright instead of admitted-and-failed
+        mid-prefill. Returns the number of requests admitted; the
+        ``repro.admit`` span carries their rids.
 
         With ``preemption`` on, a shortfall (no free slot, or not enough
         free pages) for the queue head triggers eviction of ONE armed
@@ -1184,53 +1122,46 @@ class ServingEngine:
             return []                  # defer admissions; drain active work
         if self.queue and self.paged and not self._site_ready("page_alloc"):
             return []                  # backing off a faulted reservation
-        free = self.free_slots()
-        take: List[Request] = []
-        share: Dict[int, Tuple[int, List[int], int]] = {}
+        free = [self.free_slots(s) for s in range(self.S)]
+        taken = [0] * self.S
+        take: List[Tuple[Request, int, Tuple[int, List[int], int]]] = []
         while self.queue:
             req = self.queue[0]
-            if len(take) >= len(free):
+            open_slots = [len(f) - t for f, t in zip(free, taken)]
+            if not any(open_slots[s] > 0 for s in self._placeable()):
                 if not self._try_preempt(req):
                     break              # no slot and nobody to evict
-                free = self.free_slots()
+                free = [self.free_slots(s) for s in range(self.S)]
                 continue
             self._apply_pressure_clamp(req)
+            s, share = 0, (0, [], 0)
             if self.paged:
-                L = len(req.prompt)
-                ps = self.cfg.page_size
                 n_total = paged.pages_needed(
-                    L + max(req.max_new_tokens - 1, 0), ps)
+                    len(req.prompt) + max(req.max_new_tokens - 1, 0),
+                    self.cfg.page_size)
                 # pages have no ring eviction: a request whose prompt +
-                # decode budget exceeds the block table (max_len) or the
-                # whole pool can NEVER be represented — reject it instead
-                # of admitting into silent context loss (the contiguous
-                # engine ring-wraps such requests; paged must refuse them).
-                # The unshared worst case decides: shared pages are a
-                # transient property of current residents, not capacity.
+                # decode budget exceeds the block table (max_len) or a
+                # shard's whole pool can NEVER be represented — reject it
+                # instead of admitting into silent context loss (the
+                # contiguous engine ring-wraps such requests; paged must
+                # refuse them). The unshared worst case decides: shared
+                # pages are a transient property of current residents,
+                # not capacity.
                 if n_total > self.max_pages_slot or n_total > self.num_pages:
                     self.queue.popleft()
                     self._reject(req)
                     continue
-                resv = n_total
-                if self.sharing:
-                    # reserve only the UNSHARED worst case: the pages this
-                    # request will itself pop — novel prompt pages + decode
-                    # budget + (when the whole prompt is shared) the one
-                    # copy-on-write pop for the recomputed tail token.
-                    # Matching is re-done on every admission pass: the
-                    # index fills as earlier residents finish prefilling.
-                    n_pg, phys = self._match_prefix(req)
-                    first_tok = min(n_pg * ps, L - 1)
-                    resv = n_total - first_tok // ps
-                    share[req.rid] = (n_pg, phys, first_tok)
-                if resv > self.free_pages:
+                placed = self._place(req, open_slots)
+                if placed is None:
                     if self._try_preempt(req):
-                        free = self.free_slots()
+                        free = [self.free_slots(s) for s in range(self.S)]
                         continue       # evicted pages now in the pool
                     break              # keep waiting (FCFS, no overtaking)
-                self.free_pages -= resv
+                s, resv, share = placed
+                self.free_pages[s] -= resv
                 self._resv[req.rid] = resv
-            take.append(self.queue.popleft())
+            taken[s] += 1
+            take.append((self.queue.popleft(), s, share))
         if take and self.paged:
             try:
                 self._inject("page_alloc")
@@ -1238,70 +1169,74 @@ class ServingEngine:
                 # the reservation launch "failed": undo it exactly — give
                 # every page back and restore the takes at the queue head
                 # in order. Nothing device-side happened yet by design.
-                for req in reversed(take):
-                    self.free_pages += self._resv.pop(req.rid)
+                for req, s, _ in reversed(take):
+                    self.free_pages[s] += self._resv.pop(req.rid)
                     self.queue.appendleft(req)
                 self._site_failed("page_alloc")
                 return []
             self._site_ok("page_alloc")
         if self.paged:
-            self.peak_pages_reserved = max(self.peak_pages_reserved,
-                                           self.num_pages - self.free_pages)
+            for s in range(self.S):
+                self.peak_pages_reserved[s] = max(
+                    self.peak_pages_reserved[s],
+                    self.num_pages - self.free_pages[s])
         if not take:
             return []
-        if self.chunked:
-            # quantum scheduler: admission only claims the slot + pages and
-            # queues the request for chunk-at-a-time prefill — no prefill
-            # launch here, so decode slots are never stalled by admission
-            slot_iter = iter(free)
-            slots: List[int] = []
-            for req in take:
-                slot = next(slot_iter)
-                self.slot_rid[slot] = req.rid
-                self.slot_budget[slot] = 0           # armed after last chunk
-                self.slot_eos[slot] = req.eos_id
-                self._slot_ctx[slot] = 0.0
-                self._slo[slot] = req.slo_s
-                self._slot_pages[slot] = self._resv.pop(req.rid)
-                self._slot_req[slot] = req
-                self._slot_prio[slot] = req.priority
-                self._slot_deadline[slot] = req.deadline_s
-                self._stamp_admit(req)
-                req.prefill_pos = 0
-                self._prefilling.append((req, slot))
-                slots.append(slot)
-            self.caches = _BEGIN_CHUNKED(self.caches,
-                                         jnp.asarray(slots, jnp.int32))
-            if self.sharing:
-                for req, slot in zip(take, slots):
-                    self._adopt_prefix(req, slot, *share[req.rid])
-                    # the resumed request has re-adopted its pinned prefix
-                    # through the ordinary index path (increfs above) — the
-                    # pin's own references can go now, adopt-then-release
-                    # so the pages never transit refcount zero
-                    if req.rid in self._pins:
-                        self._drop_pin(req.rid)
-            return take
-        # bucket prompts: padded power-of-two buckets when the model masks
-        # pad tokens exactly; exact-length groups otherwise (rwkv/enc-dec).
-        # Buckets are clamped to max_len — past that the cache ring must
-        # keep the LAST W real tokens, so padding would evict real tokens
-        # in favor of pads; those prompts prefill at exact length.
-        padded = self.model.supports_padded_prefill
-        groups: Dict[int, List[Request]] = {}
-        for req in take:
-            L = len(req.prompt)
-            if padded and L <= self.cfg.max_len:
-                b = min(sampling.prefill_bucket(L, self.cfg.prefill_min_bucket),
-                        self.cfg.max_len)
-            else:
-                b = L
-            groups.setdefault(b, []).append(req)
-        slot_iter = iter(free)
-        for bucket, reqs in groups.items():
-            slots = [next(slot_iter) for _ in reqs]
-            self._prefill_group(bucket, reqs, slots)
-        return take
+        for req, s, _ in take:
+            self._req_shard[req.rid] = s
+            self.shard_requests[s] += 1
+        if not self.chunked:
+            # monolithic prefill at admission (one shard): padded power-
+            # of-two buckets when the model masks pad tokens exactly;
+            # exact-length groups otherwise (rwkv/enc-dec). Buckets are
+            # clamped to max_len — past that the cache ring must keep the
+            # LAST W real tokens, so padding would evict real tokens in
+            # favor of pads; those prompts prefill at exact length.
+            padded = self.model.supports_padded_prefill
+            groups: Dict[int, List[Request]] = {}
+            for req, _, _ in take:
+                L = len(req.prompt)
+                if padded and L <= self.cfg.max_len:
+                    b = min(sampling.prefill_bucket(
+                        L, self.cfg.prefill_min_bucket), self.cfg.max_len)
+                else:
+                    b = L
+                groups.setdefault(b, []).append(req)
+            slot_iter = iter(free[0])
+            for bucket, reqs in groups.items():
+                self._prefill_group(bucket, reqs,
+                                    [next(slot_iter) for _ in reqs])
+            return [req for req, _, _ in take]
+        slot_iter = [iter(f) for f in free]
+        claims = [(req, s, next(slot_iter[s]), share)
+                  for req, s, share in take]
+        # quantum scheduler: admission only claims the slot + pages and
+        # queues the request for chunk-at-a-time prefill — no prefill
+        # launch here, so decode slots are never stalled by admission
+        for req, s, slot, _ in claims:
+            self._occupy(s, slot, req, 0, 0.0, False)  # armed after last chunk
+            self._slot_pages[s][slot] = self._resv.pop(req.rid)
+            self._stamp_admit(req)
+            req.prefill_pos = 0
+            self._prefilling[s].append((req, slot))
+        # one slot-reset program for every shard, each shard's slot list
+        # padded with the sentinel id B (out-of-range scatters drop ->
+        # idle lanes run the same program and write nothing)
+        lanes = [[slot for _, t, slot, _ in claims if t == s]
+                 for s in range(self.S)]
+        self.caches = self._launch(_BEGIN_CHUNKED, _BEGIN_FLEET,
+                                   (self.caches,),
+                                   (stack_lanes(lanes, self.B),))
+        if self.sharing:
+            for req, s, slot, share in claims:
+                self._adopt_prefix(req, s, slot, *share)
+                # the resumed request has re-adopted its pinned prefix
+                # through the ordinary index path (increfs above) — the
+                # pin's own references can go now, adopt-then-release so
+                # the pages never transit refcount zero
+                if req.rid in self._pins:
+                    self._drop_pin(req.rid)
+        return [req for req, _, _ in take]
 
     def _prefill_group(self, bucket: int, reqs: List[Request],
                        slots: List[int]) -> None:
@@ -1347,7 +1282,7 @@ class ServingEngine:
         tot_real = sum(len(r.prompt) for r in reqs)
         rep = self._meter_prefill(n_pad, bucket, useful_seq=tot_real / n_pad)
         now = time.perf_counter()
-        released: List[int] = []
+        released: List[Tuple[int, int]] = []
         for i, (req, slot) in enumerate(zip(reqs, slots)):
             self._stamp_admit(req)
             resp = self.responses[req.rid]
@@ -1356,38 +1291,37 @@ class ServingEngine:
             resp.tokens.append(int(first_h[i]))
             resp.t_emit.append(now)
             if self.paged:
-                self._slot_pages[slot] = self._resv.pop(req.rid)
+                self._slot_pages[0][slot] = self._resv.pop(req.rid)
             if req.max_new_tokens <= 1:
                 resp.finished = True   # prefill token was the whole budget
                 resp.finish_reason = "length"
-                released.append(slot)  # return its prompt pages right away
+                released.append((0, slot))   # return its prompt pages now
                 continue               # slot stays free (device side agrees)
-            self.slot_rid[slot] = req.rid
-            self.slot_budget[slot] = req.max_new_tokens - 1
-            self.slot_eos[slot] = req.eos_id
-            self._slot_ctx[slot] = float(len(req.prompt))
-            self._slo[slot] = req.slo_s
-            self._slot_armed[slot] = True
-            self._slot_req[slot] = req
-            self._slot_prio[slot] = req.priority
-            self._slot_deadline[slot] = req.deadline_s
+            self._occupy(0, slot, req, req.max_new_tokens - 1,
+                         float(len(req.prompt)), True)
         self._release_slots(released)
 
-    def _adopt_prefix(self, req: Request, slot: int, n_pg: int,
+    def _adopt_prefix(self, req: Request, s: int, slot: int, n_pg: int,
                       phys: List[int], first_tok: int) -> None:
-        """Map a matched prefix run into the freshly claimed slot (device
-        increfs + logical-history rows) and start its chunk schedule at
-        the first unshared token."""
-        self._slot_shared_in[slot] = []
-        self._slot_own_idx[slot] = []
+        """Map a matched prefix run into the freshly claimed slot on shard
+        ``s`` (device increfs + logical-history rows; every other lane
+        sees the sentinel slot id and writes nothing) and start its chunk
+        schedule at the first unshared token."""
+        self._slot_shared_in[s][slot] = []
+        self._slot_own_idx[s][slot] = []
         if n_pg == 0:
             return
-        pages = np.full((self.max_pages_slot,), -1, np.int32)
-        pages[:n_pg] = phys
-        self.caches = _MAP_PREFIX(
-            self.caches, jnp.asarray(slot, jnp.int32), jnp.asarray(pages),
-            jnp.asarray(n_pg * self.cfg.page_size, jnp.int32),
-            jnp.asarray(first_tok, jnp.int32))
+        slot_a = np.full((self.S,), self.B, np.int32)
+        slot_a[s] = slot
+        pages = np.full((self.S, self.max_pages_slot), -1, np.int32)
+        pages[s, :n_pg] = phys
+        n_shared = np.zeros((self.S,), np.int32)
+        n_shared[s] = n_pg * self.cfg.page_size
+        start = np.zeros((self.S,), np.int32)
+        start[s] = first_tok
+        self.caches = self._launch(_MAP_PREFIX, _MAP_PREFIX_FLEET,
+                                   (self.caches,),
+                                   (slot_a, pages, n_shared, start))
         req.prefill_pos = first_tok
         req.shared_prefix_tokens = first_tok
         # whole prompt shared: the first chunk recomputes the tail token
@@ -1395,129 +1329,137 @@ class ServingEngine:
         # packer never puts two such rows in one launch
         req.cow_pending = first_tok < n_pg * self.cfg.page_size
         for p in phys:
-            self._page_ref[p] += 1
-        self._slot_shared_in[slot] = list(phys)
+            self._page_ref[s][p] += 1
+        self._slot_shared_in[s][slot] = list(phys)
         self.prefix_hit_tokens += first_tok
         self.prefix_shared_requests += 1
-        cur = sum(len(v) for v in self._slot_shared_in.values())
+        cur = sum(len(v) for d in self._slot_shared_in for v in d.values())
         self.peak_shared_mappings = max(self.peak_shared_mappings, cur)
 
-    def _register_prefix(self, req: Request, slot: int,
+    def _register_prefix(self, req: Request, s: int, slot: int,
                          row: np.ndarray) -> None:
-        """After the LAST chunk, publish the prompt's whole pages into the
-        prefix index (``row`` is the slot's block-table row, fetched with
-        the first-token sync — no extra device round-trip). First writer
-        wins: a page already indexed under the same key (this slot adopted
-        it, or a concurrent twin prefilled the same novel prefix) is not
-        re-registered; the slot's private duplicate stays untracked."""
-        own = self._slot_own_idx.setdefault(slot, [])
+        """After the LAST chunk, publish the prompt's whole pages into
+        shard ``s``'s prefix index (``row`` is the slot's block-table row,
+        fetched with the first-token sync — no extra device round-trip).
+        First writer wins: a page already indexed under the same key (this
+        slot adopted it, or a concurrent twin prefilled the same novel
+        prefix) is not re-registered; the slot's private duplicate stays
+        untracked."""
+        own = self._slot_own_idx[s].setdefault(slot, [])
         for i, key in enumerate(self._prompt_page_keys(req)):
             p = int(row[i])
-            if key not in self._prefix_index:
-                self._prefix_index[key] = p
-                self._page_key[p] = key
-                self._page_ref[p] = self._page_ref.get(p, 0) + 1
+            if key not in self._prefix_index[s]:
+                self._prefix_index[s][key] = p
+                self._page_key[s][p] = key
+                self._page_ref[s][p] = self._page_ref[s].get(p, 0) + 1
                 own.append(p)
 
     # ------------------------------------------------------ chunked prefill
     def _prefill_quantum(self) -> int:
         """Run AT MOST ONE prefill launch — the prefill half of a
-        scheduling quantum. The launch carries the FCFS head's next chunk
-        plus (``prefill_pack`` > 1) the whole remainders of requests behind
-        it while the combined token count fits ``prefill_chunk``, so decode
-        slots stall for one chunk budget's compute regardless of how many
-        small prompts are queued. Returns the number of launches (0 or 1)."""
-        if not self._prefilling:
+        scheduling quantum, for every shard at once. On each shard the
+        launch carries the FCFS head's next chunk plus (``prefill_pack``
+        > 1) the whole remainders of requests behind it while the combined
+        token count fits ``prefill_chunk``, so decode slots stall for one
+        chunk budget's compute regardless of how many small prompts are
+        queued; shards with nothing to prefill run sentinel lanes.
+        Returns the number of launches (0 or 1)."""
+        if not any(self._prefilling):
             return 0
         if not self._site_ready("prefill_chunk"):
             return 0                   # backing off a faulted chunk launch
         C = self.cfg.prefill_chunk
         with trace.span("prefill.prepare"):
-            packed = pack_chunks(self._prefilling, C, self.cfg.prefill_pack)
+            packs = [pack_chunks(q, C, self.cfg.prefill_pack)
+                     for q in self._prefilling]
             try:
                 self._inject("prefill_chunk")
             except InjectedFault:
                 # the launch never ran: the packed requests are still at
-                # the head of ``_prefilling`` with their prefill_pos
-                # untouched — the SAME chunks relaunch after backoff,
-                # nothing is dropped
+                # the head of each shard's ``_prefilling`` with their
+                # prefill_pos untouched — the SAME chunks relaunch after
+                # backoff, nothing is dropped
                 self._site_failed("prefill_chunk")
                 return 0
             self._site_ok("prefill_chunk")
-            n = len(packed)
-            tokens = np.zeros((n, C), np.int32)
-            mask = np.zeros((n, C), np.int32)
-            for i, (_, _, _, piece) in enumerate(packed):
-                tokens[i, :len(piece)] = piece
-                mask[i, :len(piece)] = 1
+            n = max(len(pk) for pk in packs)
+            tokens = np.zeros((self.S, n, C), np.int32)
+            mask = np.zeros((self.S, n, C), np.int32)
+            slots = np.full((self.S, n), self.B, np.int32)
+            for s, pk in enumerate(packs):
+                for i, (_, slot, _, piece) in enumerate(pk):
+                    tokens[s, i, :len(piece)] = piece
+                    mask[s, i, :len(piece)] = 1
+                    slots[s, i] = slot
         with trace.span("prefill.launch") as sp:
-            trace.tag(sp, "rids", (req.rid for req, _, _, _ in packed))
-            slots_a = jnp.asarray([slot for _, slot, _, _ in packed],
-                                  jnp.int32)
-            first, logits, tbl_rows, self.caches, counts = _CHUNK_PREFILL(
-                self.model, self.params, self.caches, jnp.asarray(tokens),
-                jnp.asarray(mask), slots_a, self._next_key(),
-                vocab=self.model.cfg.vocab,
-                temperature=self.cfg.temperature,
+            trace.tag(sp, "rids", (req.rid for pk in packs
+                                   for req, _, _, _ in pk))
+            first, logits, rows, self.caches, counts = self._launch(
+                _CHUNK_PREFILL, _CHUNK_FLEET, (self.caches,),
+                (tokens, mask, slots), runs_model=True,
+                vocab=self.model.cfg.vocab, temperature=self.cfg.temperature,
                 page_size=self.cfg.page_size, sharing=self.sharing)
         self.prefill_chunks += 1
         if counts:
             self._pending_counts.append(counts)
-        finished: List[int] = []
-        for i, (req, slot, pos0, piece) in enumerate(packed):
-            req.prefill_pos += len(piece)
-            if self.sharing and piece:
-                # mirror the device's copy-on-write: if this chunk wrote
-                # into an adopted page still shared (refcount > 1), the
-                # device swapped in a private copy — the slot no longer
-                # maps the indexed original. Sole-owner pages are written
-                # in place and stay mapped (and indexed; the rewrite
-                # recomputes identical rows, so the entry remains valid).
-                shared = self._slot_shared_in.get(slot) or []
-                lp = pos0 // self.cfg.page_size
-                if lp < len(shared) and self._page_ref[shared[lp]] > 1:
-                    self._page_ref[shared[lp]] -= 1
-                    self._slot_shared_in[slot] = shared[:lp]
-                req.cow_pending = False    # its CoW (if any) just ran
-            if req.prefill_pos >= len(req.prompt):
-                finished.append(i)
+        finished: List[Tuple[int, int]] = []   # (shard, row)
+        for s, pk in enumerate(packs):
+            done = 0
+            for i, (req, slot, pos0, piece) in enumerate(pk):
+                req.prefill_pos += len(piece)
+                if self.sharing and piece:
+                    # mirror the device's copy-on-write: if this chunk
+                    # wrote into an adopted page still shared (refcount
+                    # > 1), the device swapped in a private copy — the
+                    # slot no longer maps the indexed original. Sole-owner
+                    # pages are written in place and stay mapped (and
+                    # indexed; the rewrite recomputes identical rows, so
+                    # the entry remains valid).
+                    shared = self._slot_shared_in[s].get(slot) or []
+                    lp = pos0 // self.cfg.page_size
+                    if lp < len(shared) and self._page_ref[s][shared[lp]] > 1:
+                        self._page_ref[s][shared[lp]] -= 1
+                        self._slot_shared_in[s][slot] = shared[:lp]
+                    req.cow_pending = False    # its CoW (if any) just ran
+                if req.prefill_pos >= len(req.prompt):
+                    finished.append((s, i))
+                    done += 1
+            # by construction only the head can be mid-prompt after a
+            # launch (packed tails always carried their whole remainder),
+            # so finished rows are exactly the first ``done`` entries
+            assert done in (0, len(pk)), "packed tail finished before head"
+            for _ in range(done):
+                self._prefilling[s].popleft()
         if not finished:
             return 1                   # intermediate chunk: no host sync
-        # by construction only the head can be mid-prompt after a launch
-        # (packed tails always carried their whole remainder), so finished
-        # rows are exactly the first len(finished) deque entries
-        assert finished == list(range(n)), "packed tail finished before head"
-        for _ in finished:
-            self._prefilling.popleft()
         # last chunks: the sampled tokens are the requests' first emissions
         # — ONE host sync for every request finishing in this launch
         with trace.span("prefill.fetch"):
-            first_h, rows_h, pending = jax.device_get(
-                (first, tbl_rows, self._pending_counts))
+            first_h, rows_h, pending = self._fetch(
+                (first, rows, self._pending_counts))
         self._add_prefill_counts(pending)
-        first_h, rows_h = np.asarray(first_h), np.asarray(rows_h)
         self.prefill_batches += 1      # one first-token host sync
         with trace.span("prefill.finish") as sp:
-            trace.tag(sp, "rids", (packed[i][0].rid for i in finished))
-            self._finish_prefill(packed, finished, first_h, rows_h, logits)
+            trace.tag(sp, "rids", (packs[s][i][0].rid for s, i in finished))
+            self._finish_prefill(packs, finished, first_h, rows_h, logits)
         return 1
 
-    def _finish_prefill(self, packed, finished: List[int],
+    def _finish_prefill(self, packs, finished: List[Tuple[int, int]],
                         first_h: np.ndarray, rows_h: np.ndarray,
                         logits: jax.Array) -> None:
         """Requests whose last chunk just ran: register their prompt pages
-        in the prefix index, meter their prefill, record their first
-        token, and arm their slots for decode (or release a slot whose
-        budget the first token spent)."""
+        in their shard's prefix index, meter their prefill, record their
+        first token, and arm their slots for decode with one launch (or
+        release a slot whose budget the first token spent)."""
         now = time.perf_counter()
-        released: List[int] = []
-        arm: List[Tuple[int, int, int, int]] = []   # slot, tok, budget, eos
-        for i in finished:
-            req, slot, _, _ = packed[i]
+        released: List[Tuple[int, int]] = []
+        arm: List[Tuple[int, int, int, int, int]] = []  # s, slot, tok, budget, eos
+        for s, i in finished:
+            req, slot, _, _ = packs[s][i]
             if self.sharing:
-                self._register_prefix(req, slot, rows_h[i])
+                self._register_prefix(req, s, slot, rows_h[s, i])
             if self.prompt_logits is not None:
-                self.prompt_logits[req.rid] = logits[i]
+                self.prompt_logits[req.rid] = self._lane_of(logits, s)[i]
             # chunking changes the schedule, not the modeled energy:
             # attribute the request's prefill at its true prompt length
             # exactly once, so modeled J/token is invariant to the
@@ -1528,14 +1470,15 @@ class ServingEngine:
             # (operational J/prompt-token falls with every cache hit).
             rep = self._meter_prefill(
                 1, len(req.prompt), skip=req.shared_prefix_tokens,
-                phase="recompute" if req.preemptions else "prefill")
+                phase="recompute" if req.preemptions else "prefill",
+                shard=s)
             resp = self.responses[req.rid]
             resp.prefill_s += rep.t_total
             resp.energy_j += rep.energy_j
             if req.preemptions:
                 resp.recompute_j += rep.energy_j
                 self.preempted_recompute_j += rep.energy_j
-            tok = int(first_h[i])
+            tok = int(first_h[s, i])
             resp.tokens.append(tok)
             resp.t_emit.append(now)
             budget = req.max_new_tokens - 1
@@ -1549,35 +1492,32 @@ class ServingEngine:
             if budget <= 0 or eos_hit:
                 resp.finished = True   # prefill token was the whole budget
                 resp.finish_reason = "eos" if eos_hit else "length"
-                self.slot_rid[slot] = -1
-                self._slo[slot] = None
-                self._slot_req[slot] = None
-                self._slot_prio[slot] = 0
-                self._slot_deadline[slot] = None
-                released.append(slot)
+                self._clear_slot(s, slot)
+                released.append((s, slot))
                 continue
-            arm.append((slot, tok, budget,
+            arm.append((s, slot, tok, budget,
                         -1 if req.eos_id is None else req.eos_id))
-            self.slot_budget[slot] = budget
-            self._slot_ctx[slot] = float(len(req.prompt))
-            self._slot_armed[slot] = True
+            self.slot_budget[s][slot] = budget
+            self._slot_ctx[s][slot] = float(len(req.prompt))
+            self._slot_armed[s][slot] = True
         if arm:
             # one batched arm for every request finishing in this launch
             # (first tokens come from the host fetch above — no extra sync)
-            self.cur_tokens, self.state = _ARM(
-                self.cur_tokens, self.state,
-                jnp.asarray([a[0] for a in arm], jnp.int32),
-                jnp.asarray([a[1] for a in arm], jnp.int32),
-                jnp.asarray([a[2] for a in arm], jnp.int32),
-                jnp.asarray([a[3] for a in arm], jnp.int32))
+            def lanes(col, fill):
+                return stack_lanes([[a[col] for a in arm if a[0] == s]
+                                     for s in range(self.S)], fill)
+            self.cur_tokens, self.state = self._launch(
+                _ARM, _ARM_FLEET, (self.cur_tokens, self.state),
+                (lanes(1, self.B), lanes(2, 0), lanes(3, 0), lanes(4, -1)))
         self._release_slots(released)
 
     # --------------------------------------------------------------- decode
     def _decode_chunk(self, max_steps: int) -> bool:
         """One fused on-device chunk of up to ``sync_every`` decode steps
-        for all armed slots (phase 2); a single host sync at the end.
-        Slots still mid-chunked-prefill ride along inert (device ``active``
-        false, cursors frozen by the fused step). Returns whether a chunk
+        for every armed slot on every shard (phase 2); a single host sync
+        at the end, on the (S, n, B) token/emission matrices. Slots still
+        mid-chunked-prefill ride along inert (device ``active`` false,
+        cursors frozen by the fused step). Returns whether a chunk
         actually launched (False while the ``decode_scan`` site backs off
         a fault — armed slots keep their state and relaunch later)."""
         if not self._site_ready("decode_scan"):
@@ -1591,20 +1531,21 @@ class ServingEngine:
             self._site_failed("decode_scan")
             return False
         self._site_ok("decode_scan")
-        budgets = [self.slot_budget[s] for s in range(self.cfg.max_batch)
-                   if self._slot_armed[s]]
+        budgets = [self.slot_budget[s][b]
+                   for s in range(self.S) for b in range(self.B)
+                   if self._slot_armed[s][b]]
         n = min(self.cfg.sync_every, max(max(budgets), 1),
                 max(max_steps - self._steps, 1))
         with trace.span("decode.launch"):
             (self.caches, self.cur_tokens, self.state, tok_mat,
-             emit_mat, counts) = _FUSED_STEPS(
-                self.model, self.params, self.caches, self.cur_tokens,
-                self.state, self._next_key(), n_steps=n,
-                temperature=self.cfg.temperature,
+             emit_mat, counts) = self._launch(
+                _FUSED_STEPS, _FUSED_FLEET,
+                (self.caches, self.cur_tokens, self.state), runs_model=True,
+                n_steps=n, temperature=self.cfg.temperature,
                 page_size=self.cfg.page_size if self.paged else 0,
                 freeze_inactive=self.chunked)
         with trace.span("decode.fetch"):
-            tok_h, emit_h, counts_h, pending = jax.device_get(
+            tok_h, emit_h, counts_h, pending = self._fetch(
                 (tok_mat, emit_mat, counts, self._pending_counts))
         self._add_prefill_counts(pending)
         if counts_h:
@@ -1626,61 +1567,69 @@ class ServingEngine:
     def _add_prefill_counts(self, fetched: List[Dict]) -> None:
         """Add fetched chunk counts to the totals; the list on device is
         emptied (they were fetched with this sync's tokens)."""
-        self.moe_prefill_pairs += sum(int(c["moe_pairs"]) for c in fetched)
+        self.moe_prefill_pairs += sum(int(c["moe_pairs"].sum())
+                                      for c in fetched)
         self._pending_counts = []
 
     def _emit_decoded(self, n: int, tok_h: np.ndarray,
-                      emit_h: np.ndarray) -> List[int]:
+                      emit_h: np.ndarray) -> List[Tuple[int, int]]:
         """Hand each micro-step's emitted tokens to their responses and
-        meter the step; returns the slots whose request finished."""
+        meter each shard's step; returns the finished (shard, slot)
+        pairs."""
         now = time.perf_counter()
-        released: List[int] = []
+        released: List[Tuple[int, int]] = []
         for i in range(n):
-            act = emit_h[i]
-            n_active = int(act.sum())
-            if n_active == 0:
-                continue               # all slots drained mid-chunk
-            ctx = float(np.mean([self._slot_ctx[s]
-                                 for s in np.flatnonzero(act)]))
-            rep = self._meter_decode(n_active, max(ctx, 1.0))
-            per_tok_t = rep.t_total / n_active
-            per_tok_e = rep.energy_j / n_active
-            for slot in np.flatnonzero(act):
-                rid = self.slot_rid[slot]
-                resp = self.responses[rid]
-                tok = int(tok_h[i, slot])
-                resp.tokens.append(tok)
-                resp.t_emit.append(now)
-                resp.decode_s += per_tok_t
-                resp.energy_j += per_tok_e
-                self._slot_ctx[slot] += 1.0
-                self.slot_budget[slot] -= 1
-                eos_hit = (self.slot_eos[slot] is not None
-                           and tok == self.slot_eos[slot])
-                if self.slot_budget[slot] <= 0 or eos_hit:
-                    resp.finished = True
-                    resp.finish_reason = "eos" if eos_hit else "length"
-                    self.slot_rid[slot] = -1
-                    self._slot_armed[slot] = False
-                    self._slo[slot] = None
-                    self._slot_req[slot] = None
-                    self._slot_prio[slot] = 0
-                    self._slot_deadline[slot] = None
-                    released.append(int(slot))
-            self._steps += 1
+            emitted_any = False
+            for s in range(self.S):
+                act = emit_h[s, i]
+                n_active = int(act.sum())
+                if n_active == 0:
+                    continue           # this shard drained mid-chunk
+                emitted_any = True
+                self.shard_steps += 1
+                ctx = float(np.mean([self._slot_ctx[s][b]
+                                     for b in np.flatnonzero(act)]))
+                rep = self._meter_decode(n_active, max(ctx, 1.0), shard=s)
+                per_tok_t = rep.t_total / n_active
+                per_tok_e = rep.energy_j / n_active
+                for b in np.flatnonzero(act):
+                    rid = self.slot_rid[s][b]
+                    resp = self.responses[rid]
+                    tok = int(tok_h[s, i, b])
+                    resp.tokens.append(tok)
+                    resp.t_emit.append(now)
+                    resp.decode_s += per_tok_t
+                    resp.energy_j += per_tok_e
+                    self._slot_ctx[s][b] += 1.0
+                    self.slot_budget[s][b] -= 1
+                    eos_hit = (self.slot_eos[s][b] is not None
+                               and tok == self.slot_eos[s][b])
+                    if self.slot_budget[s][b] <= 0 or eos_hit:
+                        resp.finished = True
+                        resp.finish_reason = "eos" if eos_hit else "length"
+                        self._clear_slot(s, b)
+                        released.append((s, int(b)))
+            if emitted_any:
+                self._steps += 1
         return released
 
     def step(self, max_steps: int = 10_000) -> bool:
-        """Run ONE scheduling quantum: deadline sweep (when any request
-        declared one), admission, at most one prefill chunk, one fused
-        decode scan. Returns whether anything progressed — the async
-        server drives this directly so it can interleave submissions and
-        stream tokens between quanta."""
+        """Run ONE scheduling quantum: a fault campaign's shard-loss and
+        admin declarations, deferral release, deadline sweep (when any
+        request declared one), drain and power-cap progress, admission,
+        at most one prefill chunk launch, one fused decode scan — for
+        every shard at once. Returns whether anything progressed — the
+        async server drives this directly so it can interleave
+        submissions and stream tokens between quanta."""
         self._quantum += 1
         with trace.quantum(self._quantum) as q:
+            ev0, mig0 = self.shard_down_events, self.migrations
+            if self.faults is not None:
+                self._absorb_declarations()
             released = self._release_deferred() if self.deferred else 0
             if self._has_deadlines:
                 self._sweep_deadlines()
+            self._rebalance()
             admitted = self._admit()
             chunks = self._prefill_quantum() if self.chunked else 0
             decoded = (self._decode_chunk(max_steps) if self.decoding
@@ -1691,13 +1640,26 @@ class ServingEngine:
                 if trace.enabled():
                     q.set_metadata(**self._quantum_counts)
                 self._quantum_counts = {}
-        return bool(released or admitted or chunks or decoded)
+            dt = max(self._q_time)
+            if dt > 0.0:
+                if self.S > 1:
+                    # shards run in parallel: the quantum's wall time is
+                    # the slowest shard's (one meter advances it itself)
+                    self.clock.hours += dt / 3600.0
+                self._q_time = [0.0] * self.S
+        # a recovery event IS progress: the watchdog can declare a shard
+        # dead inside a launch handler (after this quantum's admission
+        # pass), and the evacuees it re-queued must reach the next
+        # admission pass — not be misread as an unplaceable head
+        return bool(released or admitted or chunks or decoded
+                    or self.shard_down_events != ev0
+                    or self.migrations != mig0)
 
     def _count_pages(self, q) -> None:
         """Add this quantum's pages reserved (admission's worst case) and
         pages mapped (handed out by the device allocator) to their running
         totals; a traced quantum carries both as arguments."""
-        reserved = self.num_pages - self.free_pages
+        reserved = self.num_pages * self.S - sum(self.free_pages)
         mapped = self.mapped_pages()
         self.reserved_page_quanta += reserved
         self.mapped_page_quanta += mapped
@@ -1705,22 +1667,26 @@ class ServingEngine:
             q.set_metadata(reserved_pages=reserved, mapped_pages=mapped)
 
     def mapped_pages(self) -> int:
-        """Pages the device allocator has handed out (``num_pages`` minus
-        its free-stack top), mirrored on the host without a device sync:
-        each live slot's written pages less those it adopted, plus every
-        page in the prefix index once (adopted, registered or pinned) less
-        those a live slot registered, which its own count holds."""
+        """Pages the device allocators have handed out (``num_pages``
+        minus the free-stack top, summed over shards), mirrored on the
+        host without a device sync: each live slot's written pages less
+        those it adopted, plus every page in a prefix index once
+        (adopted, registered or pinned) less those a live slot
+        registered, which its own count holds."""
         ps = self.cfg.page_size
         n = 0
-        for s, rid in enumerate(self.slot_rid):
-            if rid >= 0:
-                written = (int(self._slot_ctx[s]) if self._slot_armed[s]
-                           else self._slot_req[s].prefill_pos)
-                n += -(-written // ps)
+        for s in range(self.S):
+            for b, rid in enumerate(self.slot_rid[s]):
+                if rid >= 0:
+                    written = (int(self._slot_ctx[s][b])
+                               if self._slot_armed[s][b]
+                               else self._slot_req[s][b].prefill_pos)
+                    n += -(-written // ps)
         if self.sharing:
-            n += len(self._page_ref)
-            n -= sum(len(v) for v in self._slot_shared_in.values())
-            n -= sum(len(v) for v in self._slot_own_idx.values())
+            for s in range(self.S):
+                n += len(self._page_ref[s])
+                n -= sum(len(v) for v in self._slot_shared_in[s].values())
+                n -= sum(len(v) for v in self._slot_own_idx[s].values())
         return n
 
     def _resolve_stall(self) -> None:
@@ -1729,14 +1695,18 @@ class ServingEngine:
         missing pages (spill them — resume just recomputes more) or the
         head request can never fit and must fail. Shared by run() and the
         async server's drive loop."""
-        if self.paged and self._pins and self.free_pages < self.num_pages:
+        live = self.health.live
+        if (self.paged and self._pins
+                and any(self.free_pages[s] < self.num_pages for s in live)):
             for rid in list(self._pins):
                 self._drop_pin(rid)
             return
-        if not self.paged or self.free_pages == self.num_pages:
-            # nothing running and admission had the ENTIRE pool available
-            # yet still refused the head request: it can never fit — fail
-            # it rather than spin
+        if not self.paged or all(self.free_pages[s] == self.num_pages
+                                 for s in live):
+            # nothing running, every LIVE shard's whole pool free, and
+            # admission still refused the head: it can never fit — per-
+            # shard capacity is identical, so never-fits is the same
+            # verdict on a degraded fleet — fail it rather than spin
             self._reject(self.queue.popleft())
         else:
             raise RuntimeError(        # unreachable: release returns
@@ -1781,7 +1751,8 @@ class ServingEngine:
     @property
     def host_syncs(self) -> int:
         """Device->host synchronization points (decode chunk fetches plus
-        one first-token fetch per prefill batch)."""
+        one first-token fetch per prefill batch) — S shards have the same
+        sync count as one."""
         return self.decode_chunks + self.prefill_batches
 
     def stats(self) -> Dict[str, float]:
@@ -1802,18 +1773,19 @@ class ServingEngine:
                 slo_ok += (r.prefill_s + r.decode_s) <= slo
         out: Dict[str, float] = {}
         if self.paged:
+            peak = sum(self.peak_pages_reserved)
             out.update({
                 "paged": 1.0,
                 "page_size": self.cfg.page_size,
-                "pages_total": self.num_pages,
-                "peak_pages_reserved": self.peak_pages_reserved,
-                "free_pages": self.free_pages,
+                "pages_total": self.num_pages * self.S,
+                "pages_per_shard": self.num_pages,
+                "peak_pages_reserved": peak,
+                "free_pages": sum(self.free_pages),
                 "reserved_page_quanta": self.reserved_page_quanta,
                 "mapped_page_quanta": self.mapped_page_quanta,
                 # provisioned KV rows actually backing peak load — feeds
                 # the embodied-carbon memory model (ROADMAP: paged pool)
-                "peak_kv_rows_reserved":
-                    self.peak_pages_reserved * self.cfg.page_size,
+                "peak_kv_rows_reserved": peak * self.cfg.page_size,
             })
         if self.chunked:
             out.update({
@@ -1842,7 +1814,7 @@ class ServingEngine:
                 # actually backed peak load — shared pages counted ONCE,
                 # which is why peak_kv_rows_reserved (the Eq. 2-4 embodied
                 # input) falls under prefix-heavy traffic
-                "unique_pages": self.peak_pages_reserved,
+                "unique_pages": sum(self.peak_pages_reserved),
             })
         # front door: queueing, degradation, preemption, fault recovery
         out.update({
@@ -1906,4 +1878,5 @@ class ServingEngine:
             "decode_adpe_mg": dc.adpe_mg,
             "water_per_token_l": t.water_per_token,
         })
+        self._fleet_stats(out)
         return out

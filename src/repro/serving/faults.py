@@ -18,9 +18,9 @@ Injectable sites (the strings ``ServingEngine._inject`` is called with):
   * ``"prefill_chunk"``  — the chunked-prefill launch, before the chunk
                            touches the device cache.
   * ``"decode_scan"``    — the fused decode chunk launch.
-  * ``"shard_down"``     — whole-shard loss (fleet engines only): the
+  * ``"shard_down"``     — whole-shard loss (fleets of S > 1): the
                            plan names a ``shard``; at the chosen quantum
-                           the ``ShardedServingEngine`` declares it dead
+                           the engine declares it dead
                            and evacuates its in-flight work onto the
                            survivors. Not a retry site — there is no
                            backoff, the shard stays dead until an
@@ -38,7 +38,7 @@ slots by migration until its modeled draw fits). Both name a ``shard``
 like ``shard_down`` and are absorbed by ``admin_fires`` — declarations,
 not retries.
 
-``HealthMonitor`` is the fleet's watchdog: the sharded engine reports
+``HealthMonitor`` is the fleet's watchdog: the engine reports
 which shards each faulted/successful launch touched, and a shard whose
 CONSECUTIVE faulted-launch count exceeds ``max_retries`` is declared
 dead (same budget the per-site backoff gives a launch site before
@@ -216,7 +216,7 @@ class HealthMonitor:
     """Fleet health watchdog: per-shard consecutive-faulted-launch
     counters plus the authoritative dead set.
 
-    The sharded engine reports every launch outcome with the set of
+    The engine reports every launch outcome with the set of
     shards the launch TOUCHED (shards with packed prefill work, armed
     decode slots, or admission takes this quantum). A successful launch
     clears its shards' counters; a shard whose consecutive count exceeds

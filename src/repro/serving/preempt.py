@@ -9,8 +9,8 @@ slots keep burning is spent on exactly the wrong tokens; EcoServe
 (arXiv:2502.05043) and GreenLLM (arXiv:2412.20322) both assume the
 engine can reclaim and reassign resources mid-request.
 
-The contract (implemented in ``ServingEngine._evict_slot`` and the
-sharded twin; property-tested in tests/test_preemption.py):
+The contract (implemented in ``ServingEngine._evict_slot``, at any
+shard count; property-tested in tests/test_preemption.py):
 
   * Only ARMED slots (mid-decode) are victims — a mid-prefill slot has
     produced nothing a user has seen, so cancelling it is the deadline

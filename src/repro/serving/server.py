@@ -34,7 +34,7 @@ budget) stops the drive loop, marks every unfinished response with
 reason ``"error"``, ends all streams, and re-raises from ``result()`` /
 ``drain()`` — a wedged fleet fails loudly, it never hangs clients.
 
-Shard loss is NOT an error at this layer: when the fleet engine's health
+Shard loss is NOT an error at this layer: when a fleet's (S > 1) health
 watchdog converts a retry-exhausted launch site into a shard-down
 declaration, the engine evacuates in-flight work onto the survivors and
 keeps serving, so the server sees an ordinary (if slower) quantum. Only
@@ -54,7 +54,7 @@ _END = object()                        # per-stream end-of-tokens sentinel
 
 
 class AsyncServingServer:
-    """Wrap a ``ServingEngine`` or ``ShardedServingEngine`` (anything with
+    """Wrap a ``ServingEngine`` at any shard count (anything with
     ``submit``/``step``/``queue``/``active``/``decoding``/``deferred``/
     ``responses`` and the stall/fault/deferral helpers) behind an asyncio
     streaming API. Deferred (low-CI-window) work keeps the driver alive:

@@ -8,7 +8,7 @@ scheduling quantum and phase, never per token; the spans of one request
 are linked by the rids they carry. Arguments are attached only while a
 trace is being taken.
 
-One quantum of ``ServingEngine`` or ``ShardedServingEngine``::
+One quantum of ``ServingEngine``, at any shard count::
 
     repro.quantum            step_num = the engine's quantum count
       repro.admit            rids admitted

@@ -68,7 +68,7 @@ def assert_pool_clean(eng):
     assert int(alloc["top"]) == P
     assert (np.asarray(alloc["tbl"]) == -1).all()
     assert (np.asarray(alloc["ref"]) == 0).all()
-    assert eng.free_pages == eng.num_pages
+    assert eng.free_pages == [eng.num_pages]
 
 
 # ------------------------------------------------------------------ parity
@@ -403,13 +403,13 @@ class WatchedEngine(ServingEngine):
         self.cow_launches = self.frozen_scans = 0
 
     def _prefill_quantum(self):
-        cow = any(req.cow_pending for req, _ in self._prefilling)
+        cow = any(req.cow_pending for req, _ in self._prefilling[0])
         ran = super()._prefill_quantum()
         self.cow_launches += bool(ran and cow)
         return ran
 
     def _decode_chunk(self, max_steps):
-        frozen = any(0 < req.prefill_pos for req, _ in self._prefilling)
+        frozen = any(0 < req.prefill_pos for req, _ in self._prefilling[0])
         ran = super()._decode_chunk(max_steps)
         self.frozen_scans += bool(ran and frozen)
         return ran

@@ -61,7 +61,7 @@ def _engine(m, params, **kw):
 
 def check_quantum_invariants(eng):
     """Truths that hold after EVERY scheduling quantum."""
-    slot_rids = {r for r in eng.slot_rid if r >= 0}
+    slot_rids = {r for row in eng.slot_rid for r in row if r >= 0}
     for req in eng.deferred:
         rid = req.rid
         assert rid in eng.deferred_rids
@@ -75,7 +75,7 @@ def check_quantum_invariants(eng):
     assert len(eng.deferred_rids) == len(eng.deferred)
     # page conservation: free + slot-held + pending reservations == pool
     # (sharing off, so no shared mappings or pins complicate the count)
-    assert (eng.free_pages + sum(eng._slot_pages)
+    assert (sum(eng.free_pages) + sum(map(sum, eng._slot_pages))
             + sum(eng._resv.values()) == eng.num_pages)
 
 
@@ -145,7 +145,7 @@ def _check_release_interleaving(parts, specs, seed):
     assert admit_order == deferred_class, \
         f"release reordered: submitted {deferred_class}, " \
         f"admitted {admit_order}"
-    assert eng.free_pages == eng.num_pages
+    assert eng.free_pages == [eng.num_pages]
 
 
 def _check_preemption_interleaving(parts, specs, seed):
@@ -157,7 +157,7 @@ def _check_preemption_interleaving(parts, specs, seed):
         resp = eng.responses[rid]
         assert resp.finished
         assert resp.finish_reason != "deadline"
-    assert eng.free_pages == eng.num_pages
+    assert eng.free_pages == [eng.num_pages]
 
 
 if HAVE_HYPOTHESIS:

@@ -39,7 +39,7 @@ def test_oversized_prompt_rejected_not_admitted(parts):
     assert resps[0].rejected and resps[0].finished and not resps[0].tokens
     assert not resps[1].rejected and len(resps[1].tokens) == 5
     assert eng.prefill_batches == 1                  # rid 0 never prefilled
-    assert eng.free_pages == eng.num_pages           # nothing leaked
+    assert eng.free_pages == [eng.num_pages]           # nothing leaked
 
 
 def test_request_waits_for_free_pages_then_completes(parts):
@@ -57,7 +57,7 @@ def test_request_waits_for_free_pages_then_completes(parts):
     assert all(not r.rejected and r.finished and len(r.tokens) == 8
                for r in resps.values())
     assert eng.stats()["peak_pages_reserved"] <= 6
-    assert eng.free_pages == eng.num_pages
+    assert eng.free_pages == [eng.num_pages]
 
 
 def test_fcfs_no_overtaking_under_pressure(parts):
@@ -109,7 +109,7 @@ def test_prompt_exactly_at_capacity_is_admitted(parts):
     resps = {r.rid: r for r in eng.run()}
     assert resps[0].finished and not resps[0].rejected
     assert len(resps[0].tokens) == 9
-    assert eng.free_pages == eng.num_pages
+    assert eng.free_pages == [eng.num_pages]
 
 
 # ----------------------------------------------- per-tenant rate limiting

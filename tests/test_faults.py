@@ -60,7 +60,7 @@ def assert_pool_clean(eng):
     assert int(alloc["top"]) == P
     assert (np.asarray(alloc["tbl"]) == -1).all()
     assert (np.asarray(alloc["ref"]) == 0).all()
-    assert eng.free_pages == eng.num_pages
+    assert eng.free_pages == [eng.num_pages]
 
 
 # ----------------------------------------------------------- site-by-site
@@ -99,13 +99,13 @@ def test_page_alloc_rollback_exact(parts):
     reqs = _reqs(2)
     for r in reqs:
         eng.submit(Request(**r))
-    free0 = eng.free_pages
+    free0 = list(eng.free_pages)
     order0 = [r.rid for r in eng.queue]
     assert eng.step() == 0             # the faulted quantum: no progress
     assert eng.free_pages == free0, "rollback leaked reservation pages"
     assert [r.rid for r in eng.queue] == order0, "rollback reordered queue"
     assert not eng._resv
-    assert eng.peak_pages_reserved == 0, \
+    assert eng.peak_pages_reserved == [0], \
         "faulted reservations must not count as provisioned peak"
     got = {r.rid: r for r in eng.run()}
     assert all(r.finished for r in got.values())
@@ -153,7 +153,7 @@ def test_retry_exhaustion_raises_fault_error_state_consistent(parts):
         eng.submit(Request(**r))
     with pytest.raises(FaultError, match="page_alloc"):
         eng.run()
-    assert eng.free_pages == eng.num_pages   # reservations all returned
+    assert eng.free_pages == [eng.num_pages]   # reservations all returned
     assert len(eng.queue) == len(reqs)       # nothing dropped
     eng.faults = None                        # "the device came back"
     eng._backoff.clear()

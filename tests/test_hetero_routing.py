@@ -314,9 +314,9 @@ def test_simulate_day_matches_live_place(parts, profiles, regions):
 
 
 def test_single_engine_rejects_bad_knobs(parts):
-    """Config validation: routing/deferral knobs are checked in the base
-    engine (the sharded probe construction inherits it), and per-shard
-    list lengths are checked by the fleet."""
+    """Config validation: routing/deferral knobs and per-shard list
+    lengths are checked once, in the engine's constructor, at every
+    shard count."""
     _, m, params = parts
     base = dict(max_batch=2, max_len=64, paged=True, page_size=PS,
                 prefill_chunk=CH)

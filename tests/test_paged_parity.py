@@ -73,7 +73,7 @@ def assert_pool_clean(eng):
     assert (np.asarray(alloc["tbl"]) == -1).all()
     assert (np.asarray(alloc["ref"]) == 0).all()
     assert sorted(np.asarray(alloc["free"]).tolist()) == list(range(P))
-    assert eng.free_pages == eng.num_pages
+    assert eng.free_pages == [eng.num_pages]
 
 
 @pytest.mark.parametrize("num_pages", [None, 12],

@@ -45,7 +45,7 @@ class CheckedPreemptEngine(ServingEngine):
         for row in tbl:
             for p in row[row >= 0]:
                 counts[p] += 1
-        for pins in self._pins.values():
+        for _, pins in self._pins.values():
             for p in pins:
                 counts[p] += 1
         assert (ref == counts).all(), \
@@ -129,7 +129,7 @@ def test_preemption_parity_and_invariants(parts):
     for r in preempted:
         assert len(r.tokens) == 24          # full budget despite eviction
         assert r.recompute_j > 0.0
-    assert eng.free_pages == eng.num_pages  # drained pool, pins gone
+    assert eng.free_pages == [eng.num_pages]  # drained pool, pins gone
     assert not eng._pins
     st = eng.stats()
     assert st["preemption_count"] == eng.preemption_count
@@ -166,7 +166,7 @@ def test_partially_shared_victim_parity(parts):
     assert eng.preemption_count >= 1
     for rid in want:
         assert got[rid].tokens == want[rid].tokens, f"request {rid} diverged"
-    assert eng.free_pages == eng.num_pages
+    assert eng.free_pages == [eng.num_pages]
 
 
 @KINDS
@@ -236,7 +236,7 @@ def test_repeated_preemption_same_request(parts):
     assert got[0].preemptions >= 1
     want = oracle(m, params, [dict(rid=0, prompt=orig, max_new_tokens=40)])
     assert got[0].tokens == want[0].tokens
-    assert eng.free_pages == eng.num_pages
+    assert eng.free_pages == [eng.num_pages]
 
 
 def test_no_victim_below_priority_waits(parts):

@@ -61,13 +61,13 @@ class CheckedEngine(ServingEngine):
             "mapped page on the free stack"
 
     def _prefill_quantum(self):
-        head = self._prefilling[0] if self._prefilling else None
+        head = self._prefilling[0][0] if self._prefilling[0] else None
         pos0 = head[0].prefill_pos if head else 0
         ran = super()._prefill_quantum()
         if head and ran:
             req, slot = head
             nv = max(req.prefill_pos - pos0, 1)
-            if self.slot_rid[slot] == req.rid or req.prefill_pos < len(
+            if self.slot_rid[0][slot] == req.rid or req.prefill_pos < len(
                     req.prompt):
                 tbl, _, _, ref = self._alloc_state()
                 for lp in range(pos0 // PS, (pos0 + nv - 1) // PS + 1):
@@ -82,8 +82,8 @@ class CheckedEngine(ServingEngine):
         # every page a slot can write during this chunk must be private
         tbl, _, _, ref = self._alloc_state()
         for s in range(self.cfg.max_batch):
-            if self._slot_armed[s]:
-                t = int(self._slot_ctx[s])
+            if self._slot_armed[0][s]:
+                t = int(self._slot_ctx[0][s])
                 for lp in range(t // PS,
                                 min((t + self.cfg.sync_every - 1) // PS,
                                     tbl.shape[1] - 1) + 1):
@@ -125,8 +125,9 @@ def assert_pool_clean(eng):
     assert (np.asarray(alloc["tbl"]) == -1).all()
     assert (np.asarray(alloc["ref"]) == 0).all()
     assert sorted(np.asarray(alloc["free"]).tolist()) == list(range(P))
-    assert eng.free_pages == eng.num_pages
-    assert not eng._prefix_index and not eng._page_key and not eng._page_ref
+    assert eng.free_pages == [eng.num_pages]
+    assert not eng._prefix_index[0] and not eng._page_key[0] \
+        and not eng._page_ref[0]
 
 
 # ------------------------------------------------------------------ parity
@@ -229,18 +230,18 @@ def test_whole_prompt_shared_triggers_cow(parts):
         prefill_chunk=CH, prefix_sharing=True, num_pages=8))
     eng.submit(Request(**donor))
     eng._admit()
-    while eng._prefilling:
+    while eng._prefilling[0]:
         eng._prefill_quantum()
-    assert len(eng._prefix_index) == 3      # donor registered 3 pages
+    assert len(eng._prefix_index[0]) == 3   # donor registered 3 pages
     d_row = np.asarray(jax.device_get(eng.caches["paged"]["tbl"]))[0]
 
     eng.submit(Request(**follower))
     eng._admit()
     tbl, _, _, ref = eng._alloc_state()
-    f_slot = eng.slot_rid.index(1)
+    f_slot = eng.slot_rid[0].index(1)
     assert tbl[f_slot, :3].tolist() == d_row[:3].tolist()   # fully adopted
     assert all(ref[p] == 2 for p in d_row[:3])
-    assert eng._prefilling[0][0].prefill_pos == 3 * PS - 1  # recompute tail
+    assert eng._prefilling[0][0][0].prefill_pos == 3 * PS - 1  # recompute tail
 
     eng._prefill_quantum()                  # the 1-token CoW chunk
     tbl, _, _, ref = eng._alloc_state()

@@ -201,7 +201,7 @@ class CountedEngine(ServingEngine):
         ran = super().step(max_steps)
         top = int(jax.device_get(self.caches["paged"]["top"]))
         assert self.mapped_pages() == self.num_pages - top, self._quantum
-        assert self.mapped_pages() <= self.num_pages - self.free_pages
+        assert self.mapped_pages() <= self.num_pages - self.free_pages[0]
         self.checked = getattr(self, "checked", 0) + 1
         return ran
 
@@ -264,4 +264,4 @@ def test_mapped_pages_mirror_the_device(parts, case):
     st = eng.stats()
     assert eng.checked == eng._quantum
     assert 0 < st["mapped_page_quanta"] <= st["reserved_page_quanta"]
-    assert eng.mapped_pages() == 0 and eng.free_pages == eng.num_pages
+    assert eng.mapped_pages() == 0 and eng.free_pages == [eng.num_pages]
